@@ -7,7 +7,7 @@ rebuilds its full capability surface TPU-first:
 - **System plane** (agents, aRPC fabric, scheduler, server, archive formats)
   in Python/asyncio with C++ native hot paths — the reference's Go role.
 - **Data plane** (content-defined chunking, SHA-256 fingerprinting, chunk
-  index probing, similarity sketching) as batched JAX/Pallas programs on TPU,
+  index probing, similarity sketching) as batched JAX programs on TPU,
   sharded over `jax.sharding.Mesh` axes (agent fan-in = batch axis, sharded
   chunk index = index axis, long streams = sequence axis with halo exchange).
 

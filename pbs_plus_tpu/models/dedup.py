@@ -30,10 +30,9 @@ import numpy as np
 
 from ..chunker.spec import WINDOW, ChunkerParams, select_cuts
 from ..ops.cuckoo import CuckooIndex
-from ..ops.rolling_hash import batched_candidate_hits, device_tables
+from ..ops.rolling_hash import (batched_candidate_hits, device_tables,
+                                segment_class)
 from ..ops.sha256 import sha256_streams_chunks
-
-_PIPE_MASK_ROWS = 32          # candidate-batch row cap per dispatch
 
 
 @dataclass(frozen=True)
@@ -108,24 +107,24 @@ class DedupPipeline:
             self.stats["bytes_in"] += len(a)
             for off in range(0, len(a), seg):
                 S = min(seg, len(a) - off)
-                S_pad = max(1 << 14, 1 << int(S - 1).bit_length())
-                tasks_by_pad.setdefault(S_pad, []).append((n, off, S))
+                tasks_by_pad.setdefault(segment_class(S), []).append(
+                    (n, off, S))
         ends_parts: dict[str, list[np.ndarray]] = {n: [] for n in names}
-        for S_pad, tasks in sorted(tasks_by_pad.items()):
-            for lo in range(0, len(tasks), _PIPE_MASK_ROWS):
-                batch = tasks[lo:lo + _PIPE_MASK_ROWS]
-                hits_rows = batched_candidate_hits(
-                    [arrs[n][off:off + S] for n, off, S in batch],
-                    [arrs[n][off - (WINDOW - 1):off] if off else None
-                     for n, off, S in batch],
-                    self._tables, self.params)
-                self.stats["device_steps"] += 1
-                self.stats["batched_rows"] += len(batch)
-                self.stats["max_batch"] = max(self.stats["max_batch"],
-                                              len(batch))
-                for (n, off, S), hits in zip(batch, hits_rows):
-                    valid = hits + off >= WINDOW - 1
-                    ends_parts[n].append(hits[valid] + 1 + off)
+        for _, batch in sorted(tasks_by_pad.items()):
+            # one call per padded size; the op splits it into as many
+            # dispatches as the device's memory budget asks for
+            hits_rows = batched_candidate_hits(
+                [arrs[n][off:off + S] for n, off, S in batch],
+                [arrs[n][off - (WINDOW - 1):off] if off else None
+                 for n, off, S in batch],
+                self._tables, self.params)
+            self.stats["device_steps"] += 1
+            self.stats["batched_rows"] += len(batch)
+            self.stats["max_batch"] = max(self.stats["max_batch"],
+                                          len(batch))
+            for (n, off, S), hits in zip(batch, hits_rows):
+                valid = hits + off >= WINDOW - 1
+                ends_parts[n].append(hits[valid] + 1 + off)
         all_cuts: dict[str, list[int]] = {}
         for n in names:
             ends = np.sort(np.concatenate(ends_parts[n])) \
@@ -163,6 +162,27 @@ class DedupPipeline:
         return out
 
 
+class DeviceDispatchError(RuntimeError):
+    """A device dispatch made for a ``chunker="tpu"`` session failed.
+    The session ends and its job fails with this name in ``last_error``;
+    the work is never re-run on the host behind the operator's back."""
+
+    def __init__(self, what: str, cause: BaseException):
+        super().__init__(f"DeviceDispatchError: {what} failed: "
+                         f"{type(cause).__name__}: {cause}")
+
+
+def device_sha256_batch(chunks: list) -> list:
+    """The ``chunker="tpu"`` batch hasher: this stream's chunk batch goes
+    to the process-wide DeviceFeeder, which coalesces it with other
+    concurrent writers' batches into one device round."""
+    from .feeder import get_feeder
+    try:
+        return get_feeder().sha256_batch(chunks)
+    except Exception as e:
+        raise DeviceDispatchError("sha256 batch", e) from e
+
+
 class TpuChunker:
     """chunker-interface adapter: feed/finalize returning absolute cut
     offsets, computed by the device kernel.  Drop-in for CpuChunker in
@@ -191,7 +211,10 @@ class TpuChunker:
     def _candidates(self, data: np.ndarray) -> np.ndarray:
         from .feeder import get_feeder
         TpuChunker.device_dispatches += 1
-        hits = get_feeder().candidate_hits(data, self._tail, self.params)
+        try:
+            hits = get_feeder().candidate_hits(data, self._tail, self.params)
+        except Exception as e:
+            raise DeviceDispatchError("candidate scan", e) from e
         valid = hits + self._seen >= WINDOW - 1
         return hits[valid] + 1 + self._seen
 

@@ -49,12 +49,12 @@ from typing import Optional
 import numpy as np
 
 from ..chunker.spec import ChunkerParams
+from ..utils.log import L
 
-# combined SHA dispatch cap: bounds the one-dispatch device buffer when
-# many writers flush 64 MiB hash batches at once
+# combined SHA round cap: bounds the host copy one feeder round packs
+# (ops/sha256.py splits it into staging buffers) when many writers flush
+# hash batches at once
 _SHA_BATCH_BYTES_CAP = 256 << 20
-# candidate batch row cap per dispatch (jit cache: B padded to pow2)
-_MASK_BATCH_ROWS_CAP = 64
 
 
 @dataclass
@@ -92,8 +92,9 @@ class DeviceFeeder:
         self._thread: Optional[threading.Thread] = None
         self._tables_cache: dict[tuple, object] = {}   # params key → device tables
         self.stats = {"mask_dispatches": 0, "mask_rows": 0,
-                      "max_mask_batch": 0, "sha_dispatches": 0,
-                      "sha_streams": 0, "max_sha_streams": 0}
+                      "max_mask_batch": 0, "mask_retried_alone": 0,
+                      "sha_dispatches": 0, "sha_streams": 0,
+                      "max_sha_streams": 0, "sha_retried_alone": 0}
 
     # -- public API (writer threads) --------------------------------------
     def candidate_hits(self, buf: np.ndarray, history: np.ndarray,
@@ -181,57 +182,66 @@ class DeviceFeeder:
         return t
 
     def _dispatch_masks(self, reqs: list[_MaskReq]) -> None:
-        # group by chunker params (mask/magic/seed differ per job config)
+        # group by chunker params (mask/magic/seed differ per job config);
+        # batched_candidate_hits splits a group the device budget cannot
+        # take in one dispatch
         groups: dict[tuple, list[_MaskReq]] = {}
         for r in reqs:
             groups.setdefault(r.key, []).append(r)
         for key, group in groups.items():
-            for i in range(0, len(group), _MASK_BATCH_ROWS_CAP):
-                self._dispatch_mask_group(key, group[i:i + _MASK_BATCH_ROWS_CAP])
+            self._dispatch_mask_group(key, group)
+
+    def _mask_hits(self, key: tuple, group: list[_MaskReq]) -> list:
+        # import + table build inside the caller's guard: a backend-init
+        # or device failure here must fail THESE waiters, not the thread
+        from ..ops.rolling_hash import batched_candidate_hits
+        params = group[0].params
+        hits = batched_candidate_hits([r.buf for r in group],
+                                      [r.history for r in group],
+                                      self._tables(key, params), params)
+        self.stats["mask_dispatches"] += 1
+        self.stats["mask_rows"] += len(group)
+        return hits
 
     def _dispatch_mask_group(self, key: tuple, group: list[_MaskReq]) -> None:
-        params = group[0].params
         try:
-            # import + table build inside the guard: a backend-init or
-            # device failure here must fail THESE waiters, not the thread
-            from ..ops.rolling_hash import batched_candidate_hits
-            tables = self._tables(key, params)
-            hits = batched_candidate_hits([r.buf for r in group],
-                                          [r.history for r in group],
-                                          tables, params)
-            self.stats["mask_dispatches"] += 1
-            self.stats["mask_rows"] += len(group)
+            hits = self._mask_hits(key, group)
             self.stats["max_mask_batch"] = max(self.stats["max_mask_batch"],
                                                len(group))
             for r, h in zip(group, hits):
                 r.hits = h
                 r.done.set()
-        except BaseException:
+        except BaseException as batch_exc:
+            if len(group) == 1:
+                group[0].exc = batch_exc
+                group[0].done.set()
+                return
             # failure isolation: retry each stream's request alone so a
-            # poisoned input (or a batch-sized OOM) fails only its owner,
-            # never the unrelated jobs co-batched with it.  Re-resolve the
-            # import/tables per retry — the batch may have failed there.
+            # poisoned input fails only its owner, never the unrelated
+            # jobs co-batched with it.  Counted: a batch path that is
+            # broken on this device would otherwise show only as a slow
+            # run (every request succeeding alone).
+            L.warning("device scan batch of %d failed (%s: %s); retrying "
+                      "each request alone", len(group),
+                      type(batch_exc).__name__, batch_exc)
             for r in group:
+                self.stats["mask_retried_alone"] += 1
                 try:
-                    from ..ops.rolling_hash import batched_candidate_hits
-                    r.hits = batched_candidate_hits(
-                        [r.buf], [r.history], self._tables(key, params),
-                        params)[0]
-                    self.stats["mask_dispatches"] += 1
-                    self.stats["mask_rows"] += 1
+                    r.hits = self._mask_hits(key, [r])[0]
                 except BaseException as e:
                     r.exc = e
                 r.done.set()
 
+    def _sha_digests(self, reqs: list[_ShaReq]) -> list:
+        from ..ops.sha256 import sha256_chunks
+        digests = sha256_chunks([c for r in reqs for c in r.chunks])
+        self.stats["sha_dispatches"] += 1
+        self.stats["sha_streams"] += len(reqs)
+        return digests
+
     def _dispatch_sha(self, reqs: list[_ShaReq]) -> None:
         try:
-            from ..ops.sha256 import sha256_chunks
-            all_chunks: list = []
-            for r in reqs:
-                all_chunks.extend(r.chunks)
-            digests = sha256_chunks(all_chunks)
-            self.stats["sha_dispatches"] += 1
-            self.stats["sha_streams"] += len(reqs)
+            digests = self._sha_digests(reqs)
             self.stats["max_sha_streams"] = max(self.stats["max_sha_streams"],
                                                 len(reqs))
             off = 0
@@ -239,14 +249,19 @@ class DeviceFeeder:
                 r.digests = digests[off:off + len(r.chunks)]
                 off += len(r.chunks)
                 r.done.set()
-        except BaseException:
-            # same isolation contract as the mask path
+        except BaseException as batch_exc:
+            if len(reqs) == 1:
+                reqs[0].exc = batch_exc
+                reqs[0].done.set()
+                return
+            # same isolation contract (and the same count) as the mask path
+            L.warning("device hash batch of %d streams failed (%s: %s); "
+                      "retrying each alone", len(reqs),
+                      type(batch_exc).__name__, batch_exc)
             for r in reqs:
+                self.stats["sha_retried_alone"] += 1
                 try:
-                    from ..ops.sha256 import sha256_chunks
-                    r.digests = sha256_chunks(r.chunks)
-                    self.stats["sha_dispatches"] += 1
-                    self.stats["sha_streams"] += 1
+                    r.digests = self._sha_digests([r])
                 except BaseException as e:
                     r.exc = e
                 r.done.set()

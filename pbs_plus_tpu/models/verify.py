@@ -17,6 +17,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from ..ops.sha256 import sha256_chunks, sha256_stream_chunks
+from ..utils import jaxenv
 from ..utils.log import L
 
 
@@ -111,18 +112,10 @@ class VerifyPipeline:
             digests = [digests[i] for i in idx]
         digests = list(dict.fromkeys(digests))   # meta/payload may share
         res = VerifyResult(checked=len(digests))
-        # batched device hashing only when a real accelerator is live —
-        # the jax SHA pipeline on the CPU backend is orders of magnitude
+        # batched device hashing only on an accelerator backend — the
+        # jax SHA pipeline on the CPU backend is orders of magnitude
         # slower than hashlib (it exists for the TPU's batch geometry)
-        use_device = False
-        try:
-            from ..utils.jaxdev import ensure_backend
-            if ensure_backend() != "cpu":
-                import jax
-                use_device = jax.default_backend() != "cpu"
-        except Exception as e:
-            L.debug("device backend probe failed; verifying with "
-                    "hashlib: %s", e)
+        use_device = jaxenv.pick_twin("verify.rehash")
 
         def fetch(d: bytes) -> bytes | None:
             # the cache path verifies sha256 on load (ChunkStore.get /

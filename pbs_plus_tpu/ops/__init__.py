@@ -1,4 +1,4 @@
-"""TPU data-plane kernels (jnp + Pallas).
+"""TPU data-plane kernels (jnp).
 
 The native-accelerated equivalent of the reference's chunker/hash hot loops
 (SURVEY §2.10: "the hard kernel" — segment-parallel CDC; §3.4: the commit

@@ -29,6 +29,8 @@ SLOTS = 4
 _MIX = np.uint32(0x9E3779B1)
 _MAX_KICKS = 500
 BUCKET_BYTES = SLOTS * 2 * 4        # uint32[SLOTS, 2] per bucket
+# padded digest counts of a device probe: 64, 256, 1024, … (powers of four)
+_PROBE_CLASSES = tuple(1 << k for k in range(6, 31, 2))
 
 
 def buckets_for_bytes(budget_bytes: int, *, minimum: int = 1 << 10) -> int:
@@ -419,13 +421,20 @@ class CuckooIndex:
             self._dirty = False
         return self._device_table
 
-    def probe(self, digests: np.ndarray | jax.Array) -> jax.Array:
+    def probe(self, digests: np.ndarray | jax.Array) -> np.ndarray:
         """digests uint8[N,32] → bool[N] (maybe-present; exact-confirm via
-        contains_exact on hits if false positives matter)."""
-        d = jnp.asarray(digests, dtype=jnp.uint8)
-        return _lookup(self.device_table(), d)
+        contains_exact on hits if false positives matter).  The batch is
+        padded on the host to a probe class, so the lookup compiles for
+        a handful of batch sizes and not for every N."""
+        arr = np.asarray(digests, dtype=np.uint8)
+        n = arr.shape[0]
+        n_pad = next(c for c in _PROBE_CLASSES if c >= n)
+        padded = np.zeros((n_pad, 32), dtype=np.uint8)
+        padded[:n] = arr
+        return np.asarray(_lookup(self.device_table(),
+                                  jnp.asarray(padded)))[:n]
 
     def probe_confirmed(self, digests: list[bytes]) -> list[bool]:
         arr = np.frombuffer(b"".join(digests), dtype=np.uint8).reshape(-1, 32)
-        maybe = np.asarray(self.probe(arr))
+        maybe = self.probe(arr)
         return [bool(m) and (d in self._known) for m, d in zip(maybe, digests)]

@@ -19,6 +19,8 @@ Bit parity gate: BASELINE.md config #2.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -27,9 +29,12 @@ from ..chunker import observe
 from ..chunker.spec import WINDOW, ChunkerParams, buzhash_subtables
 from ..chunker.spec import select_cuts
 
-# multi-chip dispatch evidence (test/metrics probe): bumped whenever a
-# batched dispatch is sharded over the data mesh
-stats = {"mesh_dispatches": 0, "mesh_devices": 0}
+# multi-chip dispatch evidence and padding occupancy (test/metrics
+# probe): mesh_* move whenever a batched dispatch is sharded over the
+# data mesh; mesh_shard_devices is how many distinct devices the last
+# sharded input's shards really sat on
+stats = {"mesh_dispatches": 0, "mesh_devices": 0, "mesh_shard_devices": 0,
+         "dispatches": 0, "rows": 0, "bytes": 0, "padded_bytes": 0}
 
 
 def _rotl(x: jax.Array, r: int) -> jax.Array:
@@ -48,9 +53,10 @@ def device_tables(params: ChunkerParams) -> jax.Array:
 def _table_lookup(data: jax.Array, tables: jax.Array) -> jax.Array:
     """T[b] = A[b>>4] ^ B[b&15] as 32 unrolled selects — no gather.
 
-    XLA TPU element-gathers run ~0.12 GB/s on this hardware; the nibble
-    decomposition (chunker/spec.py buzhash_table) turns the lookup into
-    VPU-friendly compare/select/xor chains that XLA fuses into one pass.
+    The nibble decomposition (chunker/spec.py buzhash_table) turns the
+    lookup into compare/select/xor chains that XLA can fuse into one
+    pass, where a 256-entry table would be an element gather.  On-chip
+    rates of the two: not measured.
     """
     hi = data >> np.uint8(4)
     lo = data & np.uint8(0xF)
@@ -110,52 +116,116 @@ def candidate_mask(data: jax.Array, tables: jax.Array, mask: int,
                                jnp.uint32(magic), history)
 
 
+# The whole jit key of the batched scan is (padded rows, padded segment
+# length), and both come from these two short lists.
+_ROW_CLASSES = (1, 4, 16, 64)
+_SEG_CLASSES = tuple(1 << k for k in range(16, 31, 2))     # 64 KiB … 1 GiB
+# device bytes one scanned byte costs while the program runs: input +
+# mask out + the uint32 hash array and its shifted copy (the chip's
+# compiler reports temp = 8.04x input at [8, 4 MiB] and [64, 4 MiB] —
+# tests/test_tpu_compile.py)
+_SCAN_BYTES_PER_BYTE = 10
+# share of a device's memory one scan dispatch may take: the hash
+# staging buffers and the index table live there too
+_SCAN_MEMORY_SHARE = 4
+_ASSUMED_DEVICE_BYTES = 16 << 30      # backends that report no limit (CPU)
+
+
+def _class_for(n: int, classes: tuple) -> int:
+    return next(c for c in classes if c >= n)
+
+
+def segment_class(n: int) -> int:
+    """Padded length a segment of ``n`` bytes is scanned at."""
+    if n > _SEG_CLASSES[-1]:
+        raise ValueError(f"scan segment of {n} bytes exceeds "
+                         f"{_SEG_CLASSES[-1]}")
+    return _class_for(n, _SEG_CLASSES)
+
+
+@functools.cache
+def scan_budget_bytes() -> int:
+    """Device bytes one scan dispatch may occupy on each device (decided
+    once per process, like ``parallel.mesh.data_mesh``)."""
+    ms = jax.devices()[0].memory_stats() or {}
+    return ms.get("bytes_limit", _ASSUMED_DEVICE_BYTES) // _SCAN_MEMORY_SHARE
+
+
+def dispatch_rows(seg_bytes: int, n_devices: int = 1) -> int:
+    """Largest row class whose ``[rows, seg_bytes]`` scan fits the
+    per-device budget when the rows spread over ``n_devices``."""
+    cap = scan_budget_bytes() // (seg_bytes * _SCAN_BYTES_PER_BYTE) \
+        * n_devices
+    fits = [c for c in _ROW_CLASSES if c <= cap]
+    if not fits:
+        raise ValueError(f"a {seg_bytes}-byte scan segment does not fit "
+                         f"the device budget of {scan_budget_bytes()} bytes")
+    return fits[-1]
+
+
 def batched_candidate_hits(bufs: list, hists: list, tables: jax.Array,
                            params: ChunkerParams) -> list[np.ndarray]:
     """THE pack/dispatch/unpack step for cross-stream candidate batching:
     stack variable-length segments (with optional per-row 63-byte history)
-    into one pow2-padded ``[B_pad, S_pad]`` candidate_mask dispatch and
-    return each row's raw hit indices (0-based positions, unfiltered —
-    callers apply their own window-validity/offset arithmetic).
+    into class-padded ``[B_pad, S_pad]`` candidate_mask dispatches — as
+    many as the device budget splits the rows into — and return each
+    row's raw hit indices (0-based positions, unfiltered — callers apply
+    their own window-validity/offset arithmetic).
 
     Shared by the production DeviceFeeder (models/feeder.py) and the
     whole-stream DedupPipeline so their padding/history handling cannot
     diverge (the bit-parity guarantee hangs on this one implementation).
     """
-    B = len(bufs)
     # backend observability: every batched device scan lands here (the
     # feeder AND the whole-stream pipeline), so this is the one "tpu"
     # scan-bytes accounting point (chunker/observe.py)
     observe.add_scan_bytes("tpu", sum(len(b) for b in bufs))
-    S_max = max(len(b) for b in bufs)
-    S_pad = max(1 << 14, 1 << int(S_max - 1).bit_length()) if S_max \
-        else 1 << 14
-    B_pad = 1 << int(B - 1).bit_length() if B > 1 else 1
+    S_pad = segment_class(max(len(b) for b in bufs))
     # multi-chip: any coalesced batch (≥2 rows) shards over the data
     # mesh, padded up to mesh width — each chip computes ≤ceil(B/n)
     # rows instead of one chip computing B, so latency drops even when
     # some chips get zero rows.  Single-row dispatches stay local.
     mesh = None
-    if B_pad >= 2:
+    if len(bufs) >= 2:
         from ..parallel.mesh import data_mesh
-        m_ = data_mesh()
-        if m_ is not None:
-            mesh = m_
-            n = m_.size
-            B_pad = ((max(B_pad, n) + n - 1) // n) * n
+        mesh = data_mesh()
+    rows = dispatch_rows(S_pad, mesh.size if mesh is not None else 1)
+    out: list[np.ndarray] = []
+    for lo in range(0, len(bufs), rows):
+        out.extend(_dispatch_hits(bufs[lo:lo + rows], hists[lo:lo + rows],
+                                  S_pad, mesh, tables, params))
+    return out
+
+
+def _dispatch_hits(bufs: list, hists: list, S_pad: int, mesh,
+                   tables: jax.Array, params: ChunkerParams,
+                   ) -> list[np.ndarray]:
+    B_pad = _class_for(len(bufs), _ROW_CLASSES)
+    if mesh is not None:
+        n = mesh.size
+        B_pad = ((max(B_pad, n) + n - 1) // n) * n
     buf = np.zeros((B_pad, S_pad), dtype=np.uint8)
     hist = np.zeros((B_pad, WINDOW - 1), dtype=np.uint8)
     for i, (b, h) in enumerate(zip(bufs, hists)):
         buf[i, :len(b)] = b
         if h is not None:
             hist[i] = h
-    dbuf, dhist = jnp.asarray(buf), jnp.asarray(hist)
     if mesh is not None:
+        # straight from the host to each device's shard: going through a
+        # one-device array first would compile a slicing program per shape
         from jax.sharding import NamedSharding, PartitionSpec as P
-        dbuf = jax.device_put(dbuf, NamedSharding(mesh, P("data", None)))
-        dhist = jax.device_put(dhist, NamedSharding(mesh, P("data", None)))
+        rows = NamedSharding(mesh, P("data", None))
+        dbuf, dhist = jax.device_put(buf, rows), jax.device_put(hist, rows)
         stats["mesh_dispatches"] += 1
         stats["mesh_devices"] = mesh.size
+        stats["mesh_shard_devices"] = len(
+            {s.device for s in dbuf.addressable_shards})
+    else:
+        dbuf, dhist = jnp.asarray(buf), jnp.asarray(hist)
+    stats["dispatches"] += 1
+    stats["rows"] += len(bufs)
+    stats["bytes"] += sum(len(b) for b in bufs)
+    stats["padded_bytes"] += buf.size
     m = np.asarray(candidate_mask(dbuf, tables, params.mask,
                                   params.magic, history=dhist))
     return [np.nonzero(m[i, :len(b)])[0] for i, b in enumerate(bufs)]
@@ -170,8 +240,8 @@ def candidate_ends_host(data: bytes | np.ndarray, params: ChunkerParams,
     arr = np.frombuffer(data, dtype=np.uint8) if not isinstance(data, np.ndarray) else data
     tables = device_tables(params)
     n = len(arr)
-    # pad to a power-of-two length so the jit cache sees few shapes
-    S = max(1 << 14, 1 << (n - 1).bit_length()) if n else 1 << 14
+    # pad to a segment class so the jit cache sees few shapes
+    S = segment_class(n)
     if S != n:
         padded = np.zeros(S, dtype=np.uint8)
         padded[:n] = arr

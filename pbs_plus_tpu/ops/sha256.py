@@ -1,15 +1,18 @@
 """Batched whole-chunk SHA-256 on TPU.
 
 SHA-256 is strictly sequential per chunk (64-byte block chain), so TPU
-throughput comes from batching: a ``lax.scan`` over block index advances N
-chunk states in lockstep on the VPU; variable chunk lengths are handled by
+throughput comes from batching: a loop over block index advances N chunk
+states in lockstep on the VPU; variable chunk lengths are handled by
 masking (finished chunks freeze), and the standard SHA padding (0x80 +
-zeros + 64-bit bit length) is applied on device so chunks never touch the
-host.  Blocks are gathered per step straight from the device-resident
-stream buffer — the padded [T, N, 64] block tensor is never materialized.
+zeros + 64-bit bit length) is applied on device.  Blocks are gathered per
+step straight from a device-resident staging buffer — the padded
+[T, N, 64] block tensor is never materialized.
 
-Chunks are bucketed by block count (next power of two) so padding waste is
-<50% per bucket and jit cache keys stay bounded.
+Chunks are packed on the host into a staging buffer of one of a few
+lengths and hashed in one dispatch per length bucket (next power of two
+of the block count, so padding waste is <50% per bucket).  The loop's
+trip count is a run-time argument: the compiled program's key is only
+(staging-buffer class, row class).
 
 Digest parity vs hashlib/OpenSSL is a correctness gate
 (tests/test_ops.py::test_sha256_matches_hashlib).
@@ -22,11 +25,11 @@ server-side sha256 verification pool
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from .rolling_hash import _class_for
 
 _K = np.array([
     0x428a2f98, 0x71374491, 0xb5c0fbcf, 0xe9b5dba5,
@@ -53,6 +56,7 @@ _H0 = np.array([
 ], dtype=np.uint32)
 
 MAX_CHUNK_BYTES = (1 << 29) - 64   # uint32 bit-length arithmetic bound
+SLACK_BYTES = 4096                 # readable bytes past the last chunk (unroll <= 64)
 
 
 def _rotr(x: jax.Array, r: int) -> jax.Array:
@@ -118,20 +122,27 @@ def _compress(state: jax.Array, words: jax.Array, active: jax.Array) -> jax.Arra
 
 
 def _sha256_scan_impl(stream: jax.Array, starts: jax.Array, lengths: jax.Array,
-                      t_max: int, unroll: int | None = None,
-                      assume_padded: bool = False) -> jax.Array:
+                      n_blocks, unroll: int | None = None) -> jax.Array:
     """stream uint8[S]; starts/lengths int32[N] → digests uint32[N,8].
-    Padded slots (length<0) produce garbage digests the caller discards.
+    ``n_blocks`` is how many 64-byte block steps to run: at least
+    ``(max(lengths) + 8) // 64 + 1``.  It may be a traced int32 scalar —
+    the loop then reads its trip count at run time, so one compiled
+    program serves every chunk length (a static trip count made one
+    program per length bucket; on the TPU each is a compile of over a
+    minute).  Padded slots (length 0) hash the empty string; the caller
+    discards them.  The caller leaves SLACK_BYTES after the last chunk
+    byte: a row slice that holds chunk bytes then never clamps, and one
+    that starts past its chunk's end may — it is masked out entirely.
 
-    Blocks are fetched per scan step as contiguous rows via vmap'd
-    dynamic_slice (XLA TPU element-gathers run ~0.12 GB/s; row slices are
-    orders of magnitude faster), ``unroll`` blocks per step to amortize
-    loop overhead.  CPU defaults to unroll=1 (its compress is an inner
-    scan; big unrolled bodies blow up the CPU pass pipeline)."""
+    Blocks are fetched per step as contiguous rows via vmap'd
+    dynamic_slice rather than an element gather (on-chip rates of the
+    two: not measured), ``unroll`` blocks per step to amortize loop
+    overhead.  CPU defaults to unroll=1 (its compress is an inner scan;
+    big unrolled bodies blow up the CPU pass pipeline)."""
     if unroll is None:
         unroll = 16 if jax.default_backend() != "cpu" else 1
-    unroll = max(1, min(unroll, t_max))
-    n_steps = (t_max + unroll - 1) // unroll
+    unroll = max(1, unroll)
+    n_steps = (n_blocks + unroll - 1) // unroll
     N = starts.shape[0]
     L = lengths
     nblocks = (L + 8) // 64 + 1                      # data + pad + bitlen
@@ -139,19 +150,13 @@ def _sha256_scan_impl(stream: jax.Array, starts: jax.Array, lengths: jax.Array,
     j = jnp.arange(64, dtype=jnp.int32)
     widx = jnp.arange(16, dtype=jnp.int32)
     row = unroll * 64
-    # guard slice-clamping: the furthest read is start + n_steps*row.
-    # Callers hashing many buckets of one stream pre-pad once and pass
-    # assume_padded=True (the pad is an O(S) device copy otherwise).
-    if assume_padded:
-        padded = stream
-    else:
-        padded = jnp.concatenate(
-            [stream, jnp.zeros((n_steps * row,), dtype=stream.dtype)])
+    if row > SLACK_BYTES:
+        raise ValueError(f"unroll {unroll} reads past the stream's slack")
 
-    def step(state, ti):
+    def step(ti, state):
         offs = starts + ti * row
         rows = jax.vmap(
-            lambda o: jax.lax.dynamic_slice(padded, (o,), (row,)))(offs)
+            lambda o: jax.lax.dynamic_slice(stream, (o,), (row,)))(offs)
         for u in range(unroll):
             t = ti * unroll + u
             raw = rows[:, u * 64:(u + 1) * 64]       # uint8[N,64]
@@ -169,157 +174,154 @@ def _sha256_scan_impl(stream: jax.Array, starts: jax.Array, lengths: jax.Array,
             words = jnp.where(is_last & (widx == 15)[None, :],
                               bitlen_lo[:, None], words)
             state = _compress(state, words, t < nblocks)
-        return state, None
+        return state
 
     # derive the init carry from the inputs so it inherits their varying
-    # manual axes under shard_map (scan carry-in/out types must match,
+    # manual axes under shard_map (loop carry-in/out types must match,
     # including the varying-axis annotation)
     vma_seed = (stream[0].astype(jnp.uint32)
                 + starts[0].astype(jnp.uint32)) * jnp.uint32(0)
     init = jnp.broadcast_to(jnp.asarray(_H0), (N, 8)).astype(jnp.uint32) \
         + vma_seed
-    state, _ = jax.lax.scan(step, init, jnp.arange(n_steps, dtype=jnp.int32))
-    return state
+    return jax.lax.fori_loop(0, n_steps, step, init)
 
 
 # jitted entry for standalone use; inside shard_map call _sha256_scan_impl
 # directly (a nested jit inside shard_map deadlocks the CPU backend)
-_sha256_scan = jax.jit(_sha256_scan_impl,
-                       static_argnames=("t_max", "unroll", "assume_padded"))
+_sha256_scan = jax.jit(_sha256_scan_impl, static_argnames=("unroll",))
 
 
-def _digests_to_bytes(d: np.ndarray) -> list[bytes]:
-    return [w.astype(">u4").tobytes() for w in d]
+_dispatch_count = 0      # device dispatches (integration-test probe)
+
+# multi-chip dispatch evidence and padding occupancy (test/metrics
+# probe), mirror of rolling_hash.stats
+stats = {"mesh_dispatches": 0, "mesh_devices": 0, "mesh_shard_devices": 0,
+         "rows": 0, "padded_rows": 0, "bytes": 0, "padded_bytes": 0}
+
+# The whole jit key of ``_sha256_scan`` is (staging-buffer length, padded
+# row count), and both come from these two short lists — a flush whose
+# size was seen before compiles nothing.  A staging buffer is filled up
+# to SLAB_BYTES / the largest row class; only a single chunk larger than
+# SLAB_BYTES takes a longer one.
+_ROW_CLASSES = (8, 64, 512, 4096)
+SLAB_BYTES = 64 << 20
+_SLAB_CLASSES = tuple(n + SLACK_BYTES for n in
+                      (16 << 20, SLAB_BYTES, 256 << 20, 1 << 30))
 
 
-_dispatch_count = 0      # device-batch dispatches (integration-test probe)
+def _hash_slab(views: list, unroll: int | None) -> list[bytes]:
+    """Pack ``views`` (uint8 arrays) back to back into ONE class-sized
+    staging buffer, upload it once, and hash it in one dispatch per
+    length bucket (next power of two of the block count: lanes of a
+    dispatch run in lockstep, so a bucket wastes under half its steps on
+    the shorter chunks).  Every bucket runs the same compiled program."""
+    global _dispatch_count
+    lens = np.array([len(v) for v in views], dtype=np.int64)
+    total = int(lens.sum())
+    slab = np.zeros(_class_for(total + SLACK_BYTES, _SLAB_CLASSES),
+                    dtype=np.uint8)
+    starts = np.concatenate([[0], np.cumsum(lens)[:-1]])
+    for v, off in zip(views, starts):
+        slab[off:off + len(v)] = v
+    # multi-chip: rows shard over the data mesh, the buffer is replicated
+    # (per-row slices are local reads); host arrays go straight to their
+    # devices — through a one-device array they would compile a slicing
+    # program per shape
+    from ..parallel.mesh import data_mesh
+    mesh = data_mesh()
+    if mesh is not None and _ROW_CLASSES[0] % mesh.size == 0:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        row_sharding = NamedSharding(mesh, P("data"))
+        ds = jax.device_put(slab, NamedSharding(mesh, P()))
+    else:
+        row_sharding = None
+        ds = jnp.asarray(slab)
+    stats["bytes"] += total
+    stats["padded_bytes"] += len(slab)
+    nblocks = (lens + 8) // 64 + 1
+    buckets: dict[int, list[int]] = {}
+    for i, nb in enumerate(nblocks):
+        buckets.setdefault(int(nb - 1).bit_length(), []).append(i)
+    out: list[bytes | None] = [None] * len(views)
+    for _, idxs in sorted(buckets.items()):
+        n_pad = _class_for(len(idxs), _ROW_CLASSES)
+        bs = np.zeros(n_pad, dtype=np.int32)
+        bl = np.zeros(n_pad, dtype=np.int32)
+        bs[:len(idxs)] = starts[idxs]
+        bl[:len(idxs)] = lens[idxs]
+        if row_sharding is None:
+            dbs, dbl = jnp.asarray(bs), jnp.asarray(bl)
+        else:
+            dbs = jax.device_put(bs, row_sharding)
+            dbl = jax.device_put(bl, row_sharding)
+            stats["mesh_dispatches"] += 1
+            stats["mesh_devices"] = mesh.size
+            stats["mesh_shard_devices"] = len(
+                {s.device for s in dbs.addressable_shards})
+        _dispatch_count += 1
+        stats["rows"] += len(idxs)
+        stats["padded_rows"] += n_pad
+        # deliberate batched sync: ONE device→host transfer per dispatch
+        # of up to 4096 chunks (the digests must land on the host), not
+        # a per-chunk sync
+        # pbslint: disable=no-hostsync-in-hot-loop
+        dig = np.asarray(_sha256_scan(ds, dbs, dbl,
+                                      np.int32(nblocks[idxs].max()),
+                                      unroll=unroll))
+        for k, i in enumerate(idxs):
+            out[i] = dig[k].astype(">u4").tobytes()
+    return out  # type: ignore[return-value]
 
-# multi-chip dispatch evidence (test/metrics probe), mirror of
-# rolling_hash.stats: bumped when a bucket shards over the data mesh
-stats = {"mesh_dispatches": 0, "mesh_devices": 0}
+
+def sha256_chunks(chunks: list, *, unroll: int | None = None) -> list[bytes]:
+    """SHA-256 of each chunk buffer (bytes-like or uint8 array), in input
+    order.  Chunks are packed in order into as few staging buffers as
+    hold them."""
+    views = [c if isinstance(c, np.ndarray) else np.frombuffer(c, np.uint8)
+             for c in chunks]
+    if any(len(v) > MAX_CHUNK_BYTES for v in views):
+        raise ValueError("chunk length out of supported range")
+    out: list[bytes] = []
+    lo = 0
+    while lo < len(views):
+        hi, used = lo, 0
+        while hi < len(views) and hi - lo < _ROW_CLASSES[-1] and (
+                hi == lo or used + len(views[hi]) <= SLAB_BYTES):
+            used += len(views[hi])
+            hi += 1
+        out.extend(_hash_slab(views[lo:hi], unroll))
+        lo = hi
+    return out
 
 
 def sha256_stream_chunks(stream, bounds: list[tuple[int, int]], *,
-                         max_batch: int = 4096,
                          unroll: int | None = None) -> list[bytes]:
-    """SHA-256 of ``stream[s:e]`` for each (s, e) in bounds, bucketed by
-    block count.  ``stream`` may be bytes / numpy uint8 / jax uint8 (kept
-    on device if already there).  Returns 32-byte digests in input order.
-    """
-    if not bounds:
-        return []
-    global _dispatch_count
-    _dispatch_count += 1
+    """SHA-256 of ``stream[s:e]`` for each (s, e) in bounds, in input
+    order.  ``stream`` may be bytes / numpy uint8 / a jax uint8 array
+    (which is brought to the host: dispatches are packed there)."""
     if isinstance(stream, (bytes, bytearray, memoryview)):
         stream = np.frombuffer(stream, dtype=np.uint8)
-    starts = np.array([s for s, _ in bounds], dtype=np.int32)
-    lens = np.array([e - s for s, e in bounds], dtype=np.int32)
-    if lens.min() < 0 or lens.max() > MAX_CHUNK_BYTES:
+    stream = np.asarray(stream)
+    if any(e < s or e - s > MAX_CHUNK_BYTES for s, e in bounds):
         raise ValueError("chunk length out of supported range")
-    nblocks = (lens.astype(np.int64) + 8) // 64 + 1
-    # pad the device stream ONCE to cover the largest bucket's furthest
-    # row-slice (each scan call then skips its own O(S) pad copy)
-    t_worst = 1 << int(max(nblocks) - 1).bit_length() if len(nblocks) else 1
-    pad = t_worst * 64 + 2048
-    dstream = jnp.concatenate(
-        [jnp.asarray(stream), jnp.zeros(pad, dtype=jnp.uint8)])
-    # bucket by next-pow2 block count; pad batch to pow2 for jit-cache reuse
-    buckets: dict[int, list[int]] = {}
-    for i, nb in enumerate(nblocks):
-        t = 1 << int(nb - 1).bit_length() if nb > 1 else 1
-        buckets.setdefault(t, []).append(i)
-    # multi-chip: shard each bucket's rows over the data mesh (stream
-    # replicated, per-row slices local); buckets narrower than the mesh
-    # stay single-device
-    from ..parallel.mesh import data_mesh
-    mesh = data_mesh()
-    mesh_sharding = None
-    if mesh is not None:
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        mesh_sharding = (NamedSharding(mesh, P("data")),
-                         NamedSharding(mesh, P()))
-    dstream_rep = None        # stream replicated over the mesh, once
-    out: list[bytes | None] = [None] * len(bounds)
-    for t_max, idxs in sorted(buckets.items()):
-        for lo in range(0, len(idxs), max_batch):
-            part = idxs[lo:lo + max_batch]
-            n = len(part)
-            n_pad = max(8, 1 << (n - 1).bit_length())
-            if mesh is not None and n_pad >= mesh.size:
-                # row axis must divide evenly over the mesh
-                n_pad = ((n_pad + mesh.size - 1)
-                         // mesh.size) * mesh.size
-            bs = np.zeros(n_pad, dtype=np.int32)
-            bl = np.zeros(n_pad, dtype=np.int32)
-            bs[:n] = starts[part]
-            bl[:n] = lens[part]
-            dbs, dbl = jnp.asarray(bs), jnp.asarray(bl)
-            ds = dstream
-            if mesh_sharding is not None and n_pad >= mesh.size:
-                row_s, rep_s = mesh_sharding
-                dbs = jax.device_put(dbs, row_s)
-                dbl = jax.device_put(dbl, row_s)
-                if dstream_rep is None:
-                    dstream_rep = jax.device_put(dstream, rep_s)
-                ds = dstream_rep
-                stats["mesh_dispatches"] += 1
-                stats["mesh_devices"] = mesh.size
-            # deliberate batched sync: ONE device→host transfer per
-            # dispatch of up to max_batch chunks (the digests must land
-            # on the host), not a per-chunk sync
-            # pbslint: disable=no-hostsync-in-hot-loop
-            dig = np.asarray(_sha256_scan(ds, dbs, dbl, t_max,
-                                          unroll=unroll, assume_padded=True))
-            for k, i in enumerate(part):
-                out[i] = dig[k].astype(">u4").tobytes()
-    return out  # type: ignore[return-value]
+    return sha256_chunks([stream[s:e] for s, e in bounds], unroll=unroll)
 
 
 def sha256_streams_chunks(streams: list, bounds_per_stream: list,
                           ) -> list[list[bytes]]:
-    """Cross-stream bucketed digesting: concatenate many streams into ONE
-    device buffer so every stream's chunks share the same bucketed
-    dispatches (the batch axis across agent streams — without this, B
-    streams cost B dispatch sets even when their chunks would bucket
-    together).  Returns per-stream digest lists in input order."""
+    """Cross-stream digesting: every stream's chunks share the same
+    packed dispatches (the batch axis across agent streams — without
+    this, B streams cost B dispatch sets).  Returns per-stream digest
+    lists in input order."""
     arrs = [np.frombuffer(s, dtype=np.uint8)
-            if isinstance(s, (bytes, bytearray, memoryview)) else s
+            if isinstance(s, (bytes, bytearray, memoryview)) else np.asarray(s)
             for s in streams]
-    total = sum(int(len(a)) for a in arrs)
-    # starts are int32 in the scan kernel: past ~2 GiB combined, fall back
-    # to per-stream dispatch sets rather than overflow
-    if total > (1 << 31) - MAX_CHUNK_BYTES - (1 << 20):
-        return [sha256_stream_chunks(a, b) if b else []
-                for a, b in zip(arrs, bounds_per_stream)]
-    all_bounds: list[tuple[int, int]] = []
-    counts: list[int] = []
-    off = 0
-    for a, bounds in zip(arrs, bounds_per_stream):
-        all_bounds.extend((off + s, off + e) for s, e in bounds)
-        counts.append(len(bounds))
-        off += len(a)
-    if not all_bounds:
-        return [[] for _ in arrs]
-    dstream = jnp.concatenate([jnp.asarray(a) for a in arrs if len(a)]) \
-        if total else jnp.zeros(0, dtype=jnp.uint8)
-    flat = sha256_stream_chunks(dstream, all_bounds)
+    flat = sha256_chunks([a[lo:hi]
+                          for a, bounds in zip(arrs, bounds_per_stream)
+                          for lo, hi in bounds])
     out: list[list[bytes]] = []
     k = 0
-    for c in counts:
-        out.append(flat[k:k + c])
-        k += c
+    for bounds in bounds_per_stream:
+        out.append(flat[k:k + len(bounds)])
+        k += len(bounds)
     return out
-
-
-def sha256_chunks(chunks: list[bytes]) -> list[bytes]:
-    """Digest a list of standalone chunk buffers (concatenates into one
-    stream buffer, then bucket-hashes)."""
-    if not chunks:
-        return []
-    stream = np.frombuffer(b"".join(chunks), dtype=np.uint8)
-    bounds = []
-    off = 0
-    for c in chunks:
-        bounds.append((off, off + len(c)))
-        off += len(c)
-    return sha256_stream_chunks(stream, bounds)

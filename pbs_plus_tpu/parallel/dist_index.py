@@ -51,8 +51,8 @@ from typing import Iterable, Iterator, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ._compat import shard_map
 
 from ..ops.cuckoo import SLOTS, _MIX, CuckooIndex, _digest_words
 from ..utils import atomicio, fswitness

@@ -18,8 +18,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ._compat import shard_map
 
 from ..chunker.spec import ChunkerParams
 from ..ops.cuckoo import CuckooIndex
@@ -37,7 +37,7 @@ def _words_to_bytes(words: jax.Array) -> jax.Array:
 
 
 def _step_body(streams, table, index_table, proj, mask, magic,
-               *, chunk_len: int, t_max: int, n_buckets: int,
+               *, chunk_len: int, n_buckets: int,
                data_axis: str, index_axis: str):
     b_local, S = streams.shape
     # 1) candidate mask (dense pass 1)
@@ -47,7 +47,7 @@ def _step_body(streams, table, index_table, proj, mask, magic,
     flat = streams.reshape(-1)
     starts = jnp.arange(b_local, dtype=jnp.int32) * S
     lens = jnp.full((b_local,), chunk_len, dtype=jnp.int32)
-    words = _sha256_scan_impl(flat, starts, lens, t_max)
+    words = _sha256_scan_impl(flat, starts, lens, (chunk_len + 8) // 64 + 1)
     digests = _words_to_bytes(words)
     # 3) distributed index probe: partial hits psum over the index axis
     part = _probe_local(index_table, digests, n_buckets, index_axis)
@@ -71,10 +71,8 @@ def multichip_dedup_step(mesh: Mesh, *, chunk_len: int, n_buckets: int,
     """Build the jitted sharded step.  Returns
     ``step(streams, table, index_table, proj, mask, magic) ->
     (cand_count[B], hits[B], sketches[B, k/32], total_candidates)``."""
-    nb = (chunk_len + 8) // 64 + 1
-    t_max = 1 << (nb - 1).bit_length()
     body = functools.partial(
-        _step_body, chunk_len=chunk_len, t_max=t_max, n_buckets=n_buckets,
+        _step_body, chunk_len=chunk_len, n_buckets=n_buckets,
         data_axis=data_axis, index_axis=index_axis)
     fn = shard_map(
         body, mesh=mesh,

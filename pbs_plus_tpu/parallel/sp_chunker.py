@@ -15,8 +15,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ._compat import shard_map
 
 from ..chunker.spec import WINDOW, ChunkerParams, select_cuts
 from ..ops.rolling_hash import _candidate_mask_impl, device_tables

@@ -72,7 +72,7 @@ from typing import Iterable, Iterator, Sequence
 
 import numpy as np
 
-from ..utils import atomicio, fswitness
+from ..utils import atomicio, fswitness, jaxenv
 from .digestlog import FLAG_DATABLOB as _DATABLOB
 from .digestlog import FLAG_TOMBSTONE as _TOMB
 from .digestlog import MAN_MAGIC as _MAN_MAGIC
@@ -333,11 +333,12 @@ class DedupIndex:
 
     def _probe_arr(self, arr: np.ndarray) -> np.ndarray:
         """Maybe-present bool[N] for uint8[N,32] — numpy host mirror on
-        CPU, the vmap'd device lookup when an accelerator is the
-        default jax backend (the table uploads once per insert batch
-        and is reused across probes)."""
-        if _device_probe_enabled():
-            return np.asarray(self._cuckoo.probe(arr))
+        CPU (no jit dispatch per probe batch), the vmap'd device lookup
+        when an accelerator is the default jax backend (the table
+        uploads once per insert batch and is reused across probes;
+        which of the two is faster there: not measured)."""
+        if jaxenv.pick_twin("index.probe"):
+            return self._cuckoo.probe(arr)
         return self._cuckoo.probe_host(arr)
 
     # -- mutation ----------------------------------------------------------
@@ -701,21 +702,3 @@ class DedupIndex:
             off += _SKETCH_REC.size
             out.append((d, s, dp))
         return out
-
-
-def _device_probe_enabled() -> bool:
-    """True when jax's default backend is a real accelerator — probing
-    through the device table then beats the numpy mirror.  Decided once
-    (backends don't change mid-process); CPU-only hosts never pay a jit
-    dispatch per probe batch."""
-    global _DEVICE_PROBE
-    if _DEVICE_PROBE is None:
-        try:
-            import jax
-            _DEVICE_PROBE = jax.default_backend() != "cpu"
-        except Exception:
-            _DEVICE_PROBE = False
-    return _DEVICE_PROBE
-
-
-_DEVICE_PROBE: "bool | None" = None

@@ -1,7 +1,7 @@
 """Pipelined multi-worker chunk+fingerprint engine (the CPU data plane).
 
-BENCH_r05 put the end-to-end chunk+fingerprint path at ~193 MiB/s on one
-core while the raw buzhash scan alone reaches ~610 MiB/s multithreaded:
+An earlier round's CPU-only driver record put the end-to-end
+chunk+fingerprint path at ~193 MiB/s on one core while the raw buzhash scan alone reaches ~610 MiB/s multithreaded:
 the sequential writer chunks, hashes, and inserts one chunk at a time,
 so SHA-256 and store IO serialize behind the scan.  ``PipelinedStream``
 splits the path into three overlapped stages (the stage-pipelining lever

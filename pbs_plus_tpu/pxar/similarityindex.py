@@ -52,6 +52,7 @@ from typing import Iterable, Sequence
 
 import numpy as np
 
+from ..utils import jaxenv
 from ..utils.log import L
 
 _BANDS = 4
@@ -102,28 +103,6 @@ def metrics_snapshot() -> dict:
     return METRICS.snapshot()
 
 
-def _sketch_backend():
-    """The batched sketch kernel for this host: numpy on CPU, the jax
-    twin when a real accelerator backend is up (decided once, like
-    chunkindex._device_probe_enabled)."""
-    global _SKETCH_FN
-    if _SKETCH_FN is None:
-        from ..ops import similarity as _sim
-        fn = _sim.content_sketch_host
-        try:
-            import jax
-            if jax.default_backend() != "cpu":
-                fn = _sim.content_sketch_device
-        except Exception as e:
-            L.debug("similarity: jax backend probe failed (%s); "
-                    "sketching on the numpy host path", e)
-        _SKETCH_FN = fn
-    return _SKETCH_FN
-
-
-_SKETCH_FN = None
-
-
 class SimilarityIndex:
     """Thread-safe banded sketch index over stored chunks."""
 
@@ -164,7 +143,10 @@ class SimilarityIndex:
     @staticmethod
     def sketch_batch(chunks: Sequence[bytes]) -> np.ndarray:
         """uint64[N] content sketches in one batched kernel call."""
-        return _sketch_backend()(list(chunks))
+        from ..ops import similarity as _sim
+        if jaxenv.pick_twin("similarity.sketch"):
+            return _sim.content_sketch_device(list(chunks))
+        return _sim.content_sketch_host(list(chunks))
 
     def presketch(self, digests: Sequence[bytes], chunks: Sequence[bytes],
                   known: "Sequence[bool] | None") -> int:

@@ -1615,7 +1615,10 @@ class _WorkerProc:
         repo_root = os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))))
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # a worker verifies and probes through jax (models/verify.py,
+        # pxar/chunkindex.py); an accelerator belongs to ONE process,
+        # and that is never a worker of a driver that may hold it
+        env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = repo_root + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
         self.proc = await asyncio.create_subprocess_exec(

@@ -53,6 +53,10 @@ class MountService:
         if fuse:
             argv += ["--mountpoint", mountpoint]
         env = dict(os.environ)
+        # the mount process re-hashes on commit through jax
+        # (mount/commit.py → models/verify.py); an accelerator belongs to
+        # ONE process — this server's — so its child stays on the host
+        env["JAX_PLATFORMS"] = "cpu"
         # the package may be run from a checkout (no site install): make the
         # subprocess resolve it regardless of cwd
         pkg_root = os.path.dirname(os.path.dirname(

@@ -53,13 +53,10 @@ class DedupService:
                  index_buckets: int = 1 << 20, use_tpu: bool | None = None):
         self.params = params or ChunkerParams(avg_size=4 << 20)
         if use_tpu is None:
-            try:
-                from ..utils.jaxdev import ensure_backend
-                ensure_backend()       # never hang on a dead accelerator
-                import jax
-                use_tpu = jax.default_backend() != "cpu"
-            except Exception:
-                use_tpu = False
+            # "auto": the device chunker where jax's backend is an
+            # accelerator; a backend that fails to initialise raises
+            from ..utils import jaxenv
+            use_tpu = jaxenv.on_accelerator()
         self.use_tpu = use_tpu
         self.index = CuckooIndex(n_buckets=index_buckets)
         self.similarity = SimilarityModel()

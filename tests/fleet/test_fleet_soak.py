@@ -246,6 +246,38 @@ def _mixed_assertions(cfg: FleetConfig, rep, d: dict) -> None:
     assert d["queued_max"] <= cfg.max_queued
 
 
+# The mixed soak has been seen to stop for good with its event loop idle
+# in select() and every pool thread parked (an await nothing wakes:
+# ROADMAP D0).  There is no pytest-timeout wheel here, so the test
+# carries its own limit: it FAILS after this long instead of holding its
+# xdist worker — and the tests queued behind it — until the run is cut.
+SOAK_LIMIT_S = 120.0
+
+
+def _run_fleet_bounded(datastore_dir: str, cfg, limit_s: float):
+    """``run_fleet`` on a daemon thread, joined with a time limit.  A
+    soak that outlives the limit fails the test; its thread is left
+    parked where it hung (idle, daemon) rather than waited for."""
+    import threading
+    box: dict = {}
+
+    def work():
+        try:
+            box["rep"] = run_fleet(datastore_dir, cfg)
+        except BaseException as e:     # re-raised on the test's thread
+            box["exc"] = e
+
+    t = threading.Thread(target=work, name="fleet-soak", daemon=True)
+    t.start()
+    t.join(limit_s)
+    if t.is_alive():
+        pytest.fail(f"fleet soak still running after {limit_s:.0f}s "
+                    "(ROADMAP D0: lost wake-up)")
+    if "exc" in box:
+        raise box["exc"]
+    return box["rep"]
+
+
 def test_fleet_soak_mixed_traffic_hostiles(tmp_path):
     """ISSUE 19: the N=100 survival soak — two backup waves per agent
     with keepalive churn, restore + verify + sync lanes concurrent with
@@ -254,7 +286,7 @@ def test_fleet_soak_mixed_traffic_hostiles(tmp_path):
     attacking the same listener.  Every legit job publishes, every
     attack is observed server-side, every bound holds."""
     cfg = _mixed_cfg(100)
-    rep = run_fleet(str(tmp_path / "ds"), cfg)
+    rep = _run_fleet_bounded(str(tmp_path / "ds"), cfg, SOAK_LIMIT_S)
     _mixed_assertions(cfg, rep, rep.to_dict())
 
 

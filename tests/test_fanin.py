@@ -168,3 +168,54 @@ def test_fanin_8_agents_tpu_chunker(tmp_path, monkeypatch):
             "cpu/tpu cut parity broken: cpu run produced new chunks"
 
     asyncio.run(main())
+
+
+def test_failing_device_dispatch_fails_the_job_by_name(tmp_path, monkeypatch):
+    """A ``chunker="tpu"`` session whose device dispatch raises ends its
+    job as FAILED with ``DeviceDispatchError`` named in ``last_error`` —
+    within the wait below (never a hang), and with nothing published
+    (never a quiet run on the host chunker instead)."""
+    import pbs_plus_tpu.models.feeder as feeder_mod
+    from pbs_plus_tpu.chunker import observe
+
+    feeder = feeder_mod.DeviceFeeder(linger_s=0.0)
+    monkeypatch.setattr(feeder_mod, "_feeder", feeder)
+
+    def lost_device(key, group):
+        raise RuntimeError("injected: device lost")
+    monkeypatch.setattr(feeder, "_mask_hits", lost_device)
+
+    async def main():
+        cfg = ServerConfig(
+            state_dir=str(tmp_path / "state"),
+            cert_dir=str(tmp_path / "certs"),
+            datastore_dir=str(tmp_path / "ds"),
+            chunker="tpu", chunk_avg=1 << 16, max_concurrent=2)
+        server = Server(cfg)
+        await server.start()
+        agent, task = await _spawn_agent(server, cfg, tmp_path, "agent-00")
+        try:
+            src = tmp_path / "src"
+            src.mkdir()
+            (src / "data.bin").write_bytes(os.urandom(300_000))
+            server.db.upsert_backup_job(database.BackupJobRow(
+                id="doomed", target="agent-00", source_path=str(src),
+                chunker="tpu"))
+            host_scanned = observe.snapshot()["scan_bytes"]
+            assert server.enqueue_backup("doomed")
+            await server.jobs.wait("backup:doomed", timeout=60)
+            row = server.db.get_backup_job("doomed")
+            assert row.last_status == database.STATUS_ERROR
+            assert "DeviceDispatchError" in row.last_error, row.last_error
+            assert "injected: device lost" in row.last_error
+            assert not row.last_snapshot
+            assert not server.datastore.datastore.list_snapshots(
+                all_namespaces=True)
+            # no chunker, host ones included, scanned a byte for the job
+            assert observe.snapshot()["scan_bytes"] == host_scanned
+        finally:
+            await agent.stop()
+            task.cancel()
+            await server.stop()
+
+    asyncio.run(main())

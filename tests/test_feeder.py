@@ -155,5 +155,80 @@ def test_poisoned_request_does_not_fail_cobatched_streams(wide_feeder):
             f"innocent co-batched stream {i} was failed: {results[i]!r}"
 
 
+def test_failed_hash_round_is_retried_alone_and_counted(wide_feeder):
+    """A poison small enough to share a round with other streams (a str
+    is not a buffer) fails the combined dispatch; every stream is then
+    hashed alone, the innocents succeed, and the feeder says it had to —
+    a batch path broken on a device would otherwise show only as a slow
+    run."""
+    n_good = 3
+    goods = [[_data(1000 + i, seed=800 + i)] for i in range(n_good)]
+    results: dict = {}
+    barrier = threading.Barrier(n_good + 1)
+
+    def work(key, chunks):
+        barrier.wait()
+        try:
+            results[key] = wide_feeder.sha256_batch(chunks)
+        except BaseException as e:
+            results[key] = e
+    threads = [threading.Thread(target=work, args=(i, goods[i]))
+               for i in range(n_good)]
+    threads.append(threading.Thread(target=work, args=("bad", ["not bytes"])))
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(60)
+        assert not t.is_alive()
+    assert isinstance(results["bad"], TypeError), results["bad"]
+    for i in range(n_good):
+        assert results[i] == [hashlib.sha256(goods[i][0]).digest()]
+    assert wide_feeder.stats["sha_retried_alone"] >= 2, wide_feeder.stats
+    assert wide_feeder.stats["mask_retried_alone"] == 0
+
+
+def test_failed_scan_batch_is_retried_alone_and_counted(wide_feeder,
+                                                        monkeypatch):
+    """A batched scan dispatch that raises (a compile error, an HBM
+    overflow) is retried request by request — every stream still gets
+    its own hits — and each retry is counted; a request that fails alone
+    too gets the exception, wrapped by name at the session."""
+    from pbs_plus_tpu.models.dedup import DeviceDispatchError
+    real = wide_feeder._mask_hits
+
+    def batches_fail(key, group):
+        if len(group) > 1:
+            raise MemoryError("injected: batch does not fit")
+        return real(key, group)
+    monkeypatch.setattr(wide_feeder, "_mask_hits", batches_fail)
+    n = 4
+    datas = [_data(30_000, seed=700 + i) for i in range(n)]
+    cuts: dict[int, object] = {}
+    barrier = threading.Barrier(n)
+
+    def work(i):
+        barrier.wait()
+        ch = TpuChunker(P)
+        cuts[i] = ch.feed(datas[i]) + ch.finalize()
+    threads = [threading.Thread(target=work, args=(i,)) for i in range(n)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(60)
+        assert not t.is_alive()
+    for i in range(n):
+        cpu = CpuChunker(P)
+        assert cuts[i] == cpu.feed(datas[i]) + cpu.finalize()
+    assert wide_feeder.stats["mask_retried_alone"] >= 2, wide_feeder.stats
+    assert wide_feeder.stats["max_mask_batch"] <= 1     # no batch landed
+
+    def all_fail(key, group):
+        raise MemoryError("injected: device lost")
+    monkeypatch.setattr(wide_feeder, "_mask_hits", all_fail)
+    with pytest.raises(DeviceDispatchError, match="candidate scan failed: "
+                                                  "MemoryError: injected"):
+        TpuChunker(P).feed(datas[0])
+
+
 def test_empty_sha_batch_is_noop(wide_feeder):
     assert wide_feeder.sha256_batch([]) == []

@@ -52,23 +52,55 @@ def test_candidate_mask_with_history():
     assert np.array_equal(got[1], whole[half:])
 
 
-def test_pallas_kernel_matches_cpu():
-    """The fused Pallas rolling-hash kernel (interpret mode on CPU) is
-    bit-identical to the CPU chunker's candidate set."""
-    from pbs_plus_tpu.ops.pallas_rolling_hash import candidate_mask_pallas
-    data = np.frombuffer(_data(50_000, seed=21), dtype=np.uint8)
-    got_mask = np.asarray(candidate_mask_pallas(jnp.asarray(data), P))
-    got = (np.nonzero(got_mask)[0] + 1).astype(np.int64)
-    want = candidates(data, P, force_numpy=True)
-    assert np.array_equal(got, want)
-    # batched form + tile-boundary coverage (stream > several tiles)
-    data2 = np.frombuffer(_data(40_000, seed=22), dtype=np.uint8)
-    batch = np.stack([data[:40_000], data2])
-    bm = np.asarray(candidate_mask_pallas(jnp.asarray(batch), P))
-    for i, row in enumerate(batch):
-        want_i = candidates(row, P, force_numpy=True)
-        got_i = (np.nonzero(bm[i])[0] + 1).astype(np.int64)
-        assert np.array_equal(got_i, want_i), i
+def test_scan_budget_split_changes_no_hit(monkeypatch):
+    """A request the device budget cannot take at once goes out as
+    several dispatches; every row's hits equal the unsplit run's."""
+    from pbs_plus_tpu.ops import rolling_hash as rh
+    rng = np.random.default_rng(31)
+    bufs = [rng.integers(0, 256, n, dtype=np.uint8)
+            for n in (70_000, 5_000, 262_144, 100, 131_072, 65_536, 9)]
+    hists = [None, bufs[0][-63:], None, None, bufs[2][-63:], None, None]
+    table = device_tables(P)
+    d0 = rh.stats["dispatches"]
+    whole = rh.batched_candidate_hits(bufs, hists, table, P)
+    assert rh.stats["dispatches"] == d0 + 1
+    # room for ONE row of the 256 KiB class on each device: the eight
+    # virtual devices' mesh takes eight, the largest row class under
+    # that is four
+    import jax
+    monkeypatch.setattr(rh, "scan_budget_bytes",
+                        lambda: (256 << 10) * rh._SCAN_BYTES_PER_BYTE)
+    assert rh.dispatch_rows(256 << 10) == 1
+    assert rh.dispatch_rows(256 << 10, len(jax.devices())) == 4
+    split = rh.batched_candidate_hits(bufs, hists, table, P)
+    assert rh.stats["dispatches"] == d0 + 3            # 7 rows, 4 + 3
+    assert all(np.array_equal(a, b) for a, b in zip(whole, split))
+    # a segment the budget cannot take even alone is refused, by name
+    monkeypatch.setattr(rh, "scan_budget_bytes", lambda: 1 << 20)
+    with pytest.raises(ValueError, match="does not fit the device budget"):
+        rh.batched_candidate_hits(bufs, hists, table, P)
+
+
+def test_scan_shapes_come_from_two_short_lists(monkeypatch):
+    """Whatever the row count and segment lengths, the batched scan
+    compiles for (row class, segment class) pairs only."""
+    from pbs_plus_tpu.ops import rolling_hash as rh
+    rng = np.random.default_rng(32)
+    table = device_tables(P)
+    seen = set()
+    real = rh.candidate_mask
+
+    def spy(dbuf, *a, **kw):
+        seen.add(tuple(dbuf.shape))
+        return real(dbuf, *a, **kw)
+    monkeypatch.setattr(rh, "candidate_mask", spy)
+    for rows, n in ((1, 1), (1, 65_537), (2, 300), (3, 70_000),
+                    (5, 65_536), (9, 12), (17, 1_000)):
+        bufs = [rng.integers(0, 256, n, dtype=np.uint8)] * rows
+        rh.batched_candidate_hits(bufs, [None] * rows, table, P)
+    # single rows stay local; batches pad to the 8 virtual devices' mesh
+    assert seen <= {(b, s) for b in (1, 8, 16, 24, 64)
+                    for s in (1 << 16, 1 << 18)}, seen
 
 
 def test_device_cuts_match_cpu_cuts():
@@ -93,6 +125,30 @@ def test_sha256_stream_bounds():
     got = sha256_stream_chunks(data, bounds)
     want = [hashlib.sha256(data[s:e]).digest() for s, e in bounds]
     assert got == want
+
+
+def test_sha256_packs_by_class_and_keeps_order(monkeypatch):
+    """Chunks fill staging buffers up to SLAB_BYTES and the largest row
+    class; digests come back in input order whatever the packing, and
+    the compiled program's shapes come from the two class lists only."""
+    from pbs_plus_tpu.ops import sha256 as sha
+    monkeypatch.setattr(sha, "SLAB_BYTES", 100_000)
+    monkeypatch.setattr(sha, "_ROW_CLASSES", (8, 16))
+    shapes = set()
+    real = sha._sha256_scan
+
+    def spy(ds, dbs, dbl, n_blocks, **kw):
+        shapes.add((ds.shape[0], dbs.shape[0]))
+        return real(ds, dbs, dbl, n_blocks, **kw)
+    monkeypatch.setattr(sha, "_sha256_scan", spy)
+    sizes = [40_000, 0, 70_000, 1, 64, 30_000] + [200] * 40 + [150_000, 5]
+    chunks = [_data(n, seed=500 + i) for i, n in enumerate(sizes)]
+    d0 = sha._dispatch_count
+    assert sha256_chunks(chunks) == [hashlib.sha256(c).digest()
+                                     for c in chunks]
+    assert sha._dispatch_count > d0 + 3        # several buffers were needed
+    assert {s for s, _ in shapes} <= set(sha._SLAB_CLASSES)
+    assert {r for _, r in shapes} <= {8, 16}
 
 
 def test_sha256_rejects_oversized():
@@ -290,6 +346,22 @@ def test_content_sketch_tracks_similarity():
     assert near <= 10
     assert far >= 18
     assert sketch_hamming(s[0], s[0]) == 0
+
+
+def test_cuckoo_probe_compiles_for_a_handful_of_batch_sizes():
+    """The probe pads its batch to a power of four (64 at least): probing
+    every N from 1 to 300 asks for three programs, not three hundred."""
+    from pbs_plus_tpu.ops import cuckoo
+    idx = CuckooIndex(n_buckets=1 << 10)
+    rng = np.random.default_rng(33)
+    digs = rng.integers(0, 256, (300, 32), dtype=np.uint8)
+    idx.insert_many([d.tobytes() for d in digs[:150]])
+    before = cuckoo._lookup._cache_size()
+    for n in range(1, 301):
+        got = idx.probe(digs[:n])
+        assert got.shape == (n,) and got[:min(n, 150)].all()
+        assert np.array_equal(got, idx.probe_host(digs[:n]))
+    assert cuckoo._lookup._cache_size() - before <= 3
 
 
 def test_sha256_unroll_parity():

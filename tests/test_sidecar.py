@@ -134,3 +134,24 @@ def test_sidecar_chunker_in_writer(sidecar, tmp_path):
     # chunk boundaries identical to the local CPU chunker
     want_n = len(chunk_bounds(data, P))
     assert len(list(r.payload_index.records())) == want_n
+
+
+def test_auto_backend_probe_failure_is_raised(monkeypatch):
+    """``--tpu auto`` asks jax for its backend; an exception there is the
+    caller's — it used to become ``use_tpu = False`` in silence."""
+    import jax
+
+    from pbs_plus_tpu.sidecar.service import DedupService
+    from pbs_plus_tpu.utils import jaxenv
+
+    def broken():
+        raise RuntimeError("injected: backend init failed")
+    jaxenv.on_accelerator.cache_clear()
+    monkeypatch.setattr(jax, "default_backend", broken)
+    try:
+        with pytest.raises(RuntimeError, match="backend init failed"):
+            DedupService(use_tpu=None)
+    finally:
+        monkeypatch.undo()
+        jaxenv.on_accelerator.cache_clear()
+    assert DedupService(use_tpu=None).use_tpu is False    # CPU backend

@@ -1,0 +1,164 @@
+"""The device kernels, compiled for a described TPU v5e at the widths
+``chip_smoke.py`` runs them at (on-chip-measurement guide, section 2,
+rehearsal 3).  Nothing runs: these tests ask the chip's own compiler,
+which is installed here, whether it takes each program and how much
+device memory it plans — what it refuses here costs no chip time.
+
+The tests' backend stays the CPU, so code that picks by
+``jax.default_backend()`` would take its CPU branch: the TPU branch of
+SHA-256 is steered in by monkeypatch, in the test, never by an option of
+the program.
+
+Everything that touches the topology is inside fixtures and tests: only
+one process at a time may load the TPU's library, and every xdist worker
+imports this file.  For the same reason these tests live in ONE file.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from pbs_plus_tpu.ops import cuckoo, fingerprint, rolling_hash, sha256
+from pbs_plus_tpu.ops import similarity
+
+MIB = 1 << 20
+# what the chip's compiler reports for one v5e ("Used 16.00G of 15.75G hbm")
+V5E_HBM_BYTES = int(15.75 * (1 << 30))
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def shape(topo):
+    """``shape((rows, cols), dtype)`` → a ShapeDtypeStruct placed on the
+    described topology's first chip."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    return lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype,
+                                                    sharding=one_chip)
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    """A program compiled for a described chip is written to the
+    persistent cache but cannot be read back without the chip: the next
+    run would warn and compile again.  Off around this file."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes)
+
+
+def compile_scan(shape, rows: int, seg: int):
+    return rolling_hash._candidate_mask_jit.lower(
+        shape((rows, seg), jnp.uint8), shape((2, 16), jnp.uint32),
+        shape((), jnp.uint32), shape((), jnp.uint32),
+        shape((rows, 63), jnp.uint8)).compile()
+
+
+@pytest.fixture
+def v5e_budget(monkeypatch):
+    """The scan's memory budget as the program derives it on one v5e
+    (here ``memory_stats()`` is the CPU's, which reports no limit)."""
+    budget = V5E_HBM_BYTES // rolling_hash._SCAN_MEMORY_SHARE
+    monkeypatch.setattr(rolling_hash, "scan_budget_bytes", lambda: budget)
+    return budget
+
+
+def test_scan_compiles_at_the_feeders_largest_batch(shape, v5e_budget):
+    """64 concurrent 4 MiB pages: the widest dispatch the feeder forms."""
+    rows = rolling_hash.dispatch_rows(4 * MIB)
+    assert rows == rolling_hash._ROW_CLASSES[-1] == 64
+    assert device_bytes(compile_scan(shape, rows, 4 * MIB)) <= v5e_budget
+
+
+def test_scan_budget_splits_what_the_chip_refuses(shape, v5e_budget):
+    """DedupPipeline's old default, 32 rows of 64 MiB in one dispatch,
+    is refused by the chip's compiler; the budget splits the same request
+    into dispatches that each compile and fit."""
+    with pytest.raises(Exception, match="RESOURCE_EXHAUSTED"):
+        compile_scan(shape, 32, 64 * MIB)
+    rows = rolling_hash.dispatch_rows(64 * MIB)
+    assert 1 <= rows < 32 and rows in rolling_hash._ROW_CLASSES
+    assert device_bytes(compile_scan(shape, rows, 64 * MIB)) <= v5e_budget
+    # the longest segment class the budget admits at all, one row
+    assert rolling_hash.dispatch_rows(256 * MIB) == 1
+    assert device_bytes(compile_scan(shape, 1, 256 * MIB)) <= v5e_budget
+    with pytest.raises(ValueError, match="does not fit"):
+        rolling_hash.dispatch_rows(1 << 30)
+
+
+def test_sha256_tpu_branch_compiles_once_for_every_length(shape, monkeypatch):
+    """The branch no CPU test runs: all 64 rounds unrolled, 16 blocks a
+    step.  Compiled ONCE, as the program does since the trip count became
+    a run-time argument: one staging-buffer class and one row class serve
+    every chunk length (this is the program chip_smoke's 4 MiB chunks
+    use most).  About 70 s here."""
+    monkeypatch.setattr(sha256, "_compress", sha256._compress_unrolled)
+    slab = sha256._class_for(sha256.SLAB_BYTES, sha256._SLAB_CLASSES)
+    compiled = sha256._sha256_scan.lower(
+        shape((slab,), jnp.uint8), shape((8,), jnp.int32),
+        shape((8,), jnp.int32), shape((), jnp.int32), unroll=16).compile()
+    assert "while" in compiled.as_text()       # run-time trip count
+    assert device_bytes(compiled) < 2 * slab
+
+
+def test_cuckoo_lookup_compiles_at_the_default_table(shape):
+    """1 << 20 buckets (DedupConfig's default), 64k digests a probe."""
+    compiled = cuckoo._lookup.lower(
+        shape((1 << 20, cuckoo.SLOTS, 2), jnp.uint32),
+        shape((1 << 16, 32), jnp.uint8)).compile()
+    assert device_bytes(compiled) < 64 * MIB
+
+
+def test_simhash_projection_compiles(shape):
+    compiled = similarity._simhash.lower(
+        shape((1 << 16, 32), jnp.uint8), shape((256, 64), jnp.float32),
+        k=64).compile()
+    assert device_bytes(compiled) < 64 * MIB
+
+
+# -- kernels chip_smoke does not reach (off by default or test-only):
+#    compiled at modest widths so a lowering the chip refuses shows here ----
+
+def test_minhash_compiles(shape):
+    similarity._minhash.lower(
+        shape((1 << 16, 32), jnp.uint8), shape((128,), jnp.uint32),
+        shape((128,), jnp.uint32), k=128).compile()
+
+
+def test_pairwise_hamming_compiles(shape):
+    similarity.pairwise_hamming.lower(
+        shape((4096, 2), jnp.uint32), shape((4096, 2), jnp.uint32)).compile()
+
+
+def test_content_sketch_compiles(shape):
+    """The similarity tier's device twin (delta tier, off by default).
+    Its jit key is (chunks, longest chunk) — unbounded, and about 14 s a
+    compile even at 64 KiB: ROADMAP has it to settle before that tier is
+    switched on where there is a chip."""
+    similarity._content_sketch_words.lower(
+        shape((8, 64 << 10), jnp.uint8), shape((8,), jnp.int32)).compile()
+
+
+def test_fold_fingerprint_compiles(shape):
+    jax.jit(fingerprint.fold_fingerprint, static_argnames=("t_max",)).lower(
+        shape((16 * MIB,), jnp.uint8), shape((64,), jnp.int32),
+        shape((64,), jnp.int32), t_max=1024).compile()

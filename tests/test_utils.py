@@ -131,3 +131,63 @@ def test_rotating_log_file(tmp_path):
         assert rec["level"] == "INFO" and "rotation line" in rec["msg"]
     finally:
         remove_rotating_file(h)
+
+
+# --- jaxenv: compile-cache placement and the host/device twin switch ------
+
+def test_compile_cache_follows_the_environment(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: the helper leaves everything to jax
+    (which reads the variable itself) and sets no other place in code."""
+    import jax
+
+    from pbs_plus_tpu.utils import jaxenv
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert jaxenv.configure_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_one_fixed_place(monkeypatch):
+    """Unset: one fixed directory inside the checkout — never a tmpdir, a
+    pid or a timestamp (the path is part of what a cache hit needs) — set
+    in jax AND in the environment children inherit."""
+    import os
+
+    import jax
+
+    from pbs_plus_tpu.utils import jaxenv
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert jaxenv.configure_compile_cache() == jaxenv.CACHE_DIR \
+        == os.path.join(repo, ".jax_cache")
+    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == jaxenv.CACHE_DIR
+    assert jax.config.jax_compilation_cache_dir == jaxenv.CACHE_DIR
+
+
+def test_pick_twin_counts_and_never_swallows(monkeypatch):
+    """On the CPU backend every twin runs its host side and says so; a
+    backend jax cannot initialise is the caller's exception — not a
+    quiet host run, and not remembered as a decision."""
+    import jax
+
+    from pbs_plus_tpu.utils import jaxenv
+    jaxenv.on_accelerator.cache_clear()
+    monkeypatch.setattr(jaxenv, "twin_counts", {})
+    try:
+        assert jaxenv.pick_twin("t") is False
+        assert jaxenv.pick_twin("t") is False
+        assert jaxenv.twin_counts == {"t": {"device": 0, "host": 2}}
+
+        def broken():
+            raise RuntimeError("injected: no backend")
+        jaxenv.on_accelerator.cache_clear()
+        monkeypatch.setattr(jax, "default_backend", broken)
+        with pytest.raises(RuntimeError, match="no backend"):
+            jaxenv.pick_twin("t")
+        assert jaxenv.twin_counts["t"] == {"device": 0, "host": 2}
+        monkeypatch.undo()
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert jaxenv.pick_twin("u") is True      # the failure did not stick
+    finally:
+        monkeypatch.undo()
+        jaxenv.on_accelerator.cache_clear()

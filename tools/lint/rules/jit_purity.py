@@ -6,7 +6,7 @@ random) silently freeze into constants; host syncs (``.item()``,
 ``np.asarray`` on traced values) either crash or force a device
 round-trip per call; ``global``/``nonlocal`` writes disappear on the
 second call.  The ops/ kernels (cuckoo, rolling_hash, sha256,
-similarity, pallas) are the dedup fingerprint path — an impure kernel
+similarity) are the dedup fingerprint path — an impure kernel
 corrupts dedup ratios in ways parity tests can't always see (cf. CDC
 drift, PAPERS.md).
 """
