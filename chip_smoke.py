@@ -152,7 +152,7 @@ def phase_kernels(seed: int, compiles: Compiles) -> None:
     # ones alone (the smallest staging buffer: a session's last flush),
     # then thirteen of one length (the 64-row class), so every program
     # the main path uses is checked here and compiled before phase 2
-    d0 = sha._dispatch_count
+    d0 = sha.stats["dispatches"]
     chunks = [rng.bytes(n) for n in
               (0, 55, 56, 64, 1 * MIB, 4 * MIB, 16 * MIB)]
     many = [rng.bytes(3 * MIB // 2) for _ in range(13)]
@@ -173,7 +173,7 @@ def phase_kernels(seed: int, compiles: Compiles) -> None:
     say(phase="kernels", seconds=round(time.monotonic() - t0, 1),
         scan_bytes=int(row.size), scan_candidates=n_cand,
         sha_lengths=[len(c) for c in chunks],
-        sha_dispatches=sha._dispatch_count - d0,
+        sha_dispatches=sha.stats["dispatches"] - d0,
         cuckoo_probes=int(len(digs)), cuckoo_hits=int(dev.sum()),
         peak_bytes_in_use=peak_bytes(), **compiles.since(c0))
 
@@ -334,19 +334,16 @@ def compare_with_reference(tpu: dict, ref: dict) -> dict:
 
 
 def device_counters() -> dict:
-    from pbs_plus_tpu.models.dedup import TpuChunker
     from pbs_plus_tpu.models.feeder import get_feeder
     from pbs_plus_tpu.ops import rolling_hash, sha256
-    return {"tpu_chunker_dispatches": TpuChunker.device_dispatches,
-            "sha_dispatches": sha256._dispatch_count,
-            "feeder": dict(get_feeder().stats),
+    return {"feeder": dict(get_feeder().stats),
             "scan": dict(rolling_hash.stats), "sha": dict(sha256.stats)}
 
 
 def assert_device_did_the_work(before: dict, now: dict) -> None:
-    if now["tpu_chunker_dispatches"] <= before["tpu_chunker_dispatches"]:
+    if now["scan"]["dispatches"] <= before["scan"]["dispatches"]:
         raise AssertionError("TpuChunker never dispatched")
-    if now["sha_dispatches"] <= before["sha_dispatches"]:
+    if now["sha"]["dispatches"] <= before["sha"]["dispatches"]:
         raise AssertionError("the batched sha path never dispatched")
     feeder = now["feeder"]
     if feeder["max_mask_batch"] <= 1:

@@ -192,9 +192,6 @@ class TpuChunker:
     streams' feeds into ``[B, S]`` batched dispatches (the production
     batch axis — models/feeder.py)."""
 
-    # device-dispatch counter across all instances: integration tests
-    # assert the TPU path actually ran when chunker="tpu" is configured
-    device_dispatches = 0
     # per-session bound-backend label (transfer._ChunkedStream picks it
     # up at bind time; rendered in job stats and /metrics)
     backend_name = "tpu"
@@ -210,7 +207,6 @@ class TpuChunker:
 
     def _candidates(self, data: np.ndarray) -> np.ndarray:
         from .feeder import get_feeder
-        TpuChunker.device_dispatches += 1
         try:
             hits = get_feeder().candidate_hits(data, self._tail, self.params)
         except Exception as e:

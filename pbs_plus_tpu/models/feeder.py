@@ -37,44 +37,66 @@ independently by the kernel (per-row history, per-row mask slice), so
 results are bit-identical to the ``[1, S]`` dispatches they replace —
 pinned by tests/test_fanin.py (digest parity with the CPU backend) and
 tests/test_feeder.py (direct batched-vs-solo equality).
+
+The thread's own time (docs/observability.md "The batcher"): every
+second of the feeder thread's life goes to one of four clocks in
+``stats`` — ``mask_busy_s`` / ``sha_busy_s`` inside a dispatch,
+``idle_s`` waiting with both queues empty, ``linger_s`` widening a batch
+— and every request adds its wait from submit to the start of its
+dispatch to ``mask_wait_s`` / ``sha_wait_s``.  Each mask group and hash
+round is one ``feeder.dispatch`` span, parent of the op's ``device.*``
+span, naming the writers' spans it served (``links``).
 """
 
 from __future__ import annotations
 
 import os
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Optional
 
 import numpy as np
 
 from ..chunker.spec import ChunkerParams
+from ..utils import trace
 from ..utils.log import L
 
 # combined SHA round cap: bounds the host copy one feeder round packs
 # (ops/sha256.py splits it into staging buffers) when many writers flush
 # hash batches at once
 _SHA_BATCH_BYTES_CAP = 256 << 20
+# submitters' contexts a ``feeder.dispatch`` span names at most
+_MAX_LINKS = 64
 
 
 @dataclass
-class _MaskReq:
+class _Req:
+    """What both kinds of request carry from the writer's thread: when
+    it was submitted, and the span it was submitted under."""
+    done: threading.Event = field(default_factory=threading.Event,
+                                  kw_only=True)
+    exc: Optional[BaseException] = field(default=None, kw_only=True)
+    submitted: float = field(default_factory=time.perf_counter,
+                             kw_only=True)
+    ctx: Optional[tuple] = field(default_factory=trace.capture,
+                                 kw_only=True)
+
+
+@dataclass
+class _MaskReq(_Req):
     buf: np.ndarray                 # uint8[S], S > 0
     history: np.ndarray             # uint8[WINDOW-1]
     key: tuple                      # (seed, mask, magic) — batch group key
     params: ChunkerParams
-    done: threading.Event = field(default_factory=threading.Event)
     hits: Optional[np.ndarray] = None    # relative candidate end indices
-    exc: Optional[BaseException] = None
 
 
 @dataclass
-class _ShaReq:
+class _ShaReq(_Req):
     chunks: list                    # list[bytes]
     nbytes: int
-    done: threading.Event = field(default_factory=threading.Event)
     digests: Optional[list] = None
-    exc: Optional[BaseException] = None
 
 
 class DeviceFeeder:
@@ -94,7 +116,13 @@ class DeviceFeeder:
         self.stats = {"mask_dispatches": 0, "mask_rows": 0,
                       "max_mask_batch": 0, "mask_retried_alone": 0,
                       "sha_dispatches": 0, "sha_streams": 0,
-                      "max_sha_streams": 0, "sha_retried_alone": 0}
+                      "max_sha_streams": 0, "sha_retried_alone": 0,
+                      # the thread's life, partitioned (module docstring)
+                      "mask_busy_s": 0.0, "sha_busy_s": 0.0,
+                      "idle_s": 0.0, "linger_s": 0.0, "rounds": 0,
+                      # requests' waits from submit to their dispatch
+                      "mask_wait_s": 0.0, "sha_wait_s": 0.0}
+        self._clock = 0.0       # the feeder thread's: start of its state
 
     # -- public API (writer threads) --------------------------------------
     def candidate_hits(self, buf: np.ndarray, history: np.ndarray,
@@ -133,16 +161,27 @@ class DeviceFeeder:
                 self._thread.start()
             self._cv.notify_all()
 
+    def _spent(self, state: str) -> None:
+        """The feeder thread's time since the last call goes to
+        ``state``: the four clocks partition the thread's life."""
+        now = time.perf_counter()
+        self.stats[state] += now - self._clock
+        self._clock = now
+
     def _run(self) -> None:
+        trace.name_os_thread("device-feeder")   # its line in a profile
+        self._clock = time.perf_counter()
         while True:
             with self._cv:
                 while not self._mask_q and not self._sha_q:
                     self._cv.wait()
+                self._spent("idle_s")
                 # adaptive widening: if only one request is pending, give
                 # concurrent writers a linger window to join the batch
                 if (self.linger_s > 0
                         and len(self._mask_q) + len(self._sha_q) == 1):
                     self._cv.wait(self.linger_s)
+                    self._spent("linger_s")
                 # drain IN PLACE — the queue list objects are permanent.
                 # (_submit callers capture the list reference outside the
                 # lock at argument-evaluation time; rebinding here would
@@ -153,11 +192,14 @@ class DeviceFeeder:
             # belt over the per-dispatch isolation: NOTHING may kill this
             # thread while drained requests are unserved — waiters block
             # with no timeout, so a lost request is a permanent deadlock
+            self.stats["rounds"] += 1
             try:
                 if mask_reqs:
                     self._dispatch_masks(mask_reqs)
+                    self._spent("mask_busy_s")
                 if sha_reqs:
                     self._dispatch_sha(sha_reqs)
+                    self._spent("sha_busy_s")
             except BaseException as e:
                 for r in mask_reqs + sha_reqs:
                     if not r.done.is_set():
@@ -203,34 +245,50 @@ class DeviceFeeder:
         self.stats["mask_rows"] += len(group)
         return hits
 
+    def _begin(self, kind: str, reqs: list) -> dict:
+        """A dispatch begins: the requests' queue waits end here.
+        Returns the ``feeder.dispatch`` span's attrs."""
+        now = time.perf_counter()
+        key = "mask_wait_s" if kind == "scan" else "sha_wait_s"
+        for r in reqs:
+            self.stats[key] += now - r.submitted
+            trace.record("feeder.queue_wait", now - r.submitted, kind=kind)
+        return {"kind": kind, "reqs": len(reqs), "retried": 0,
+                "links": [r.ctx for r in reqs[:_MAX_LINKS]
+                          if r.ctx is not None]}
+
     def _dispatch_mask_group(self, key: tuple, group: list[_MaskReq]) -> None:
-        try:
-            hits = self._mask_hits(key, group)
-            self.stats["max_mask_batch"] = max(self.stats["max_mask_batch"],
-                                               len(group))
-            for r, h in zip(group, hits):
-                r.hits = h
-                r.done.set()
-        except BaseException as batch_exc:
-            if len(group) == 1:
-                group[0].exc = batch_exc
-                group[0].done.set()
-                return
-            # failure isolation: retry each stream's request alone so a
-            # poisoned input fails only its owner, never the unrelated
-            # jobs co-batched with it.  Counted: a batch path that is
-            # broken on this device would otherwise show only as a slow
-            # run (every request succeeding alone).
-            L.warning("device scan batch of %d failed (%s: %s); retrying "
-                      "each request alone", len(group),
-                      type(batch_exc).__name__, batch_exc)
-            for r in group:
-                self.stats["mask_retried_alone"] += 1
-                try:
-                    r.hits = self._mask_hits(key, [r])[0]
-                except BaseException as e:
-                    r.exc = e
-                r.done.set()
+        with trace.span("feeder.dispatch",
+                        **self._begin("scan", group)) as sp, \
+                trace.annotation("feeder.dispatch"):
+            try:
+                hits = self._mask_hits(key, group)
+                self.stats["max_mask_batch"] = max(
+                    self.stats["max_mask_batch"], len(group))
+                for r, h in zip(group, hits):
+                    r.hits = h
+                    r.done.set()
+            except BaseException as batch_exc:
+                if len(group) == 1:
+                    group[0].exc = batch_exc
+                    group[0].done.set()
+                    return
+                # failure isolation: retry each stream's request alone so
+                # a poisoned input fails only its owner, never the
+                # unrelated jobs co-batched with it.  Counted: a batch
+                # path that is broken on this device would otherwise show
+                # only as a slow run (every request succeeding alone).
+                L.warning("device scan batch of %d failed (%s: %s); "
+                          "retrying each request alone", len(group),
+                          type(batch_exc).__name__, batch_exc)
+                sp.set(retried=len(group))
+                for r in group:
+                    self.stats["mask_retried_alone"] += 1
+                    try:
+                        r.hits = self._mask_hits(key, [r])[0]
+                    except BaseException as e:
+                        r.exc = e
+                    r.done.set()
 
     def _sha_digests(self, reqs: list[_ShaReq]) -> list:
         from ..ops.sha256 import sha256_chunks
@@ -240,31 +298,36 @@ class DeviceFeeder:
         return digests
 
     def _dispatch_sha(self, reqs: list[_ShaReq]) -> None:
-        try:
-            digests = self._sha_digests(reqs)
-            self.stats["max_sha_streams"] = max(self.stats["max_sha_streams"],
-                                                len(reqs))
-            off = 0
-            for r in reqs:
-                r.digests = digests[off:off + len(r.chunks)]
-                off += len(r.chunks)
-                r.done.set()
-        except BaseException as batch_exc:
-            if len(reqs) == 1:
-                reqs[0].exc = batch_exc
-                reqs[0].done.set()
-                return
-            # same isolation contract (and the same count) as the mask path
-            L.warning("device hash batch of %d streams failed (%s: %s); "
-                      "retrying each alone", len(reqs),
-                      type(batch_exc).__name__, batch_exc)
-            for r in reqs:
-                self.stats["sha_retried_alone"] += 1
-                try:
-                    r.digests = self._sha_digests([r])
-                except BaseException as e:
-                    r.exc = e
-                r.done.set()
+        with trace.span("feeder.dispatch",
+                        **self._begin("sha", reqs)) as sp, \
+                trace.annotation("feeder.dispatch"):
+            try:
+                digests = self._sha_digests(reqs)
+                self.stats["max_sha_streams"] = max(
+                    self.stats["max_sha_streams"], len(reqs))
+                off = 0
+                for r in reqs:
+                    r.digests = digests[off:off + len(r.chunks)]
+                    off += len(r.chunks)
+                    r.done.set()
+            except BaseException as batch_exc:
+                if len(reqs) == 1:
+                    reqs[0].exc = batch_exc
+                    reqs[0].done.set()
+                    return
+                # same isolation contract (and the same count) as the
+                # mask path
+                L.warning("device hash batch of %d streams failed (%s: "
+                          "%s); retrying each alone", len(reqs),
+                          type(batch_exc).__name__, batch_exc)
+                sp.set(retried=len(reqs))
+                for r in reqs:
+                    self.stats["sha_retried_alone"] += 1
+                    try:
+                        r.digests = self._sha_digests([r])
+                    except BaseException as e:
+                        r.exc = e
+                    r.done.set()
 
 
 _feeder: Optional[DeviceFeeder] = None
@@ -277,4 +340,6 @@ def get_feeder() -> DeviceFeeder:
         with _feeder_lock:
             if _feeder is None:
                 _feeder = DeviceFeeder()
+                # the process-wide one is what /metrics renders
+                trace.DEVICE_STATS["feeder"] = _feeder.stats
     return _feeder

@@ -20,10 +20,15 @@ upload is skipped.
 from __future__ import annotations
 
 import functools
+import threading
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..utils import jaxenv, trace
+
+jaxenv.watch_compiles()
 
 SLOTS = 4
 _MIX = np.uint32(0x9E3779B1)
@@ -31,6 +36,18 @@ _MAX_KICKS = 500
 BUCKET_BYTES = SLOTS * 2 * 4        # uint32[SLOTS, 2] per bucket
 # padded digest counts of a device probe: 64, 256, 1024, … (powers of four)
 _PROBE_CLASSES = tuple(1 << k for k in range(6, 31, 2))
+
+
+# device probes, mirror of rolling_hash.stats: ``probes`` digests asked in
+# ``dispatches`` lookups (``bytes`` of digests, ``padded_bytes`` after
+# padding to a probe class), the table copied to the device for a probe
+# ``table_uploads`` times — whole, after any insert — and the five phase
+# clocks; the table's upload is inside ``h2d_s``.  Probes run on the
+# writers' threads, several at once: a trip adds to them under the lock.
+stats = trace.device_stats("probe", {
+    "dispatches": 0, "probes": 0, "bytes": 0, "padded_bytes": 0,
+    "table_uploads": 0, "table_upload_bytes": 0})
+_stats_lock = threading.Lock()
 
 
 def buckets_for_bytes(budget_bytes: int, *, minimum: int = 1 << 10) -> int:
@@ -426,13 +443,30 @@ class CuckooIndex:
         contains_exact on hits if false positives matter).  The batch is
         padded on the host to a probe class, so the lookup compiles for
         a handful of batch sizes and not for every N."""
-        arr = np.asarray(digests, dtype=np.uint8)
-        n = arr.shape[0]
-        n_pad = next(c for c in _PROBE_CLASSES if c >= n)
-        padded = np.zeros((n_pad, 32), dtype=np.uint8)
-        padded[:n] = arr
-        return np.asarray(_lookup(self.device_table(),
-                                  jnp.asarray(padded)))[:n]
+        with trace.round_trip("device.probe", stats,
+                              lock=_stats_lock) as rt:
+            with rt.phase("pack"):
+                arr = np.asarray(digests, dtype=np.uint8)
+                n = arr.shape[0]
+                n_pad = next(c for c in _PROBE_CLASSES if c >= n)
+                padded = np.zeros((n_pad, 32), dtype=np.uint8)
+                padded[:n] = arr
+            rt.shape = f"rows={n_pad} buckets={self.n_buckets}"
+            with rt.phase("h2d"):
+                if self._dirty or self._device_table is None:
+                    # the table too, whole, after any insert
+                    rt.add(table_uploads=1,
+                           table_upload_bytes=self._table.nbytes)
+                table, dd = self.device_table(), jnp.asarray(padded)
+                jax.block_until_ready((table, dd))
+            rt.add(dispatches=1, probes=n, bytes=arr.nbytes,
+                   padded_bytes=padded.nbytes)
+            with rt.phase("device"):
+                dhit = _lookup(table, dd).block_until_ready()
+            with rt.phase("d2h"):
+                hit = np.asarray(dhit)
+            with rt.phase("unpack"):
+                return hit[:n]
 
     def probe_confirmed(self, digests: list[bytes]) -> list[bool]:
         arr = np.frombuffer(b"".join(digests), dtype=np.uint8).reshape(-1, 32)

@@ -28,13 +28,20 @@ import numpy as np
 from ..chunker import observe
 from ..chunker.spec import WINDOW, ChunkerParams, buzhash_subtables
 from ..chunker.spec import select_cuts
+from ..utils import jaxenv, trace
+
+jaxenv.watch_compiles()
 
 # multi-chip dispatch evidence and padding occupancy (test/metrics
 # probe): mesh_* move whenever a batched dispatch is sharded over the
 # data mesh; mesh_shard_devices is how many distinct devices the last
-# sharded input's shards really sat on
-stats = {"mesh_dispatches": 0, "mesh_devices": 0, "mesh_shard_devices": 0,
-         "dispatches": 0, "rows": 0, "bytes": 0, "padded_bytes": 0}
+# sharded input's shards really sat on.  The five phase clocks
+# (pack_s … unpack_s: seconds of the calling thread inside each step of
+# a round trip, docs/observability.md) come with the registration.
+stats = trace.device_stats("scan", {
+    "mesh_dispatches": 0, "mesh_devices": 0, "mesh_shard_devices": 0,
+    "dispatches": 0, "rows": 0, "padded_rows": 0, "bytes": 0,
+    "padded_bytes": 0})
 
 
 def _rotl(x: jax.Array, r: int) -> jax.Array:
@@ -204,31 +211,45 @@ def _dispatch_hits(bufs: list, hists: list, S_pad: int, mesh,
     if mesh is not None:
         n = mesh.size
         B_pad = ((max(B_pad, n) + n - 1) // n) * n
-    buf = np.zeros((B_pad, S_pad), dtype=np.uint8)
-    hist = np.zeros((B_pad, WINDOW - 1), dtype=np.uint8)
-    for i, (b, h) in enumerate(zip(bufs, hists)):
-        buf[i, :len(b)] = b
-        if h is not None:
-            hist[i] = h
-    if mesh is not None:
-        # straight from the host to each device's shard: going through a
-        # one-device array first would compile a slicing program per shape
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        rows = NamedSharding(mesh, P("data", None))
-        dbuf, dhist = jax.device_put(buf, rows), jax.device_put(hist, rows)
-        stats["mesh_dispatches"] += 1
-        stats["mesh_devices"] = mesh.size
-        stats["mesh_shard_devices"] = len(
-            {s.device for s in dbuf.addressable_shards})
-    else:
-        dbuf, dhist = jnp.asarray(buf), jnp.asarray(hist)
-    stats["dispatches"] += 1
-    stats["rows"] += len(bufs)
-    stats["bytes"] += sum(len(b) for b in bufs)
-    stats["padded_bytes"] += buf.size
-    m = np.asarray(candidate_mask(dbuf, tables, params.mask,
-                                  params.magic, history=dhist))
-    return [np.nonzero(m[i, :len(b)])[0] for i, b in enumerate(bufs)]
+    with trace.round_trip("device.scan", stats, seg_pad=S_pad,
+                          shape=f"rows={B_pad} seg={S_pad >> 10} KiB") as rt:
+        with rt.phase("pack"):
+            buf = np.zeros((B_pad, S_pad), dtype=np.uint8)
+            hist = np.zeros((B_pad, WINDOW - 1), dtype=np.uint8)
+            for i, (b, h) in enumerate(zip(bufs, hists)):
+                buf[i, :len(b)] = b
+                if h is not None:
+                    hist[i] = h
+        with rt.phase("h2d"):
+            if mesh is not None:
+                # straight from the host to each device's shard: going
+                # through a one-device array first would compile a
+                # slicing program per shape
+                from jax.sharding import NamedSharding, PartitionSpec as P
+                rows = NamedSharding(mesh, P("data", None))
+                dbuf = jax.device_put(buf, rows)
+                dhist = jax.device_put(hist, rows)
+            else:
+                dbuf, dhist = jnp.asarray(buf), jnp.asarray(hist)
+            # no wait here: the copies' end is not this phase's boundary
+            # but the calls' return, and what of them is still in flight
+            # overlaps the launch and counts as ``device``
+            # (docs/observability.md "Device round trips")
+        if mesh is not None:
+            stats["mesh_dispatches"] += 1
+            stats["mesh_devices"] = mesh.size
+            stats["mesh_shard_devices"] = len(
+                {s.device for s in dbuf.addressable_shards})
+        rt.add(dispatches=1, rows=len(bufs), padded_rows=B_pad,
+               bytes=sum(len(b) for b in bufs), padded_bytes=buf.size)
+        with rt.phase("device"):
+            dmask = candidate_mask(dbuf, tables, params.mask, params.magic,
+                                   history=dhist).block_until_ready()
+        with rt.phase("d2h"):
+            m = np.asarray(dmask)
+        with rt.phase("unpack"):
+            return [np.nonzero(m[i, :len(b)])[0]
+                    for i, b in enumerate(bufs)]
 
 
 def candidate_ends_host(data: bytes | np.ndarray, params: ChunkerParams,

@@ -29,7 +29,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils import jaxenv, trace
 from .rolling_hash import _class_for
+
+jaxenv.watch_compiles()
 
 _K = np.array([
     0x428a2f98, 0x71374491, 0xb5c0fbcf, 0xe9b5dba5,
@@ -191,12 +194,14 @@ def _sha256_scan_impl(stream: jax.Array, starts: jax.Array, lengths: jax.Array,
 _sha256_scan = jax.jit(_sha256_scan_impl, static_argnames=("unroll",))
 
 
-_dispatch_count = 0      # device dispatches (integration-test probe)
-
-# multi-chip dispatch evidence and padding occupancy (test/metrics
-# probe), mirror of rolling_hash.stats
-stats = {"mesh_dispatches": 0, "mesh_devices": 0, "mesh_shard_devices": 0,
-         "rows": 0, "padded_rows": 0, "bytes": 0, "padded_bytes": 0}
+# multi-chip dispatch evidence, padding occupancy and the five phase
+# clocks, mirror of rolling_hash.stats: ``slabs`` staging buffers went to
+# the device, ``dispatches`` programs ran over them (one per length
+# bucket)
+stats = trace.device_stats("sha", {
+    "mesh_dispatches": 0, "mesh_devices": 0, "mesh_shard_devices": 0,
+    "slabs": 0, "dispatches": 0, "rows": 0, "padded_rows": 0, "bytes": 0,
+    "padded_bytes": 0})
 
 # The whole jit key of ``_sha256_scan`` is (staging-buffer length, padded
 # row count), and both come from these two short lists — a flush whose
@@ -215,61 +220,76 @@ def _hash_slab(views: list, unroll: int | None) -> list[bytes]:
     length bucket (next power of two of the block count: lanes of a
     dispatch run in lockstep, so a bucket wastes under half its steps on
     the shorter chunks).  Every bucket runs the same compiled program."""
-    global _dispatch_count
-    lens = np.array([len(v) for v in views], dtype=np.int64)
-    total = int(lens.sum())
-    slab = np.zeros(_class_for(total + SLACK_BYTES, _SLAB_CLASSES),
-                    dtype=np.uint8)
-    starts = np.concatenate([[0], np.cumsum(lens)[:-1]])
-    for v, off in zip(views, starts):
-        slab[off:off + len(v)] = v
-    # multi-chip: rows shard over the data mesh, the buffer is replicated
-    # (per-row slices are local reads); host arrays go straight to their
-    # devices — through a one-device array they would compile a slicing
-    # program per shape
-    from ..parallel.mesh import data_mesh
-    mesh = data_mesh()
-    if mesh is not None and _ROW_CLASSES[0] % mesh.size == 0:
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        row_sharding = NamedSharding(mesh, P("data"))
-        ds = jax.device_put(slab, NamedSharding(mesh, P()))
-    else:
-        row_sharding = None
-        ds = jnp.asarray(slab)
-    stats["bytes"] += total
-    stats["padded_bytes"] += len(slab)
-    nblocks = (lens + 8) // 64 + 1
-    buckets: dict[int, list[int]] = {}
-    for i, nb in enumerate(nblocks):
-        buckets.setdefault(int(nb - 1).bit_length(), []).append(i)
-    out: list[bytes | None] = [None] * len(views)
-    for _, idxs in sorted(buckets.items()):
-        n_pad = _class_for(len(idxs), _ROW_CLASSES)
-        bs = np.zeros(n_pad, dtype=np.int32)
-        bl = np.zeros(n_pad, dtype=np.int32)
-        bs[:len(idxs)] = starts[idxs]
-        bl[:len(idxs)] = lens[idxs]
-        if row_sharding is None:
-            dbs, dbl = jnp.asarray(bs), jnp.asarray(bl)
-        else:
-            dbs = jax.device_put(bs, row_sharding)
-            dbl = jax.device_put(bl, row_sharding)
-            stats["mesh_dispatches"] += 1
-            stats["mesh_devices"] = mesh.size
-            stats["mesh_shard_devices"] = len(
-                {s.device for s in dbs.addressable_shards})
-        _dispatch_count += 1
-        stats["rows"] += len(idxs)
-        stats["padded_rows"] += n_pad
-        # deliberate batched sync: ONE device→host transfer per dispatch
-        # of up to 4096 chunks (the digests must land on the host), not
-        # a per-chunk sync
-        # pbslint: disable=no-hostsync-in-hot-loop
-        dig = np.asarray(_sha256_scan(ds, dbs, dbl,
-                                      np.int32(nblocks[idxs].max()),
-                                      unroll=unroll))
-        for k, i in enumerate(idxs):
-            out[i] = dig[k].astype(">u4").tobytes()
+    with trace.round_trip("device.sha", stats) as rt:
+        with rt.phase("pack"):
+            lens = np.array([len(v) for v in views], dtype=np.int64)
+            total = int(lens.sum())
+            slab = np.zeros(_class_for(total + SLACK_BYTES, _SLAB_CLASSES),
+                            dtype=np.uint8)
+            starts = np.concatenate([[0], np.cumsum(lens)[:-1]])
+            for v, off in zip(views, starts):
+                slab[off:off + len(v)] = v
+            nblocks = (lens + 8) // 64 + 1
+            buckets: dict[int, list[int]] = {}
+            for i, nb in enumerate(nblocks):
+                buckets.setdefault(int(nb - 1).bit_length(), []).append(i)
+        rt.attrs["slab_bytes"] = len(slab)
+        # multi-chip: rows shard over the data mesh, the buffer is
+        # replicated (per-row slices are local reads); host arrays go
+        # straight to their devices — through a one-device array they
+        # would compile a slicing program per shape
+        from ..parallel.mesh import data_mesh
+        mesh = data_mesh()
+        with rt.phase("h2d"):
+            if mesh is not None and _ROW_CLASSES[0] % mesh.size == 0:
+                from jax.sharding import NamedSharding, PartitionSpec as P
+                row_sharding = NamedSharding(mesh, P("data"))
+                ds = jax.device_put(slab, NamedSharding(mesh, P()))
+            else:
+                row_sharding = None
+                ds = jnp.asarray(slab)
+            ds.block_until_ready()
+        rt.add(slabs=1, bytes=total, padded_bytes=len(slab))
+        out: list[bytes | None] = [None] * len(views)
+        for _, idxs in sorted(buckets.items()):
+            with rt.phase("pack"):
+                n_pad = _class_for(len(idxs), _ROW_CLASSES)
+                bs = np.zeros(n_pad, dtype=np.int32)
+                bl = np.zeros(n_pad, dtype=np.int32)
+                bs[:len(idxs)] = starts[idxs]
+                bl[:len(idxs)] = lens[idxs]
+            rt.shape = f"slab={len(slab) >> 20} MiB rows={n_pad}"
+            with rt.phase("h2d"):
+                if row_sharding is None:
+                    dbs, dbl = jnp.asarray(bs), jnp.asarray(bl)
+                else:
+                    dbs = jax.device_put(bs, row_sharding)
+                    dbl = jax.device_put(bl, row_sharding)
+                # the phase boundaries, here and below: one wait for the
+                # copies and one for the program per dispatch of up to
+                # 4096 chunks, so that each is timed apart
+                jax.block_until_ready((dbs, dbl))
+            if row_sharding is not None:
+                stats["mesh_dispatches"] += 1
+                stats["mesh_devices"] = mesh.size
+                stats["mesh_shard_devices"] = len(
+                    {s.device for s in dbs.addressable_shards})
+            rt.add(dispatches=1, rows=len(idxs), padded_rows=n_pad)
+            with rt.phase("device"):
+                ddig = _sha256_scan(ds, dbs, dbl,
+                                    np.int32(nblocks[idxs].max()),
+                                    unroll=unroll)
+                # pbslint: disable=no-hostsync-in-hot-loop
+                ddig.block_until_ready()
+            with rt.phase("d2h"):
+                # deliberate batched sync: ONE device→host transfer per
+                # dispatch (the digests must land on the host), not a
+                # per-chunk sync
+                # pbslint: disable=no-hostsync-in-hot-loop
+                dig = np.asarray(ddig)
+            with rt.phase("unpack"):
+                for k, i in enumerate(idxs):
+                    out[i] = dig[k].astype(">u4").tobytes()
     return out  # type: ignore[return-value]
 
 

@@ -309,12 +309,16 @@ class _ChunkedStream:
             digests = self._hasher([c for _, c in self._pending])
         known = self._probe_known(digests)
         self._presketch(digests, [c for _, c in self._pending], known)
-        for i, ((idx, chunk), digest) in enumerate(zip(self._pending,
-                                                       digests)):
-            end, _ = self.records[idx]
-            self.records[idx] = (end, digest)
-            self._insert_probed(digest, chunk,
-                                known[i] if known is not None else None)
+        new0 = self.stats.new_chunks
+        with trace.span("ingest.store", chunks=len(digests),
+                        bytes=self._pending_bytes) as sp:
+            for i, ((idx, chunk), digest) in enumerate(zip(self._pending,
+                                                           digests)):
+                end, _ = self.records[idx]
+                self.records[idx] = (end, digest)
+                self._insert_probed(digest, chunk,
+                                    known[i] if known is not None else None)
+            sp.set(new=self.stats.new_chunks - new0)
         self._pending.clear()
         self._pending_bytes = 0
 

@@ -196,7 +196,17 @@ histogram("pbs_plus_session_open_seconds",
           "(phase=connect, soak-fed) and the backup job-session open "
           "(phase=job)")
 histogram("pbs_plus_ingest_stage_seconds",
-          "Batched ingest dispatch per stage (cdc/sha/probe/presketch)")
+          "Batched ingest dispatch per stage "
+          "(cdc/sha/probe/presketch/store)")
+histogram("pbs_plus_feeder_dispatch_seconds",
+          "One dispatch of the cross-session device batcher (a mask "
+          "group or a hash round), by kind (scan/sha)")
+histogram("pbs_plus_feeder_queue_wait_seconds",
+          "A request's wait in the device batcher from submit to the "
+          "start of its dispatch, by kind (scan/sha)")
+histogram("pbs_plus_device_dispatch_seconds",
+          "One round trip to the device (pack, copy in, program, copy "
+          "out, unpack), by op (scan/sha/probe)")
 histogram("pbs_plus_chunk_cache_fetch_seconds",
           "Chunk-cache miss loads (disk read + decompress + verify)")
 histogram("pbs_plus_digestlog_confirm_read_seconds",
@@ -504,6 +514,71 @@ class MetricsRegistry:
         gauge("pbs_plus_ingest_batch_occupancy",
               "Payload fraction of packed scan buffers (1.0 = zero "
               "packing overhead)", [({}, float(ib["occupancy"]))])
+
+        # -- device round trips and the cross-session batcher
+        #    (utils/trace.py DEVICE_STATS; docs/observability.md "Device
+        #    round trips").  An op or a feeder this process never loaded
+        #    has no entry and renders no sample: rendering imports none
+        #    of them, they import jax --------------------------------------
+        from ..utils import jaxenv as _jaxenv
+        from ..utils import trace as _trace
+        dev = {op: dict(_trace.DEVICE_STATS[op])
+               for op in ("scan", "sha", "probe")
+               if op in _trace.DEVICE_STATS}
+        fd = dict(_trace.DEVICE_STATS.get("feeder", {}))
+        gauge("pbs_plus_device_phase_seconds_total",
+              "Seconds of the calling thread inside each phase of a "
+              "device round trip (pack/h2d/device/d2h/unpack), by op",
+              [({"op": op, "phase": ph}, float(st[ph + "_s"]))
+               for op, st in dev.items() for ph in _trace.PHASES])
+        gauge("pbs_plus_device_dispatches_total",
+              "Device programs run, by op (scan/sha/probe)",
+              [({"op": op}, float(st["dispatches"]))
+               for op, st in dev.items()])
+        gauge("pbs_plus_device_bytes_total",
+              "Bytes asked for (payload) and bytes sent to the device "
+              "after padding to a shape class (padded), by op",
+              [({"op": op, "kind": kind}, float(st[key]))
+               for op, st in dev.items()
+               for kind, key in (("payload", "bytes"),
+                                 ("padded", "padded_bytes"))])
+        gauge("pbs_plus_device_table_uploads_total",
+              "Times a probe found the dedup index's table dirtied by "
+              "an insert and copied it to the device again, whole",
+              [({}, float(dev["probe"]["table_uploads"]))]
+              if "probe" in dev else [])
+        gauge("pbs_plus_device_table_upload_bytes_total",
+              "Bytes of those table copies",
+              [({}, float(dev["probe"]["table_upload_bytes"]))]
+              if "probe" in dev else [])
+        gauge("pbs_plus_feeder_thread_seconds_total",
+              "The device batcher thread's life by state: inside a scan "
+              "or a hash dispatch, idle with both queues empty, "
+              "lingering to widen a batch",
+              [({"state": state}, float(fd[key]))
+               for state, key in (("scan", "mask_busy_s"),
+                                  ("sha", "sha_busy_s"),
+                                  ("idle", "idle_s"),
+                                  ("linger", "linger_s")) if key in fd])
+        gauge("pbs_plus_feeder_requests_total",
+              "Requests the device batcher served, by kind (scan rows, "
+              "hash batches)",
+              [({"kind": kind}, float(fd[key]))
+               for kind, key in (("scan", "mask_rows"),
+                                 ("sha", "sha_streams")) if key in fd])
+        gauge("pbs_plus_feeder_rounds_total",
+              "Rounds of the device batcher: one drain of both queues, "
+              "served as a scan dispatch per chunker key and one hash "
+              "dispatch",
+              [({}, float(fd["rounds"]))] if "rounds" in fd else [])
+        gauge("pbs_plus_device_compilations_total",
+              "Programs jax built or loaded from its cache since the "
+              "device ops were loaded; one that moves while backups run "
+              "is a shape class met for the first time",
+              [({}, float(_jaxenv.compiles["count"]))])
+        gauge("pbs_plus_device_compile_seconds_total",
+              "Seconds spent building or loading those programs",
+              [({}, float(_jaxenv.compiles["seconds"]))])
 
         # -- chunker backends (chunker/observe.py; docs/data-plane.md
         #    "Chunking backends") -------------------------------------------

@@ -5,7 +5,7 @@ one, the CPU where the operator set ``JAX_PLATFORMS=cpu`` (the tests do).
 Nothing here or anywhere else in the package changes it, and a failure
 to initialise it is the caller's exception, never a quiet host run.
 
-Two helpers:
+Three helpers:
 
 - ``configure_compile_cache`` places jax's persistent compilation cache.
   ``JAX_COMPILATION_CACHE_DIR`` wins when set (jax reads it itself);
@@ -17,6 +17,11 @@ Two helpers:
   ``models/verify.py``): device when the backend is an accelerator, host
   on the CPU backend, counted per twin so a run can say which side did
   the work.
+- ``watch_compiles`` counts the programs jax builds or loads from its
+  cache, process-wide (``compiles``, exported on ``/metrics``) and per
+  thread (``thread_compiles``): a device dispatch that moved its own
+  thread's count met a shape class for the first time
+  (``utils/trace.py`` ``round_trip``).
 """
 
 from __future__ import annotations
@@ -24,6 +29,7 @@ from __future__ import annotations
 import functools
 import os
 import sys
+import threading
 
 CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
@@ -63,3 +69,32 @@ def pick_twin(name: str) -> bool:
     counts = twin_counts.setdefault(name, {"device": 0, "host": 0})
     counts["device" if device else "host"] += 1
     return device
+
+
+# programs built, or loaded from the persistent cache, since
+# ``watch_compiles``, and the seconds that took
+compiles = {"count": 0, "seconds": 0.0}
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compiles_here = threading.local()      # .count: those made on this thread
+
+
+def _on_duration(event: str, seconds: float, **_kw) -> None:
+    if event == _COMPILE_EVENT:
+        compiles["count"] += 1
+        compiles["seconds"] += seconds
+        _compiles_here.count = thread_compiles() + 1
+
+
+@functools.cache
+def watch_compiles() -> None:
+    """Register, once, the ``jax.monitoring`` listener behind
+    ``compiles``.  The device ops call it as they are imported, so it is
+    in place before their first dispatch."""
+    from jax import monitoring
+    monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def thread_compiles() -> int:
+    """Programs compiled on the calling thread so far (jax compiles on
+    the thread that makes the call)."""
+    return getattr(_compiles_here, "count", 0)

@@ -32,6 +32,16 @@ measurement substrate:
   for hot sites like mux frame writes) feeds a fixed-bucket log-spaced
   histogram in ``server/metrics.py`` — ``/metrics`` finally exports
   p50/p99-derivable latency for the whole path.
+- **Device round trips.**  ``with trace.round_trip("device.scan", stats,
+  ...) as rt:`` is a span whose body is cut into the five phases of a
+  trip to the device (``with rt.phase("pack"):`` … ``h2d``, ``device``,
+  ``d2h``, ``unpack``).  Phase seconds accumulate into the op's own
+  ``stats`` dict (what ``/metrics`` and the benchmark read) and ride on
+  the span as attrs; span and phases are also entered as
+  ``jax.profiler.TraceAnnotation`` under the same names, so a profiler
+  trace of the process shows them on the device's clock.  This module
+  never imports jax: the annotation class is taken only when jax is
+  already loaded.
 
 Tracing is ALWAYS ON.  The disabled path exists only for the bench's
 tracing-on/off comparison (``disabled()``); the per-span cost without a
@@ -43,10 +53,14 @@ from __future__ import annotations
 
 import itertools
 import os
+import sys
 import threading
 import time
 from collections import deque
 from contextvars import ContextVar
+
+from . import jaxenv
+from .log import L
 
 TRACE_HEADER = "x-pbs-trace"
 
@@ -81,6 +95,19 @@ SPANS = {
     "ingest.probe": ("pbs_plus_ingest_stage_seconds", {"stage": "probe"}),
     "ingest.presketch": ("pbs_plus_ingest_stage_seconds",
                          {"stage": "presketch"}),
+    "ingest.store": ("pbs_plus_ingest_stage_seconds", {"stage": "store"}),
+    # the cross-session batcher (models/feeder.py): one dispatch per mask
+    # group / hash round on the feeder's thread, and how long each
+    # request queued for it
+    "feeder.dispatch": ("pbs_plus_feeder_dispatch_seconds",
+                        {"kind": "$kind"}),
+    "feeder.queue_wait": ("pbs_plus_feeder_queue_wait_seconds",
+                          {"kind": "$kind"}),
+    # device round trips (ops/rolling_hash.py, ops/sha256.py,
+    # ops/cuckoo.py), opened with round_trip() below
+    "device.scan": ("pbs_plus_device_dispatch_seconds", {"op": "scan"}),
+    "device.sha": ("pbs_plus_device_dispatch_seconds", {"op": "sha"}),
+    "device.probe": ("pbs_plus_device_dispatch_seconds", {"op": "probe"}),
     # read path (pxar/chunkcache.py)
     "chunkcache.fetch": ("pbs_plus_chunk_cache_fetch_seconds", None),
     # spillable exact-confirm tier (pxar/digestlog.py)
@@ -197,6 +224,13 @@ class _Span:
         self._t0 = time.perf_counter()
         return self
 
+    def set(self, **attrs) -> None:
+        """Attrs known only once the block is under way."""
+        if self.attrs is None:
+            self.attrs = attrs
+        else:
+            self.attrs.update(attrs)
+
     def __exit__(self, exc_type, exc, tb) -> bool:
         dur = time.perf_counter() - self._t0
         _ctx.reset(self._token)
@@ -217,6 +251,9 @@ class _NoopSpan:
 
     def __enter__(self) -> "_NoopSpan":
         return self
+
+    def set(self, **attrs) -> None:
+        pass
 
     def __exit__(self, *exc) -> bool:
         return False
@@ -276,6 +313,148 @@ def record(name: str, seconds: float, **attrs) -> None:
     if not _enabled:
         return
     _feed_histogram(name, seconds, attrs or None)
+
+
+# -- device round trips ------------------------------------------------------
+# A dispatch to the device is five steps on the calling thread
+# (docs/observability.md "Device round trips"); each op times them where
+# they happen, with this one helper.
+
+PHASES = ("pack", "h2d", "device", "d2h", "unpack")
+
+# op -> the counters dict its module keeps ("scan", "sha", "probe"; the
+# process-wide feeder registers "feeder"), so that /metrics renders them
+# without importing the modules, which import jax
+DEVICE_STATS: "dict[str, dict]" = {}
+_warned_compiles: set = set()
+
+
+def device_stats(op: str, counters: dict) -> dict:
+    """Register ``op``'s counters dict, with the five phase clocks
+    (``pack_s`` … ``unpack_s``) added at zero, and return it."""
+    for phase in PHASES:
+        counters.setdefault(phase + "_s", 0.0)
+    DEVICE_STATS[op] = counters
+    return counters
+
+
+def annotation(label: str):
+    """``jax.profiler.TraceAnnotation(label)`` where jax is already
+    loaded, else a no-op: in a profiler trace of this process the block
+    then lies on its thread's line, on one clock with the device's.
+    Outside a profiler session it costs well under a microsecond."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    return _NOOP if profiler is None else profiler.TraceAnnotation(label)
+
+
+def name_os_thread(name: str) -> None:
+    """Give the calling thread its name at the OS too (Linux
+    ``PR_SET_NAME``, 15 bytes): CPython before 3.14 does not, and a
+    profiler names a thread's line after the OS name — every Python
+    thread's line would read ``python3``.  Elsewhere: nothing."""
+    try:
+        import ctypes
+        ctypes.CDLL(None).prctl(15, name.encode()[:15], 0, 0, 0)
+    except (OSError, AttributeError):
+        pass
+
+
+class _Phase:
+    __slots__ = ("_trip", "_key", "_ann", "_t0")
+
+    def __init__(self, trip: "_RoundTrip", phase: str):
+        self._trip = trip
+        self._key = phase + "_s"
+        self._ann = annotation(f"{trip.name}/{phase}")
+
+    def __enter__(self) -> "_Phase":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        dur = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        trip = self._trip
+        trip.add(**{self._key: dur})
+        if jaxenv.thread_compiles() != trip._compiles:
+            trip._compiled(dur)
+        return False
+
+
+class _RoundTrip:
+    """One trip to the device and back: a span of the registry, the
+    profiler annotation of the same name, and the phase clocks.  Use
+    only as a context manager, like a span."""
+
+    __slots__ = ("name", "stats", "attrs", "shape", "_lock", "_span",
+                 "_ann", "_compiles")
+
+    def __init__(self, name: str, stats: dict, lock, shape: str,
+                 attrs: dict):
+        self.name = name
+        self.stats = stats
+        self.attrs = attrs
+        self._lock = _NOOP if lock is None else lock
+        # the compiled program's class, for the one warning when a
+        # dispatch compiles; an op with several programs per trip (the
+        # hash buckets) sets it before each
+        self.shape = shape
+
+    def __enter__(self) -> "_RoundTrip":
+        self._compiles = jaxenv.thread_compiles()
+        self._ann = annotation(self.name)
+        self._ann.__enter__()
+        self._span = _Span(self.name, self.attrs) if _enabled else _NOOP
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._span.__exit__(*exc)
+        self._ann.__exit__(*exc)
+        return False
+
+    def phase(self, phase: str) -> _Phase:
+        """Time the block as one of ``PHASES``: into ``stats[phase_s]``,
+        the span's attrs, and an annotation ``<span>/<phase>``."""
+        return _Phase(self, phase)
+
+    def add(self, **counts) -> None:
+        """Add to the op's counters and to the span's attrs of the same
+        names (``rows``, ``bytes``, ``padded_bytes`` …)."""
+        with self._lock:
+            for key, n in counts.items():
+                self.stats[key] += n
+        for key, n in counts.items():
+            self.attrs[key] = self.attrs.get(key, 0) + n
+
+    def _compiled(self, seconds: float) -> None:
+        # a program was built or loaded inside the phase that just
+        # closed, on this thread: a shape class met for the first time
+        now = jaxenv.thread_compiles()
+        self.attrs["compiled"] = self.attrs.get("compiled", 0) \
+            + now - self._compiles
+        self._compiles = now
+        key = (self.name, self.shape)
+        if key not in _warned_compiles:
+            _warned_compiles.add(key)
+            L.warning("%s %s compiled in %.1f s inside a dispatch",
+                      self.name, self.shape, seconds)
+
+
+def round_trip(name: str, stats: dict, *, lock=None, shape: str = "",
+               **attrs) -> _RoundTrip:
+    """Open the span ``name`` (a ``device.*`` name of ``SPANS``) around
+    one trip to the device; ``stats`` is the op's counters dict
+    (``device_stats``) and ``lock``, where the op is called from several
+    threads at once, the op's own guard of it (the scan and the hash run
+    on the feeder's one thread and pass none).  The phase clocks and
+    counters run whether or not spans are enabled: they are counters,
+    not tracing."""
+    if name not in SPANS:
+        raise ValueError(f"unregistered span name {name!r} "
+                         "(add it to trace.SPANS + docs/observability.md)")
+    return _RoundTrip(name, stats, lock, shape, attrs)
 
 
 # -- propagation -------------------------------------------------------------

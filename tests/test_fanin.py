@@ -43,7 +43,7 @@ async def _spawn_agent(server, cfg, tmp_path, name: str):
 
 def test_fanin_8_agents_tpu_chunker(tmp_path, monkeypatch):
     import pbs_plus_tpu.models.feeder as feeder_mod
-    from pbs_plus_tpu.models.dedup import TpuChunker
+    from pbs_plus_tpu.ops import rolling_hash as scan_ops
     from pbs_plus_tpu.ops import sha256 as sha_ops
 
     # fresh feeder with a wide linger so the concurrent writers' device
@@ -89,8 +89,8 @@ def test_fanin_8_agents_tpu_chunker(tmp_path, monkeypatch):
                 id=f"fan-{i:02d}", target=name, source_path=str(src),
                 chunker="tpu"))            # ← the one-line TPU switch
 
-        disp0 = TpuChunker.device_dispatches
-        sha0 = sha_ops._dispatch_count
+        disp0 = scan_ops.stats["dispatches"]
+        sha0 = sha_ops.stats["dispatches"]
         for i in range(N_AGENTS):
             assert server.enqueue_backup(f"fan-{i:02d}")
         await asyncio.gather(*(server.jobs.wait(f"backup:fan-{i:02d}",
@@ -117,9 +117,9 @@ def test_fanin_8_agents_tpu_chunker(tmp_path, monkeypatch):
 
         # the device pipeline actually ran — chunker candidates and sha
         # batches were dispatched through jax, not the CPU fallback
-        assert TpuChunker.device_dispatches > disp0, \
+        assert scan_ops.stats["dispatches"] > disp0, \
             "TpuChunker never dispatched"
-        assert sha_ops._dispatch_count > sha0, \
+        assert sha_ops.stats["dispatches"] > sha0, \
             "batched sha path never dispatched"
 
         # THE batch axis (VERDICT r2 missing #2): while the 8 jobs ran

@@ -232,3 +232,116 @@ def test_failed_scan_batch_is_retried_alone_and_counted(wide_feeder,
 
 def test_empty_sha_batch_is_noop(wide_feeder):
     assert wide_feeder.sha256_batch([]) == []
+
+
+# --- the batcher's own time (ISSUE 24) -----------------------------------
+
+STATES = ("mask_busy_s", "sha_busy_s", "idle_s", "linger_s")
+
+
+def _drive(feeder, n_threads, rounds, captured=None):
+    """``n_threads`` writers, each ``rounds`` times a scan and a hash
+    through ``feeder``, each under a span of its own."""
+    from pbs_plus_tpu.utils import trace
+    errs: list[BaseException] = []
+    barrier = threading.Barrier(n_threads)
+
+    def work(i):
+        try:
+            barrier.wait()
+            data = np.frombuffer(_data(40_000, seed=900 + i), np.uint8)
+            for _ in range(rounds):
+                with trace.span("ingest.cdc"):
+                    ctx = trace.capture()
+                    feeder.candidate_hits(data, np.zeros(63, np.uint8), P)
+                with trace.span("ingest.sha"):
+                    if captured is not None:
+                        captured.append((ctx, trace.capture()))
+                    feeder.sha256_batch([data[:3000].tobytes()])
+        except BaseException as e:
+            errs.append(e)
+    threads = [threading.Thread(target=work, args=(i,))
+               for i in range(n_threads)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(120)
+        assert not t.is_alive()
+    assert not errs, errs
+
+
+def test_queue_waits_grow_and_thread_clocks_partition_its_life(wide_feeder):
+    """After concurrent scan and hash requests both wait sums grew; the
+    four state clocks only ever grow, and over a run of at least half a
+    second their sum is the feeder thread's wall time: never more, and
+    at least 0.8 of it (a loose floor: the suite runs six workers)."""
+    import time
+    t_first = time.perf_counter()       # before the thread exists
+    _drive(wide_feeder, 4, 1)           # starts the thread; may compile
+    seen = [dict(wide_feeder.stats)]
+    t0 = time.perf_counter()
+    while time.perf_counter() - t0 < 0.6:
+        _drive(wide_feeder, 4, 2)
+        seen.append(dict(wide_feeder.stats))
+    # one more round closes the idle stretch the loop ended in
+    _drive(wide_feeder, 1, 1)
+    seen.append(dict(wide_feeder.stats))
+    wall = time.perf_counter() - t_first
+    for a, b in zip(seen, seen[1:]):
+        assert all(b[k] >= a[k] for k in STATES + ("mask_wait_s",
+                                                   "sha_wait_s", "rounds"))
+    last = seen[-1]
+    assert last["mask_wait_s"] > 0 and last["sha_wait_s"] > 0
+    assert last["rounds"] >= last["mask_dispatches"] > 0
+    life = sum(last[k] for k in STATES)
+    assert wall >= 0.5 and 0.8 * wall <= life <= wall, (life, wall, last)
+    assert all(last[k] > 0 for k in STATES), last
+
+
+def test_one_dispatch_span_per_round_links_its_submitters(wide_feeder):
+    """Every mask group and hash round is one ``feeder.dispatch`` span
+    whose ``links`` are the contexts the writers captured at submit, and
+    each ``device.*`` span is the child of one."""
+    from pbs_plus_tpu.utils import trace
+    spans: list[dict] = []
+    captured: list[tuple] = []
+    trace.subscribe(spans.append)
+    try:
+        _drive(wide_feeder, 4, 2, captured)
+    finally:
+        trace.unsubscribe(spans.append)
+    dispatches = [r for r in spans if r["name"] == "feeder.dispatch"]
+    by_kind = {k: [r for r in dispatches if r["attrs"]["kind"] == k]
+               for k in ("scan", "sha")}
+    assert len(by_kind["scan"]) == wide_feeder.stats["mask_dispatches"]
+    assert len(by_kind["sha"]) == wide_feeder.stats["sha_dispatches"]
+    for kind, i in (("scan", 0), ("sha", 1)):
+        links = [tuple(c) for r in by_kind[kind]
+                 for c in r["attrs"]["links"]]
+        assert sorted(links) == sorted(c[i] for c in captured)
+        assert sum(r["attrs"]["reqs"] for r in by_kind[kind]) \
+            == len(captured)
+        assert all(r["attrs"]["retried"] == 0 and r["parent"] == ""
+                   for r in by_kind[kind])
+    ids = {r["span"]: r for r in dispatches}
+    device = [r for r in spans if r["name"].startswith("device.")]
+    assert len(device) >= len(dispatches)
+    for r in device:
+        parent = ids[r["parent"]]
+        assert r["trace"] == parent["trace"]
+        assert r["name"] == "device." + parent["attrs"]["kind"]
+        assert r["dur_s"] <= parent["dur_s"]
+    assert trace.active_spans() == []
+
+
+def test_feeder_thread_carries_its_name_at_the_os(wide_feeder):
+    """A profiler names a thread's line after the OS name: the feeder's
+    reads ``device-feeder``, not ``python3`` (Linux; skipped elsewhere)."""
+    import os
+    wide_feeder.sha256_batch([b"x"])
+    tid = wide_feeder._thread.native_id
+    comm = f"/proc/{os.getpid()}/task/{tid}/comm"
+    if not os.path.exists(comm):
+        pytest.skip("no /proc thread names here")
+    with open(comm, encoding="utf-8") as f:
+        assert f.read().strip() == "device-feeder"

@@ -2534,6 +2534,28 @@ def test_span_discipline_with_and_oneshot_usage_clean():
     assert v == []
 
 
+def test_span_discipline_round_trip_is_a_span_api_too():
+    """``trace.round_trip`` (the span of one trip to the device) is held
+    to the same contract: a ``with`` item, a literal documented name."""
+    v = run_lint("""
+        from pbs_plus_tpu.utils import trace
+
+        def f(stats, name):
+            with trace.round_trip("device.scan", stats, seg_pad=4) as rt:
+                with rt.phase("pack"):
+                    pass
+            rt = trace.round_trip("device.sha", stats)
+            with trace.round_trip(name, stats):
+                pass
+            with trace.round_trip("device.nothing", stats):
+                pass
+    """, rules={"span-discipline"})
+    assert names(v) == ["span-discipline"] * 3
+    assert "with" in v[0].message and "round_trip" in v[0].message
+    assert "literal" in v[1].message
+    assert "observability.md" in v[2].message
+
+
 def test_span_discipline_undocumented_name_flagged():
     v = run_lint("""
         from pbs_plus_tpu.utils import trace
@@ -2584,6 +2606,21 @@ def test_registry_span_literal_not_declared_flagged(tmp_path):
     assert [x.rule for x in v] == ["registry-consistency"]
     assert "mystery.span" in v[0].message
     assert v[0].path == "pbs_plus_tpu/user.py"
+
+
+def test_registry_round_trip_site_uses_its_span_name(tmp_path):
+    v = _analyze(tmp_path, _span_tree(
+        ["known.span", "device.trip"], ["known.span", "device.trip"], """
+        from pbs_plus_tpu.utils import trace
+
+        def f(stats):
+            with trace.span("known.span"):
+                with trace.round_trip("device.trip", stats):
+                    pass
+                with trace.round_trip("device.other", stats):
+                    pass
+    """), "registry-consistency")
+    assert len(v) == 1 and "device.other" in v[0].message
 
 
 def test_registry_span_orphan_declaration_flagged(tmp_path):

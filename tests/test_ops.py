@@ -143,10 +143,10 @@ def test_sha256_packs_by_class_and_keeps_order(monkeypatch):
     monkeypatch.setattr(sha, "_sha256_scan", spy)
     sizes = [40_000, 0, 70_000, 1, 64, 30_000] + [200] * 40 + [150_000, 5]
     chunks = [_data(n, seed=500 + i) for i, n in enumerate(sizes)]
-    d0 = sha._dispatch_count
+    d0 = sha.stats["dispatches"]
     assert sha256_chunks(chunks) == [hashlib.sha256(c).digest()
                                      for c in chunks]
-    assert sha._dispatch_count > d0 + 3        # several buffers were needed
+    assert sha.stats["dispatches"] > d0 + 3        # several buffers were needed
     assert {s for s, _ in shapes} <= set(sha._SLAB_CLASSES)
     assert {r for _, r in shapes} <= {8, 16}
 

@@ -470,3 +470,53 @@ def test_enqueue_to_grant_measured_from_enqueue_timestamp():
     assert after["count"] == before["count"] + 1
     # the 50ms pre_exec is inside the measured window
     assert after["sum"] - before["sum"] >= 0.05
+
+
+# ------------------------------------------------ ingest.store (ISSUE 24)
+
+
+def test_writer_insert_loop_is_one_store_span_per_hash_batch(tmp_path):
+    """Each hash batch's insert loop is one ``ingest.store`` span beside
+    its ``ingest.sha`` and ``ingest.probe``, with the batch's chunks,
+    bytes and how many were new, and feeds the stage histogram."""
+    import numpy as np
+
+    from pbs_plus_tpu.chunker import ChunkerParams
+    from pbs_plus_tpu.pxar.datastore import ChunkStore
+    from pbs_plus_tpu.pxar.transfer import _ChunkedStream
+
+    def hasher(chunks):
+        return [hashlib.sha256(c).digest() for c in chunks]
+
+    h = metrics.HISTOGRAMS["pbs_plus_ingest_stage_seconds"]
+    before = h.snapshot().get((("stage", "store"),), {"count": 0})["count"]
+    store = ChunkStore(str(tmp_path), n_shards=2, index_budget_mb=2)
+    data = np.random.default_rng(24).integers(
+        0, 256, 256 << 10, dtype=np.uint8).tobytes()
+    for new_expected in (True, False):
+        trace.clear()
+        s = _ChunkedStream(store, ChunkerParams(avg_size=4 << 10),
+                           batch_hasher=hasher)
+        s.write(data)
+        records = s.finish()
+        stores, shas = _by_name("ingest.store"), _by_name("ingest.sha")
+        assert len(stores) == len(shas) >= 1
+        assert sum(r["attrs"]["chunks"] for r in stores) == len(records)
+        assert sum(r["attrs"]["bytes"] for r in stores) == len(data)
+        assert sum(r["attrs"]["new"] for r in stores) == \
+            (len(records) if new_expected else 0)
+    after = h.snapshot()[(("stage", "store"),)]["count"]
+    assert after > before
+
+
+def test_span_set_adds_attrs_known_late_and_is_a_noop_when_disabled():
+    with trace.span("ingest.store", chunks=2) as sp:
+        sp.set(new=1)
+    with trace.span("job") as bare:
+        bare.set(kind="backup")
+    with trace.disabled():
+        with trace.span("ingest.store") as off:
+            off.set(new=3)
+    assert _by_name("ingest.store")[0]["attrs"] == {"chunks": 2, "new": 1}
+    assert _by_name("job")[0]["attrs"] == {"kind": "backup"}
+    assert len(trace.recent()) == 2

@@ -484,7 +484,7 @@ class _Extractor(ast.NodeVisitor):
             self.s.hists.append([lit, node.lineno])
         if name is not None and "." in name:
             recv, _, api = name.rpartition(".")
-            if api in ("span", "emit", "record") and \
+            if api in ("span", "emit", "record", "round_trip") and \
                     recv.lstrip("_") == "trace" and node.args:
                 first = node.args[0]
                 lit = first.value if isinstance(first, ast.Constant) and \

@@ -19,8 +19,8 @@ literal, unique across gauges+histograms, documented.
 Test/bench-only knobs (``PBS_PLUS_FLEET``, ``PBS_PLUS_BENCH*``, ...)
 live outside the product tree and are exempt by construction.
 
-Spans: every ``trace.span/emit/record`` literal in the product tree
-must be a key of ``utils/trace.py``'s ``SPANS`` registry, every
+Spans: every ``trace.span/emit/record/round_trip`` literal in the
+product tree must be a key of ``utils/trace.py``'s ``SPANS`` registry, every
 registry key must be used at some call site, and both directions must
 agree with the ``docs/observability.md`` span table — the
 failpoint-catalog discipline applied to measurement points (the

@@ -2,11 +2,12 @@
 literal, documented names.
 
 Invariant (utils/trace.py, docs/observability.md): every
-``trace.span(...)`` call is a ``with`` context item — a span object
-held in a variable and entered by hand has no guaranteed close, and an
-unclosed span is exactly the orphan the propagation tests hunt
-(``trace.active_spans()``).  ``trace.span/emit/record`` names are
-STRING LITERALS (a computed name cannot be audited against the closed
+``trace.span(...)`` call — and every ``trace.round_trip(...)``, the
+span of one trip to the device — is a ``with`` context item: a span
+object held in a variable and entered by hand has no guaranteed close,
+and an unclosed span is exactly the orphan the propagation tests hunt
+(``trace.active_spans()``).  ``trace.span/emit/record/round_trip`` names
+are STRING LITERALS (a computed name cannot be audited against the closed
 ``SPANS`` registry) and must appear in the ``docs/observability.md``
 span table — the failpoint-discipline contract applied to measurement
 points.  Cross-file registry closure (name ∈ SPANS, SPANS ⊆ used,
@@ -25,14 +26,15 @@ from ..core import REPO_ROOT, Rule
 
 _DOC_PATH = os.path.join(REPO_ROOT, "docs", "observability.md")
 _BACKTICKED = re.compile(r"`([A-Za-z0-9_.\-]+)`")
-_APIS = ("span", "emit", "record")
+_APIS = ("span", "emit", "record", "round_trip")
+_WITH_ONLY = ("span", "round_trip")
 
 
 class SpanDiscipline(Rule):
     name = "span-discipline"
-    invariant = ("trace.span is used only as a `with` context item, and "
-                 "trace.span/emit/record names are literal and listed in "
-                 "docs/observability.md")
+    invariant = ("trace.span and trace.round_trip are used only as `with` "
+                 "context items, and trace.span/emit/record/round_trip "
+                 "names are literal and listed in docs/observability.md")
 
     def __init__(self):
         self._catalog: "set[str] | None" = None
@@ -70,13 +72,13 @@ class SpanDiscipline(Rule):
                        "against the SPANS registry or the "
                        "docs/observability.md catalog)")
             return
-        if func.attr == "span" and id(node) not in ctx.with_ctx_ids:
+        if func.attr in _WITH_ONLY and id(node) not in ctx.with_ctx_ids:
             ctx.report(self, node,
-                       "`trace.span(...)` used outside a `with` item — "
-                       "a manually-entered span has no guaranteed close "
-                       "and leaks as an orphan; use `with trace.span("
-                       "...):` (one-shot measurements go through "
-                       "trace.emit/record)")
+                       f"`trace.{func.attr}(...)` used outside a `with` "
+                       "item — a manually-entered span has no guaranteed "
+                       "close and leaks as an orphan; use `with "
+                       f"trace.{func.attr}(...):` (one-shot measurements "
+                       "go through trace.emit/record)")
             return
         name = node.args[0].value
         catalog = self._load_catalog()
