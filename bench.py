@@ -1697,7 +1697,14 @@ def _tpu_pipeline(seconds_budget: float = 120.0) -> dict | None:
         from pbs_plus_tpu.chunker.spec import select_cuts
         from pbs_plus_tpu.ops.rolling_hash import (
             _candidate_mask_impl, device_tables)
-        from pbs_plus_tpu.ops.sha256 import sha256_stream_chunks
+        from pbs_plus_tpu.ops.sha256 import sha256_chunks_device
+
+        def device_digests(stream, bounds, unroll):
+            # the device program: this is its pipeline's measurement
+            # (digests elsewhere come from the host: ops/sha256.py)
+            host = np.asarray(stream)
+            return sha256_chunks_device([host[s:e] for s, e in bounds],
+                                        unroll=unroll)
 
         params = ChunkerParams(avg_size=4 << 20)
         tables = device_tables(params)
@@ -1746,9 +1753,9 @@ def _tpu_pipeline(seconds_budget: float = 120.0) -> dict | None:
             if time.time() > deadline:
                 break
             try:
-                sha256_stream_chunks(dflat, flat_bounds, unroll=unroll)
+                device_digests(dflat, flat_bounds, unroll=unroll)
                 t0 = time.perf_counter()
-                sha256_stream_chunks(dflat, flat_bounds, unroll=unroll)
+                device_digests(dflat, flat_bounds, unroll=unroll)
                 dt = time.perf_counter() - t0
                 if dt < best_dt:
                     best_unroll, best_dt = unroll, dt
@@ -1763,7 +1770,7 @@ def _tpu_pipeline(seconds_budget: float = 120.0) -> dict | None:
         p0 = pos0[(pos0 >= 0)].astype(np.int64)
         dev_ends = p0[p0 < S] + 1
         assert np.array_equal(cpu_ends, dev_ends), "cut parity failed"
-        digests = sha256_stream_chunks(dflat, flat_bounds[:4],
+        digests = device_digests(dflat, flat_bounds[:4],
                                        unroll=best_unroll)
         for i, (s0, e0) in enumerate(flat_bounds[:4]):
             b, off = divmod(s0, S)
@@ -1780,7 +1787,7 @@ def _tpu_pipeline(seconds_budget: float = 120.0) -> dict | None:
             t0 = time.perf_counter()
             pos = np.asarray(cand_positions(dd))     # dense pass 1, sparse out
             fb = bounds_from_positions(pos)          # host greedy (O(chunks))
-            sha256_stream_chunks(dd.reshape(-1), fb, unroll=best_unroll)
+            device_digests(dd.reshape(-1), fb, unroll=best_unroll)
             times.append(time.perf_counter() - t0)
             it += 1
         if not times:

@@ -147,19 +147,25 @@ def phase_kernels(seed: int, compiles: Compiles) -> None:
             scan_parity([row[k * seg:(k + 1) * seg] for k in range(rows)],
                         64 << 10)
 
-    # SHA-256, the TPU branch, against hashlib: the padding edge cases
-    # and the deployment's chunk sizes — hashed together, then the short
-    # ones alone (the smallest staging buffer: a session's last flush),
-    # then thirteen of one length (the 64-row class), so every program
-    # the main path uses is checked here and compiled before phase 2
+    # SHA-256, the device engine's TPU branch, against hashlib: the
+    # padding edge cases and the deployment's chunk sizes — hashed
+    # together, then the short ones alone (the smallest staging buffer),
+    # then thirteen of one length (the 64-row class)
     d0 = sha.stats["dispatches"]
     chunks = [rng.bytes(n) for n in
               (0, 55, 56, 64, 1 * MIB, 4 * MIB, 16 * MIB)]
     many = [rng.bytes(3 * MIB // 2) for _ in range(13)]
     for batch in (chunks, chunks[:4], many):
-        if sha.sha256_chunks(batch) != \
+        if sha.sha256_chunks_device(batch) != \
                 [hashlib.sha256(c).digest() for c in batch]:
             raise AssertionError("device sha256 diverges from hashlib")
+    # ... and the entry every caller of digests uses: the host's
+    # SHA-256, no program runs
+    d1, h0 = sha.stats["dispatches"], sha.stats["host_batches"]
+    if sha.sha256_chunks(many) != [hashlib.sha256(c).digest() for c in many]:
+        raise AssertionError("host sha256 diverges from hashlib")
+    if (sha.stats["dispatches"], sha.stats["host_batches"]) != (d1, h0 + 1):
+        raise AssertionError("sha256_chunks did not hash on the host")
 
     # cuckoo lookup against the host mirror: 64k digests, half of them
     # inserted, table of 1 << 20 buckets (DedupConfig's default)
@@ -340,11 +346,19 @@ def device_counters() -> dict:
             "scan": dict(rolling_hash.stats), "sha": dict(sha256.stats)}
 
 
-def assert_device_did_the_work(before: dict, now: dict) -> None:
+def assert_device_did_the_work(before: dict, now: dict,
+                               hashed: int) -> None:
+    """``hashed``: the bytes of the burst's payload streams, all of
+    which the tpu batch hasher hashes — on the host (ops/sha256.py),
+    while the scans have the device to themselves."""
     if now["scan"]["dispatches"] <= before["scan"]["dispatches"]:
         raise AssertionError("TpuChunker never dispatched")
-    if now["sha"]["dispatches"] <= before["sha"]["dispatches"]:
-        raise AssertionError("the batched sha path never dispatched")
+    sha = {k: now["sha"][k] - before["sha"][k]
+           for k in ("dispatches", "host_batches", "host_bytes")}
+    if sha["host_batches"] < 1 or sha["host_bytes"] != hashed \
+            or sha["dispatches"]:
+        raise AssertionError(f"the tpu batch hasher did not hash the "
+                             f"burst's {hashed} bytes on the host: {sha}")
     feeder = now["feeder"]
     if feeder["max_mask_batch"] <= 1:
         raise AssertionError(f"no cross-stream device batch formed: {feeder}")
@@ -409,7 +423,8 @@ def phase_fanin(work: str, seed: int, compiles: Compiles, *,
     extra = {}
 
     async def after(server, agents, snaps):
-        marks["backed_up"] = (time.monotonic(), compiles.snapshot())
+        marks["backed_up"] = (time.monotonic(), compiles.snapshot(),
+                              device_counters())
         if one_chip:
             extra.update(await restore_and_verify(
                 server, agents, snaps, os.path.join(work, "tpu"), trees))
@@ -419,8 +434,9 @@ def phase_fanin(work: str, seed: int, compiles: Compiles, *,
         at_half=lambda: marks.setdefault("half", compiles.snapshot()),
         after=after))
     t_tpu = time.monotonic()
-    now = device_counters()
-    assert_device_did_the_work(before, now)
+    now = marks["backed_up"][2]
+    assert_device_did_the_work(
+        before, now, sum(s["indexes"][0][-1][0] for s in tpu.values()))
     second_half = {k: marks["backed_up"][1][k] - marks["half"][k]
                    for k in marks["half"]}
     if one_chip and second_half["compilations"]:
@@ -492,13 +508,15 @@ def phase_sidecar(seed: int, compiles: Compiles, *, stream_mib: int) -> None:
 # -- phase 4: four chips -----------------------------------------------------
 
 def assert_mesh_did_the_work(now: dict, n: int) -> None:
-    """Code that never saw two chips may put everything on the first."""
-    for name in ("scan", "sha"):
-        s = now[name]
-        if s["mesh_devices"] != n or s["mesh_dispatches"] < 1 \
-                or s["mesh_shard_devices"] != n:
-            raise AssertionError(f"{name} dispatches did not spread over "
-                                 f"{n} devices: {s}")
+    """Code that never saw two chips may put everything on the first.
+    The scan is what the fan-in runs on the mesh; the SHA-256 program is
+    off its path (ops/sha256.py) and its row sharding is checked on
+    virtual devices only (tests/test_sha_engine.py)."""
+    s = now["scan"]
+    if s["mesh_devices"] != n or s["mesh_dispatches"] < 1 \
+            or s["mesh_shard_devices"] != n:
+        raise AssertionError(f"scan dispatches did not spread over "
+                             f"{n} devices: {s}")
 
 
 # -- main ----------------------------------------------------------------------

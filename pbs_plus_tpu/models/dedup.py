@@ -29,6 +29,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from ..chunker.spec import WINDOW, ChunkerParams, select_cuts
+from ..ops import sha256
 from ..ops.cuckoo import CuckooIndex
 from ..ops.rolling_hash import (batched_candidate_hits, device_tables,
                                 segment_class)
@@ -173,14 +174,16 @@ class DeviceDispatchError(RuntimeError):
 
 
 def device_sha256_batch(chunks: list) -> list:
-    """The ``chunker="tpu"`` batch hasher: this stream's chunk batch goes
-    to the process-wide DeviceFeeder, which coalesces it with other
-    concurrent writers' batches into one device round."""
-    from .feeder import get_feeder
-    try:
-        return get_feeder().sha256_batch(chunks)
-    except Exception as e:
-        raise DeviceDispatchError("sha256 batch", e) from e
+    """The ``chunker="tpu"`` batch hasher: the host's SHA-256
+    (``ops.sha256.sha256_chunks``, looked up at call time) on the
+    writer's own thread, so eight sessions hash side by side and none
+    waits in the feeder's queue.  The device's SHA-256 program loses to
+    one host thread on every batch shape measured (ops/sha256.py;
+    PERF.md section 6, PR 25) — a session's 16 MiB of 4 MiB chunks by a
+    hundred times — and held the feeder's one thread for half of every
+    second while the scans waited.  The scan stays on the device, and a
+    scan dispatch that fails still fails the job (DeviceDispatchError)."""
+    return sha256.sha256_chunks(chunks)
 
 
 class TpuChunker:

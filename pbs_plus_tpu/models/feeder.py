@@ -22,9 +22,18 @@ accumulate and form batch *k+1* — batching emerges from device latency
 itself (no mandatory linger).  A small optional linger widens batches
 when the queue is empty at wake time.
 
+Hash batches: no writer's batch comes here any more —
+``models.dedup.device_sha256_batch`` and the sidecar hash on the calling
+thread with the host's SHA-256 (``ops.sha256.sha256_chunks``), which
+beats the device program on every batch shape measured (PERF.md section
+6, PR 25).  ``sha256_batch`` stays as the device engine's cross-session
+batcher (``ops.sha256.sha256_chunks_device``): lanes filled across
+sessions is what a kernel that wins will need (ROADMAP S6), and the
+benchmark's clocks hook ``_dispatch_sha``.
+
 Multi-chip: the batched ops this feeder dispatches through
 (``ops.rolling_hash.batched_candidate_hits``,
-``ops.sha256.sha256_stream_chunks``) shard their batch rows over the
+``ops.sha256.sha256_chunks_device``) shard their batch rows over the
 process-wide data mesh (``parallel.mesh.data_mesh``) whenever more than
 one device is visible — the production path, not just
 ``dryrun_multichip``, scales with chip count (round-3 judge item #3).
@@ -140,8 +149,8 @@ class DeviceFeeder:
         return req.hits
 
     def sha256_batch(self, chunks: list) -> list:
-        """Digest a list of chunk buffers; coalesced with other streams'
-        pending batches into one bucketed device dispatch."""
+        """Digest a list of chunk buffers on the device; coalesced with
+        other streams' pending batches into one bucketed device dispatch."""
         if not chunks:
             return []
         req = _ShaReq(chunks=chunks, nbytes=sum(len(c) for c in chunks))
@@ -291,8 +300,8 @@ class DeviceFeeder:
                     r.done.set()
 
     def _sha_digests(self, reqs: list[_ShaReq]) -> list:
-        from ..ops.sha256 import sha256_chunks
-        digests = sha256_chunks([c for r in reqs for c in r.chunks])
+        from ..ops.sha256 import sha256_chunks_device
+        digests = sha256_chunks_device([c for r in reqs for c in r.chunks])
         self.stats["sha_dispatches"] += 1
         self.stats["sha_streams"] += len(reqs)
         return digests

@@ -31,7 +31,8 @@ Twins (the ``ops/cuckoo.lookup_host`` discipline):
   by the chunker parity gates) + one hashlib pass for digests.
 - **device** — ``ops/rolling_hash.candidate_mask`` over the packed
   buffer (one jitted dispatch; pow2-padded so jit cache keys stay
-  bounded) + ``ops/sha256.sha256_chunks``.  Picked when jax's backend
+  bounded) + ``ops/sha256.sha256_chunks`` (the host's SHA-256 too since
+  PR 25: the device's program loses to it).  Picked when jax's backend
   is an accelerator (``utils.jaxenv.pick_twin``, which counts the
   choice); parity is pinned on the CPU backend in
   tests/test_ingest_fused.py.
@@ -209,8 +210,9 @@ def digest_chunks_host(chunks: "list") -> "list[bytes]":
 
 
 def digest_chunks_device(chunks: "list") -> "list[bytes]":
-    """SHA-256 over a whole chunk batch in one bucketed device dispatch
-    set (ops/sha256.py; digest parity vs hashlib is that module's gate)."""
+    """SHA-256 over a whole chunk batch through ``ops/sha256.py``'s
+    entry, counted in its ``stats`` (hashlib there as well, since the
+    device program loses to it: PERF.md section 6, PR 25)."""
     from . import sha256 as _sha
     _bump("sha_dispatches")
     return _sha.sha256_chunks([bytes(c) for c in chunks])

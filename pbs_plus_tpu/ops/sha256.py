@@ -1,12 +1,27 @@
-"""Batched whole-chunk SHA-256 on TPU.
+"""Whole-chunk SHA-256: the host's, and the batched program on TPU.
 
-SHA-256 is strictly sequential per chunk (64-byte block chain), so TPU
-throughput comes from batching: a loop over block index advances N chunk
-states in lockstep on the VPU; variable chunk lengths are handled by
-masking (finished chunks freeze), and the standard SHA padding (0x80 +
-zeros + 64-bit bit length) is applied on device.  Blocks are gathered per
-step straight from a device-resident staging buffer — the padded
-[T, N, 64] block tensor is never materialized.
+``sha256_chunks`` hashes with the host's SHA-256 (hashlib / OpenSSL, on
+the calling thread, interpreter lock released), and every caller that
+wants digests — the ``chunker="tpu"`` batch hasher, the sidecar,
+verification, the fused ingest path — goes through it.
+``sha256_chunks_device`` is the jax program described below; as measured
+it loses to one host thread on every batch shape (PERF.md section 6,
+PR 25: 16 MiB/s at a hash batch's 4-6 chunks, 755 MB/s at its best, 512
+equal chunks, against 1,565 MB/s), so only the feeder's ``sha256_batch``
+(its cross-session batcher), the kernel's tests, ``chip_smoke.py`` and
+``tools/sha_crossover.py`` — the measurement a kernel PR has to beat
+before digests go back to the device — run it.  There is no rule between
+the two: one comes, from the batch's shape, with the first kernel that
+wins a batch.
+
+The device program.  SHA-256 is strictly sequential per chunk (64-byte
+block chain), so TPU throughput comes from batching: a loop over block
+index advances N chunk states in lockstep on the VPU; variable chunk
+lengths are handled by masking (finished chunks freeze), and the
+standard SHA padding (0x80 + zeros + 64-bit bit length) is applied on
+device.  Blocks are gathered per step straight from a device-resident
+staging buffer — the padded [T, N, 64] block tensor is never
+materialized.
 
 Chunks are packed on the host into a staging buffer of one of a few
 lengths and hashed in one dispatch per length bucket (next power of two
@@ -15,7 +30,7 @@ trip count is a run-time argument: the compiled program's key is only
 (staging-buffer class, row class).
 
 Digest parity vs hashlib/OpenSSL is a correctness gate
-(tests/test_ops.py::test_sha256_matches_hashlib).
+(tests/test_ops.py::test_sha256_matches_hashlib, over both entries).
 
 Reference role: the chunk fingerprinting inside RemoteDedupWriter
 (/root/reference/internal/pxarmount/commit_orchestrate.go:177) and the
@@ -24,6 +39,10 @@ server-side sha256 verification pool
 """
 
 from __future__ import annotations
+
+import hashlib
+import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -197,11 +216,16 @@ _sha256_scan = jax.jit(_sha256_scan_impl, static_argnames=("unroll",))
 # multi-chip dispatch evidence, padding occupancy and the five phase
 # clocks, mirror of rolling_hash.stats: ``slabs`` staging buffers went to
 # the device, ``dispatches`` programs ran over them (one per length
-# bucket)
+# bucket).  ``host_*``: what ``sha256_chunks`` hashed on the host —
+# batches, chunks, bytes and the calling threads' seconds.  The device's
+# counters are written on one thread (the feeder's); the host's on every
+# writer's, under ``_host_lock``.
 stats = trace.device_stats("sha", {
     "mesh_dispatches": 0, "mesh_devices": 0, "mesh_shard_devices": 0,
     "slabs": 0, "dispatches": 0, "rows": 0, "padded_rows": 0, "bytes": 0,
-    "padded_bytes": 0})
+    "padded_bytes": 0,
+    "host_batches": 0, "host_rows": 0, "host_bytes": 0, "host_s": 0.0})
+_host_lock = threading.Lock()
 
 # The whole jit key of ``_sha256_scan`` is (staging-buffer length, padded
 # row count), and both come from these two short lists — a flush whose
@@ -234,6 +258,11 @@ def _hash_slab(views: list, unroll: int | None) -> list[bytes]:
             for i, nb in enumerate(nblocks):
                 buckets.setdefault(int(nb - 1).bit_length(), []).append(i)
         rt.attrs["slab_bytes"] = len(slab)
+        # the mean number of lanes that carry data while the programs
+        # run: each runs for its longest chunk's block count, whatever
+        # its rows — what a kernel PR has to raise, or make matter less
+        rt.attrs["lanes_busy"] = round(total / (64 * sum(
+            int(nblocks[idxs].max()) for idxs in buckets.values())), 2)
         # multi-chip: rows shard over the data mesh, the buffer is
         # replicated (per-row slices are local reads); host arrays go
         # straight to their devices — through a one-device array they
@@ -293,10 +322,11 @@ def _hash_slab(views: list, unroll: int | None) -> list[bytes]:
     return out  # type: ignore[return-value]
 
 
-def sha256_chunks(chunks: list, *, unroll: int | None = None) -> list[bytes]:
+def sha256_chunks_device(chunks: list, *,
+                         unroll: int | None = None) -> list[bytes]:
     """SHA-256 of each chunk buffer (bytes-like or uint8 array), in input
-    order.  Chunks are packed in order into as few staging buffers as
-    hold them."""
+    order, by the jax program.  Chunks are packed in order into as few
+    staging buffers as hold them."""
     views = [c if isinstance(c, np.ndarray) else np.frombuffer(c, np.uint8)
              for c in chunks]
     if any(len(v) > MAX_CHUNK_BYTES for v in views):
@@ -314,25 +344,42 @@ def sha256_chunks(chunks: list, *, unroll: int | None = None) -> list[bytes]:
     return out
 
 
-def sha256_stream_chunks(stream, bounds: list[tuple[int, int]], *,
-                         unroll: int | None = None) -> list[bytes]:
+def sha256_chunks(chunks: list) -> list[bytes]:
+    """SHA-256 of each chunk buffer (bytes-like or uint8 array), in input
+    order: ``hashlib.sha256`` per chunk on the calling thread (OpenSSL
+    releases the interpreter lock while it hashes, so writers' batches
+    run side by side on the host's cores)."""
+    if not chunks:
+        return []
+    total = sum(len(c) for c in chunks)
+    with trace.span("host.sha", rows=len(chunks), bytes=total):
+        t0 = time.perf_counter()
+        out = [hashlib.sha256(c).digest() for c in chunks]
+        took = time.perf_counter() - t0
+    with _host_lock:
+        stats["host_batches"] += 1
+        stats["host_rows"] += len(chunks)
+        stats["host_bytes"] += total
+        stats["host_s"] += took
+    return out
+
+
+def sha256_stream_chunks(stream, bounds: list[tuple[int, int]]) -> list[bytes]:
     """SHA-256 of ``stream[s:e]`` for each (s, e) in bounds, in input
     order.  ``stream`` may be bytes / numpy uint8 / a jax uint8 array
-    (which is brought to the host: dispatches are packed there)."""
+    (which is brought to the host)."""
     if isinstance(stream, (bytes, bytearray, memoryview)):
         stream = np.frombuffer(stream, dtype=np.uint8)
     stream = np.asarray(stream)
     if any(e < s or e - s > MAX_CHUNK_BYTES for s, e in bounds):
         raise ValueError("chunk length out of supported range")
-    return sha256_chunks([stream[s:e] for s, e in bounds], unroll=unroll)
+    return sha256_chunks([stream[s:e] for s, e in bounds])
 
 
 def sha256_streams_chunks(streams: list, bounds_per_stream: list,
                           ) -> list[list[bytes]]:
-    """Cross-stream digesting: every stream's chunks share the same
-    packed dispatches (the batch axis across agent streams — without
-    this, B streams cost B dispatch sets).  Returns per-stream digest
-    lists in input order."""
+    """Cross-stream digesting: every stream's chunks in one batch.
+    Returns per-stream digest lists in input order."""
     arrs = [np.frombuffer(s, dtype=np.uint8)
             if isinstance(s, (bytes, bytearray, memoryview)) else np.asarray(s)
             for s in streams]

@@ -69,9 +69,12 @@ class WriterStats:
 
 BatchHasher = Callable[[list[bytes]], list[bytes]]
 # pending-hash ceiling: chunk copies held for the next batched sha256
-# dispatch.  16 MiB saturates the device hash kernel while keeping the
-# writer's peak memory ~2x this bound regardless of stream size (the
-# commit_memory_test analog in tests/test_commit_edges.py pins it)
+# call.  16 MiB keeps the writer's peak memory ~2x this bound regardless
+# of stream size (the commit_memory_test analog in
+# tests/test_commit_edges.py pins it).  It does not saturate the device
+# hash kernel: at 4 MiB chunks it is 4-6 lanes of a program that hashes
+# ~16 MiB/s at that width, a hundredth of one host core, so the tpu batch
+# hasher hashes on the host (ops/sha256.py; PERF.md, PR 25)
 _HASH_BATCH_BYTES = 16 << 20
 _HASH_BATCH_COUNT = 512
 

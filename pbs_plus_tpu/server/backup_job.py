@@ -106,15 +106,14 @@ def validate_pipeline_workers(n) -> int:
 
 def make_batch_hasher(kind: str):
     """Batched digest backend matching the chunker backend: the tpu path
-    hashes emitted chunks in device batches (ops/sha256); cpu/sidecar use
-    the writer's inline hashlib path."""
+    hashes emitted chunks a batch at a time through ops/sha256 (on the
+    host's SHA-256 since PR 25, counted there); cpu/sidecar use the
+    writer's inline hashlib path."""
     if kind == "tpu":
         def hasher(chunks):
             # imported lazily on the writer thread (never on the event
             # loop): jax initialises whatever backend the process was
-            # started with, and a failing dispatch fails the job
-            # (models.dedup.DeviceDispatchError) — it is never re-run on
-            # the host
+            # started with
             from ..models.dedup import device_sha256_batch
             return device_sha256_batch(chunks)
         return hasher

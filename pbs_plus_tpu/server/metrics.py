@@ -542,6 +542,25 @@ class MetricsRegistry:
                for op, st in dev.items()
                for kind, key in (("payload", "bytes"),
                                  ("padded", "padded_bytes"))])
+        # the host's SHA-256 (ops/sha256.py sha256_chunks: every hash
+        # batch of a writer), beside the device engine's op="sha"
+        # samples above
+        host = [dev["sha"]] if "sha" in dev else []
+        gauge("pbs_plus_device_sha_host_batches_total",
+              "Hash batches the host's SHA-256 engine took "
+              "(ops/sha256.py sha256_chunks)",
+              [({}, float(st["host_batches"])) for st in host])
+        gauge("pbs_plus_device_sha_host_rows_total",
+              "Chunks of the host engine's hash batches",
+              [({}, float(st["host_rows"])) for st in host])
+        gauge("pbs_plus_device_sha_host_bytes_total",
+              "Bytes of the host engine's hash batches; the device "
+              "engine's are pbs_plus_device_bytes_total{op=\"sha\"}",
+              [({}, float(st["host_bytes"])) for st in host])
+        gauge("pbs_plus_device_sha_host_seconds_total",
+              "Seconds the calling (writer) threads spent in the host "
+              "engine's hashlib calls, summed over threads",
+              [({}, float(st["host_s"])) for st in host])
         gauge("pbs_plus_device_table_uploads_total",
               "Times a probe found the dedup index's table dirtied by "
               "an insert and copied it to the device again, whole",

@@ -23,9 +23,9 @@ import numpy as np
 
 from ..chunker.spec import ChunkerParams
 from ..models.dedup import TpuChunker
-from ..models.feeder import get_feeder
 from ..models.similarity import SimilarityModel
 from ..ops.cuckoo import CuckooIndex
+from ..ops.sha256 import sha256_chunks
 from ..utils import codec
 from ..utils.log import L
 
@@ -94,9 +94,9 @@ class DedupService:
                 del st.pending[:n]
                 st.base = c
                 out_cuts.append(c)
-        # feeder-coalesced: concurrent gRPC streams' hash batches land in
-        # one bucketed device dispatch (models/feeder.py)
-        digests = get_feeder().sha256_batch(chunks) if chunks else []
+        # on this request's own thread, by the host's SHA-256: the
+        # device program loses on every batch shape (ops/sha256.py)
+        digests = sha256_chunks(chunks)
         with self._lock:
             self.stats["bytes"] += len(data)
             self.stats["chunks"] += len(chunks)

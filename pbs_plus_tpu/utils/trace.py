@@ -108,6 +108,9 @@ SPANS = {
     "device.scan": ("pbs_plus_device_dispatch_seconds", {"op": "scan"}),
     "device.sha": ("pbs_plus_device_dispatch_seconds", {"op": "sha"}),
     "device.probe": ("pbs_plus_device_dispatch_seconds", {"op": "probe"}),
+    # a hash batch on the host's SHA-256 (ops/sha256.py sha256_chunks),
+    # on the caller's thread: child of the writer's ingest.sha
+    "host.sha": None,
     # read path (pxar/chunkcache.py)
     "chunkcache.fetch": ("pbs_plus_chunk_cache_fetch_seconds", None),
     # spillable exact-confirm tier (pxar/digestlog.py)

@@ -37,7 +37,8 @@ def _trip(op, index=None):
             [_bytes(50_000, 1), _bytes(60_000, 2)], [None, None],
             rolling_hash.device_tables(P), P)
     elif op == "sha":
-        sha256.sha256_chunks([_bytes(5000, 3), _bytes(100, 4), b"abc"])
+        sha256.sha256_chunks_device([_bytes(5000, 3), _bytes(100, 4),
+                                     b"abc"])
     else:
         (index or _index()).probe(_bytes(20 * 32, 5).reshape(-1, 32))
 

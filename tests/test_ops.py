@@ -12,7 +12,7 @@ from pbs_plus_tpu.chunker.spec import select_cuts
 from pbs_plus_tpu.ops.rolling_hash import device_tables
 from pbs_plus_tpu.ops import (
     CuckooIndex, candidate_ends_host, candidate_mask, minhash_signature,
-    pairwise_hamming, sha256_chunks, sha256_stream_chunks, simhash_sketch,
+    pairwise_hamming, sha256_stream_chunks, simhash_sketch,
 )
 from pbs_plus_tpu.ops.rolling_hash import chunk_stream_device
 from pbs_plus_tpu.ops.similarity import minhash_similarity
@@ -110,11 +110,25 @@ def test_device_cuts_match_cpu_cuts():
 
 # --- sha256 --------------------------------------------------------------
 
-def test_sha256_matches_hashlib():
-    sizes = [0, 1, 54, 55, 56, 57, 63, 64, 65, 119, 120, 128, 1000,
-             4096, 65_537]
+# both entries of ops/sha256.py (ISSUE 25): the host's SHA-256, which
+# every caller of ``sha256_chunks`` gets, and the device program
+@pytest.fixture(params=["device", "host"])
+def sha_entry(request):
+    from pbs_plus_tpu.ops import sha256 as sha
+    return {"device": sha.sha256_chunks_device,
+            "host": sha.sha256_chunks}[request.param]
+
+
+# every padding edge alone (a message of 55 bytes pads within its block,
+# one of 56 needs a second; 0 and 64 are the empty and the full block),
+# then all lengths mixed in one batch
+@pytest.mark.parametrize("sizes", [
+    [0], [55], [56], [64],
+    [0, 1, 54, 55, 56, 57, 63, 64, 65, 119, 120, 128, 1000, 4096, 65_537],
+], ids=["len0", "len55", "len56", "len64", "mixed"])
+def test_sha256_matches_hashlib(sha_entry, sizes):
     chunks = [_data(n, seed=n + 1) for n in sizes]
-    got = sha256_chunks(chunks)
+    got = sha_entry(chunks)
     want = [hashlib.sha256(c).digest() for c in chunks]
     assert got == want
 
@@ -144,8 +158,8 @@ def test_sha256_packs_by_class_and_keeps_order(monkeypatch):
     sizes = [40_000, 0, 70_000, 1, 64, 30_000] + [200] * 40 + [150_000, 5]
     chunks = [_data(n, seed=500 + i) for i, n in enumerate(sizes)]
     d0 = sha.stats["dispatches"]
-    assert sha256_chunks(chunks) == [hashlib.sha256(c).digest()
-                                     for c in chunks]
+    assert sha.sha256_chunks_device(chunks) == [hashlib.sha256(c).digest()
+                                                for c in chunks]
     assert sha.stats["dispatches"] > d0 + 3        # several buffers were needed
     assert {s for s, _ in shapes} <= set(sha._SLAB_CLASSES)
     assert {r for _, r in shapes} <= {8, 16}
@@ -364,13 +378,13 @@ def test_cuckoo_probe_compiles_for_a_handful_of_batch_sizes():
     assert cuckoo._lookup._cache_size() - before <= 3
 
 
-def test_sha256_unroll_parity():
-    """Digests identical across block-unroll factors (the TPU tuning knob)."""
-    from pbs_plus_tpu.ops.sha256 import sha256_stream_chunks
+@pytest.mark.parametrize("unroll", [1, 2, 4, 16])
+def test_sha256_unroll_parity(unroll):
+    """Digests identical across block-unroll factors (the device
+    program's tuning knob)."""
+    from pbs_plus_tpu.ops.sha256 import sha256_chunks_device
     data = _data(120_000, seed=8)
     bounds = [(0, 55), (55, 7000), (7000, 66_000), (66_000, 120_000)]
-    base = sha256_stream_chunks(data, bounds, unroll=1)
-    for unroll in (2, 4, 16):
-        assert sha256_stream_chunks(data, bounds, unroll=unroll) == base
     want = [hashlib.sha256(data[s:e]).digest() for s, e in bounds]
-    assert base == want
+    assert sha256_chunks_device([data[s:e] for s, e in bounds],
+                                unroll=unroll) == want

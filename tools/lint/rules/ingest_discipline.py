@@ -15,10 +15,11 @@ fused collector.  Two hazards are flagged:
 - **Resurrected per-stage store calls**: ``X.probe_batch(...)`` /
   ``X.presketch_batch(...)`` on anything that is not the resolved
   ingest backend, and direct calls into the batched fingerprint
-  kernels (``sha256_chunks`` / ``sha256_stream_chunks`` /
-  ``sha256_streams_chunks``) — chunk fingerprinting flows through the
-  injected ``batch_hasher`` seam or the collector's fused pass, never
-  a per-stage kernel dispatch of the stream's own.
+  kernels (``sha256_chunks`` / ``sha256_chunks_device`` /
+  ``sha256_stream_chunks`` / ``sha256_streams_chunks``) — chunk
+  fingerprinting flows through the injected ``batch_hasher`` seam or
+  the collector's fused pass, never a per-stage kernel dispatch of the
+  stream's own.
 
 Receivers whose source text mentions the resolved backend
 (``self._ingest`` / a local named ``backend``) are the sanctioned seam.
@@ -36,8 +37,8 @@ _SCOPES = ("pbs_plus_tpu/pxar/transfer.py",
 _BATCH_ATTRS = frozenset({"probe_batch", "presketch_batch"})
 _DUCK_NAMES = frozenset({"probe_batch", "presketch_batch", "presketch",
                          "sketch_batch", "note_dedup_hit"})
-_FP_KERNELS = frozenset({"sha256_chunks", "sha256_stream_chunks",
-                         "sha256_streams_chunks"})
+_FP_KERNELS = frozenset({"sha256_chunks", "sha256_chunks_device",
+                         "sha256_stream_chunks", "sha256_streams_chunks"})
 _SEAM_MARKERS = ("ingest", "backend")
 
 
