@@ -52,9 +52,13 @@ second of the feeder thread's life goes to one of four clocks in
 ``stats`` — ``mask_busy_s`` / ``sha_busy_s`` inside a dispatch,
 ``idle_s`` waiting with both queues empty, ``linger_s`` widening a batch
 — and every request adds its wait from submit to the start of its
-dispatch to ``mask_wait_s`` / ``sha_wait_s``.  Each mask group and hash
-round is one ``feeder.dispatch`` span, parent of the op's ``device.*``
-span, naming the writers' spans it served (``links``).
+dispatch to ``mask_wait_s`` / ``sha_wait_s``.  ``linger_rounds`` counts
+the rounds that waited out a linger and ``linger_joined`` those of them
+in which a second request arrived before the wait ended: with one
+session nobody can join, and the linger only delays the request it
+holds.  Each mask group and hash round is one ``feeder.dispatch`` span,
+parent of the op's ``device.*`` span, naming the writers' spans it
+served (``links``) and whether its round lingered and was joined.
 """
 
 from __future__ import annotations
@@ -129,9 +133,14 @@ class DeviceFeeder:
                       # the thread's life, partitioned (module docstring)
                       "mask_busy_s": 0.0, "sha_busy_s": 0.0,
                       "idle_s": 0.0, "linger_s": 0.0, "rounds": 0,
+                      # of the rounds, those that lingered, and those of
+                      # them a second request joined before the wait ended
+                      "linger_rounds": 0, "linger_joined": 0,
                       # requests' waits from submit to their dispatch
                       "mask_wait_s": 0.0, "sha_wait_s": 0.0}
         self._clock = 0.0       # the feeder thread's: start of its state
+        # the round under way, for its ``feeder.dispatch`` spans
+        self._round = {"lingered": 0, "joined": 0}
 
     # -- public API (writer threads) --------------------------------------
     def candidate_hits(self, buf: np.ndarray, history: np.ndarray,
@@ -187,10 +196,17 @@ class DeviceFeeder:
                 self._spent("idle_s")
                 # adaptive widening: if only one request is pending, give
                 # concurrent writers a linger window to join the batch
-                if (self.linger_s > 0
-                        and len(self._mask_q) + len(self._sha_q) == 1):
+                lingered = (self.linger_s > 0
+                            and len(self._mask_q) + len(self._sha_q) == 1)
+                joined = False
+                if lingered:
                     self._cv.wait(self.linger_s)
                     self._spent("linger_s")
+                    joined = len(self._mask_q) + len(self._sha_q) > 1
+                    self.stats["linger_rounds"] += 1
+                    self.stats["linger_joined"] += joined
+                self._round = {"lingered": int(lingered),
+                               "joined": int(joined)}
                 # drain IN PLACE — the queue list objects are permanent.
                 # (_submit callers capture the list reference outside the
                 # lock at argument-evaluation time; rebinding here would
@@ -263,6 +279,7 @@ class DeviceFeeder:
             self.stats[key] += now - r.submitted
             trace.record("feeder.queue_wait", now - r.submitted, kind=kind)
         return {"kind": kind, "reqs": len(reqs), "retried": 0,
+                **self._round,
                 "links": [r.ctx for r in reqs[:_MAX_LINKS]
                           if r.ctx is not None]}
 

@@ -211,7 +211,9 @@ def _dispatch_hits(bufs: list, hists: list, S_pad: int, mesh,
     if mesh is not None:
         n = mesh.size
         B_pad = ((max(B_pad, n) + n - 1) // n) * n
-    with trace.round_trip("device.scan", stats, seg_pad=S_pad,
+    # ``devices``: how many the rows really sit on, so a span tells a
+    # sharded dispatch from an unsharded one on a several-chip host
+    with trace.round_trip("device.scan", stats, seg_pad=S_pad, devices=1,
                           shape=f"rows={B_pad} seg={S_pad >> 10} KiB") as rt:
         with rt.phase("pack"):
             buf = np.zeros((B_pad, S_pad), dtype=np.uint8)
@@ -240,6 +242,7 @@ def _dispatch_hits(bufs: list, hists: list, S_pad: int, mesh,
             stats["mesh_devices"] = mesh.size
             stats["mesh_shard_devices"] = len(
                 {s.device for s in dbuf.addressable_shards})
+            rt.attrs["devices"] = stats["mesh_shard_devices"]
         rt.add(dispatches=1, rows=len(bufs), padded_rows=B_pad,
                bytes=sum(len(b) for b in bufs), padded_bytes=buf.size)
         with rt.phase("device"):

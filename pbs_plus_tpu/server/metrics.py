@@ -590,6 +590,14 @@ class MetricsRegistry:
               "served as a scan dispatch per chunker key and one hash "
               "dispatch",
               [({}, float(fd["rounds"]))] if "rounds" in fd else [])
+        gauge("pbs_plus_feeder_linger_rounds_total",
+              "Rounds of the device batcher that held one request back "
+              "for the linger window, by outcome: joined by a second "
+              "request before the wait ended, or dispatched alone",
+              [({"outcome": "joined"}, float(fd["linger_joined"])),
+               ({"outcome": "alone"}, float(fd["linger_rounds"]
+                                            - fd["linger_joined"]))]
+              if "linger_rounds" in fd else [])
         gauge("pbs_plus_device_compilations_total",
               "Programs jax built or loaded from its cache since the "
               "device ops were loaded; one that moves while backups run "

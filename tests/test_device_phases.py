@@ -314,6 +314,9 @@ def test_metrics_render_every_new_gauge(tmp_path):
     for state in ("scan", "sha", "idle", "linger"):
         assert (f'pbs_plus_feeder_thread_seconds_total{{state="{state}"}}'
                 in expo)
+    for outcome in ("joined", "alone"):
+        assert (f'pbs_plus_feeder_linger_rounds_total{{outcome="{outcome}"}}'
+                in expo)
     for kind in ("scan", "sha"):
         assert f'pbs_plus_feeder_requests_total{{kind="{kind}"}}' in expo
         assert (f'pbs_plus_feeder_dispatch_seconds_count{{kind="{kind}"}}'
@@ -324,3 +327,26 @@ def test_metrics_render_every_new_gauge(tmp_path):
                  "device_table_uploads", "device_table_upload_bytes",
                  "feeder_rounds"):
         assert f"pbs_plus_{name}_total " in expo, name
+
+
+# --- how many devices a scan's rows sat on (ISSUE 26) ---------------------
+
+@pytest.mark.parametrize("rows,devices", [(1, 1), (2, 8), (5, 8)])
+def test_scan_span_names_the_devices_its_rows_sat_on(rows, devices, spans):
+    """On the suite's eight virtual devices a dispatch of one row stays
+    on one device and one of two or more is sharded over the data mesh:
+    the ``device.scan`` span says which, with the gauge's value."""
+    import jax
+    assert len(jax.devices()) == 8
+    before = rolling_hash.stats["mesh_dispatches"]
+    rolling_hash.batched_candidate_hits(
+        [_bytes(30_000, 40 + i) for i in range(rows)], [None] * rows,
+        rolling_hash.device_tables(P), P)
+    scan, = [r for r in spans if r["name"] == "device.scan"]
+    assert scan["attrs"]["devices"] == devices
+    assert scan["attrs"]["rows"] == rows
+    sharded = int(rows > 1)
+    assert rolling_hash.stats["mesh_dispatches"] - before == sharded
+    if sharded:
+        assert rolling_hash.stats["mesh_shard_devices"] == devices
+        assert scan["attrs"]["padded_rows"] % devices == 0

@@ -345,3 +345,85 @@ def test_feeder_thread_carries_its_name_at_the_os(wide_feeder):
         pytest.skip("no /proc thread names here")
     with open(comm, encoding="utf-8") as f:
         assert f.read().strip() == "device-feeder"
+
+
+# --- lingers, and whether anyone joined them (ISSUE 26) --------------------
+
+def _dispatch_spans(run):
+    """The ``feeder.dispatch`` spans closed while ``run()`` ran."""
+    from pbs_plus_tpu.utils import trace
+    spans: list[dict] = []
+    trace.subscribe(spans.append)
+    try:
+        run()
+    finally:
+        trace.unsubscribe(spans.append)
+    return [r["attrs"] for r in spans if r["name"] == "feeder.dispatch"]
+
+
+def _scan(feeder, seed):
+    return feeder.candidate_hits(
+        np.frombuffer(_data(40_000, seed=seed), np.uint8),
+        np.zeros(63, np.uint8), P)
+
+
+def test_a_round_that_lingers_alone_is_counted_and_not_joined(wide_feeder):
+    """One session: its request is held for the linger window, nobody
+    joins, and the round's span says both."""
+    attrs = _dispatch_spans(lambda: _scan(wide_feeder, 1))
+    st = wide_feeder.stats
+    assert (st["rounds"], st["linger_rounds"], st["linger_joined"]) \
+        == (1, 1, 0)
+    assert st["linger_s"] >= 0.04 and st["max_mask_batch"] == 1
+    assert [(a["reqs"], a["lingered"], a["joined"]) for a in attrs] \
+        == [(1, 1, 0)]
+
+
+def test_a_round_joined_while_it_lingers_is_counted_as_joined(monkeypatch):
+    """A second request that arrives inside the linger window ends the
+    wait early, rides in the same dispatch, and is counted."""
+    import time
+    feeder = DeviceFeeder(linger_s=30.0)
+    monkeypatch.setattr(feeder_mod, "_feeder", feeder)
+    _scan(DeviceFeeder(linger_s=0), 2)          # compile outside the timing
+    first = threading.Thread(target=_scan, args=(feeder, 3))
+
+    def both():
+        first.start()
+        # the feeder's thread books its idle time, decides to linger and
+        # only then lets go of the lock a second submit needs
+        deadline = time.perf_counter() + 20
+        while feeder.stats["idle_s"] == 0.0:
+            assert time.perf_counter() < deadline
+            time.sleep(0.001)
+        _scan(feeder, 4)
+        first.join(20)
+    t0 = time.perf_counter()
+    attrs = _dispatch_spans(both)
+    assert not first.is_alive() and time.perf_counter() - t0 < 25
+    st = feeder.stats
+    assert (st["rounds"], st["linger_rounds"], st["linger_joined"]) \
+        == (1, 1, 1)
+    assert st["mask_rows"] == 2 and st["max_mask_batch"] == 2
+    assert [(a["reqs"], a["lingered"], a["joined"]) for a in attrs] \
+        == [(2, 1, 1)]
+
+
+@pytest.mark.parametrize("linger_s,threads", [(0.0, 1), (0.0, 4),
+                                              (0.05, 4)])
+def test_linger_counts_stay_in_order(monkeypatch, linger_s, threads):
+    """``linger_joined`` <= ``linger_rounds`` <= ``rounds`` whatever the
+    traffic; a feeder that never lingers counts none."""
+    feeder = DeviceFeeder(linger_s=linger_s)
+    monkeypatch.setattr(feeder_mod, "_feeder", feeder)
+    attrs = _dispatch_spans(lambda: _drive(feeder, threads, 3))
+    st = feeder.stats
+    assert 0 <= st["linger_joined"] <= st["linger_rounds"] <= st["rounds"]
+    assert st["rounds"] > 0
+    if linger_s == 0.0:
+        assert st["linger_rounds"] == 0
+        assert not any(a["lingered"] or a["joined"] for a in attrs)
+    assert all(a["joined"] <= a["lingered"] for a in attrs)
+    # a round serves a scan group and a hash round at most: two spans
+    assert st["linger_rounds"] <= sum(a["lingered"] for a in attrs) \
+        <= 2 * st["linger_rounds"]
