@@ -124,7 +124,8 @@ class CpuChunker:
     W-1 tail carry, the feed coalescing, and the shared greedy pass
     (``spec.select_cuts``) are structural — cut-point parity between
     them reduces to candidate-set parity.  (The tpu/sidecar chunkers
-    carry their own streaming state and do not coalesce.)"""
+    carry their own streaming state; the tpu one gathers its writes by
+    a rule of its own — fixed 4 MiB device rows, models/dedup.py.)"""
 
     backend_name = "cpu"
 

@@ -20,10 +20,21 @@ work is proportional to the number of chunks, not bytes.
 Streams are processed in fixed-shape segments with 63-byte history halos so
 jit caches stay small and results are bit-identical to the streaming CPU
 chunker (same spec, same shared greedy pass).
+
+When a stream's bytes reach the device: ``DedupPipeline`` takes whole
+streams and scans them in ``segment_bytes`` rows.  A streaming session
+(``TpuChunker``, the ``chunker="tpu"`` backend) is written to a few
+bytes or a few MiB at a time — a tree of 1,024 files is some 2,100
+writes — and a device round trip costs milliseconds whatever it carries,
+so the chunker gathers the writes and sends a stream to the device in
+full ``SCAN_SEGMENT`` rows (4 MiB), plus one request for the rest at
+every flush and at the end: about sixty round trips for that tree's 235
+MiB, where one per write made 2,100 (PERF.md section 6, PR 27).
 """
 
 from __future__ import annotations
 
+from collections import deque
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -34,6 +45,15 @@ from ..ops.cuckoo import CuckooIndex
 from ..ops.rolling_hash import (batched_candidate_hits, device_tables,
                                 segment_class)
 from ..ops.sha256 import sha256_streams_chunks
+from ..utils.conf import STREAM_BUFFER_SIZE
+
+# What one scan request of a stream carries at most, and what a stream
+# collects before it asks for a scan (TpuChunker): upstream's stream
+# buffer.  One value for every stream and deployment; as a segment class
+# of the scan, a full segment travels without padding.
+SCAN_SEGMENT = STREAM_BUFFER_SIZE
+assert segment_class(SCAN_SEGMENT) == SCAN_SEGMENT, \
+    "the scan segment must be one of the scan's padded segment lengths"
 
 
 @dataclass(frozen=True)
@@ -190,10 +210,26 @@ class TpuChunker:
     """chunker-interface adapter: feed/finalize returning absolute cut
     offsets, computed by the device kernel.  Drop-in for CpuChunker in
     transfer writers (``chunker="tpu"`` — the one-line config change from
-    BASELINE.json).  Buffers segment bytes host-side; candidate evaluation
-    goes through the process-wide DeviceFeeder, which coalesces concurrent
-    streams' feeds into ``[B, S]`` batched dispatches (the production
-    batch axis — models/feeder.py)."""
+    BASELINE.json).
+
+    A stream goes to the device in full scan segments, not once per
+    write: ``feed`` keeps the write with the stream's un-scanned bytes
+    and returns no cut until they fill ``SCAN_SEGMENT``; then it submits
+    exactly one segment (a longer write is sliced into several) through
+    the process-wide DeviceFeeder, which coalesces concurrent streams'
+    segments into ``[B, S]`` batched dispatches (the production batch
+    axis — models/feeder.py).  ``finalize`` scans what is left, as one
+    request at its own segment class, and only then forces the last cut.
+    The cuts are those of a whole-stream scan however the writes fall:
+    candidates are position-local (63 bytes of history) and
+    ``select_cuts`` is handed the bytes scanned so far as the stream's
+    length, so a cut is only ever returned later than a per-write scan
+    would have returned it, never elsewhere.
+
+    Memory: the un-scanned bytes are references to the writes
+    themselves, which the writer's ``_ChunkBuffer`` holds anyway (up to
+    ``max_size``) — under one segment a stream; a segment that spans
+    writes is joined into one array for the time of its scan."""
 
     # per-session bound-backend label (transfer._ChunkedStream picks it
     # up at bind time; rendered in job stats and /metrics)
@@ -202,37 +238,75 @@ class TpuChunker:
     def __init__(self, params: ChunkerParams):
         self.params = params
         self._tail = np.zeros(WINDOW - 1, dtype=np.uint8)
-        self._seen = 0
+        self._seen = 0                  # bytes scanned
+        # writes not yet scanned, in order: under SCAN_SEGMENT bytes
+        # whenever ``feed`` returns
+        self._parts: deque[np.ndarray] = deque()
+        self._pending = 0
+        self._req_feeds = 1             # writes the request under way holds
         self._chunk_start = 0
         self._cand: list[int] = []
         self._cand_drained = 0
         self._finalized = False
 
     def _candidates(self, data: np.ndarray) -> np.ndarray:
+        """Absolute candidate ends for the bytes of ``data``, which
+        directly follow the bytes already scanned: one request to the
+        feeder, of at most ``SCAN_SEGMENT`` bytes."""
         from .feeder import get_feeder
         try:
-            hits = get_feeder().candidate_hits(data, self._tail, self.params)
+            hits = get_feeder().candidate_hits(data, self._tail, self.params,
+                                               feeds=self._req_feeds)
         except Exception as e:
             raise DeviceDispatchError("candidate scan", e) from e
         valid = hits + self._seen >= WINDOW - 1
         return hits[valid] + 1 + self._seen
+
+    def _take(self, n: int) -> tuple[np.ndarray, int]:
+        """The first ``n`` un-scanned bytes as one array (no copy where
+        one write holds them all), and how many writes they came from."""
+        out, got = [], 0
+        while got < n:
+            part = self._parts.popleft()
+            if len(part) > n - got:
+                self._parts.appendleft(part[n - got:])
+                part = part[:n - got]
+            out.append(part)
+            got += len(part)
+        self._pending -= n
+        return (out[0] if len(out) == 1 else np.concatenate(out)), len(out)
+
+    def _scan(self, n: int) -> None:
+        """The first ``n`` un-scanned bytes go to the device, as one
+        request."""
+        # ``_candidates`` keeps its one argument (the benchmark's control
+        # wraps it), so the count rides on the chunker
+        seg, self._req_feeds = self._take(n)
+        self._cand.extend(self._candidates(seg).tolist())
+        self._seen += n
+        # the history of the next request is the end of this one
+        self._tail = np.concatenate([self._tail, seg[-(WINDOW - 1):]]
+                                    )[-(WINDOW - 1):]
 
     def feed(self, data: bytes) -> list[int]:
         if self._finalized:
             raise RuntimeError("chunker already finalized")
         if not data:
             return []
-        arr = np.frombuffer(data, dtype=np.uint8)
-        self._cand.extend(self._candidates(arr).tolist())
-        self._seen += len(arr)
-        joined = np.concatenate([self._tail, arr])
-        self._tail = joined[-(WINDOW - 1):]
+        self._parts.append(np.frombuffer(data, dtype=np.uint8))
+        self._pending += len(data)
+        if self._pending < SCAN_SEGMENT:
+            return []
+        while self._pending >= SCAN_SEGMENT:
+            self._scan(SCAN_SEGMENT)
         return self._drain(final=False)
 
     def finalize(self) -> list[int]:
         if self._finalized:
             return []
         self._finalized = True
+        if self._pending:
+            self._scan(self._pending)
         return self._drain(final=True)
 
     def _drain(self, final: bool) -> list[int]:

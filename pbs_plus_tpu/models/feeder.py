@@ -2,12 +2,21 @@
 
 The missing half of the batch-axis thesis (BASELINE config #3): N
 concurrent backup jobs each drive their own writer thread, and every
-writer owns a streaming ``TpuChunker``.  Without aggregation each feed
-dispatches its own ``[1, S]`` candidate kernel and its own SHA batch, so
-the device never sees the agent fan-in.  The reference multiplexes N
-agents into one server process (internal/server/jobs/manager.go:168-179,
+writer owns a streaming ``TpuChunker``.  Without aggregation each
+stream's scan is a ``[1, S]`` candidate kernel of its own, so the device
+never sees the agent fan-in.  The reference multiplexes N agents into
+one server process (internal/server/jobs/manager.go:168-179,
 internal/conf/buffer.go:33-38); here that multiplexing is carried one
 level further — onto the device batch axis.
+
+When a stream's bytes come here: not once per write.  A ``TpuChunker``
+gathers its stream's writes and asks for a scan once they fill a 4 MiB
+segment (``models.dedup.SCAN_SEGMENT``, upstream's stream buffer and one
+of the scan's padded lengths), and once more for what is left when the
+stream is flushed or ends.  So a request is a full row, or a stream's
+last; ``feeds`` on a request says how many writes it holds, summed into
+``stats["mask_feeds"]`` beside ``mask_rows`` and onto the
+``feeder.dispatch`` span.
 
 Mechanics (single dispatch thread, adaptive batching via backpressure):
 
@@ -102,6 +111,7 @@ class _MaskReq(_Req):
     history: np.ndarray             # uint8[WINDOW-1]
     key: tuple                      # (seed, mask, magic) — batch group key
     params: ChunkerParams
+    feeds: int = 1                  # writes of its stream ``buf`` holds
     hits: Optional[np.ndarray] = None    # relative candidate end indices
 
 
@@ -127,6 +137,9 @@ class DeviceFeeder:
         self._thread: Optional[threading.Thread] = None
         self._tables_cache: dict[tuple, object] = {}   # params key → device tables
         self.stats = {"mask_dispatches": 0, "mask_rows": 0,
+                      # writes the rows held: a stream's chunker gathers
+                      # them into segments (models/dedup.py TpuChunker)
+                      "mask_feeds": 0,
                       "max_mask_batch": 0, "mask_retried_alone": 0,
                       "sha_dispatches": 0, "sha_streams": 0,
                       "max_sha_streams": 0, "sha_retried_alone": 0,
@@ -144,13 +157,15 @@ class DeviceFeeder:
 
     # -- public API (writer threads) --------------------------------------
     def candidate_hits(self, buf: np.ndarray, history: np.ndarray,
-                       params: ChunkerParams) -> np.ndarray:
+                       params: ChunkerParams, *, feeds: int = 1,
+                       ) -> np.ndarray:
         """Relative candidate end indices (0-based positions where the
         rolling hash matched) within ``buf``.  Blocks the calling writer
-        thread until the batched dispatch lands."""
+        thread until the batched dispatch lands.  ``feeds``: how many
+        writes of its stream the caller gathered into ``buf``."""
         req = _MaskReq(buf=buf, history=history,
                        key=(params.seed, params.mask, params.magic),
-                       params=params)
+                       params=params, feeds=feeds)
         self._submit(self._mask_q, req)
         req.done.wait()
         if req.exc is not None:
@@ -268,6 +283,7 @@ class DeviceFeeder:
                                       self._tables(key, params), params)
         self.stats["mask_dispatches"] += 1
         self.stats["mask_rows"] += len(group)
+        self.stats["mask_feeds"] += sum(r.feeds for r in group)
         return hits
 
     def _begin(self, kind: str, reqs: list) -> dict:
@@ -285,6 +301,7 @@ class DeviceFeeder:
 
     def _dispatch_mask_group(self, key: tuple, group: list[_MaskReq]) -> None:
         with trace.span("feeder.dispatch",
+                        feeds=sum(r.feeds for r in group),
                         **self._begin("scan", group)) as sp, \
                 trace.annotation("feeder.dispatch"):
             try:

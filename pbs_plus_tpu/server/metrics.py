@@ -585,6 +585,10 @@ class MetricsRegistry:
               [({"kind": kind}, float(fd[key]))
                for kind, key in (("scan", "mask_rows"),
                                  ("sha", "sha_streams")) if key in fd])
+        gauge("pbs_plus_feeder_scan_feeds_total",
+              "Writes of their streams the scan rows carried: a stream's "
+              "chunker gathers its writes into 4 MiB scan segments",
+              [({}, float(fd["mask_feeds"]))] if "mask_feeds" in fd else [])
         gauge("pbs_plus_feeder_rounds_total",
               "Rounds of the device batcher: one drain of both queues, "
               "served as a scan dispatch per chunker key and one hash "

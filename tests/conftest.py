@@ -51,6 +51,30 @@ def fs_witness(request):
     w.assert_clean()
 
 
+@pytest.fixture
+def hold_requests():
+    """``hold(feeder, n)``: the first ``n`` requests submitted to a fresh
+    ``DeviceFeeder`` are queued without a word to its thread, which
+    hears of them when the ``n``-th arrives — so one round carries all
+    ``n``, for certain and not by the luck of a linger.  A stream's
+    chunker asks for a scan once a segment (models/dedup.py), so short
+    streams make one request each and nothing else lets a test count on
+    two of them meeting."""
+    def hold(feeder, n: int) -> None:
+        real, left = feeder._submit, n
+
+        def submit(q, req):
+            nonlocal left
+            with feeder._cv:
+                left -= 1
+                if left > 0:
+                    q.append(req)
+                    return
+            real(q, req)
+        feeder._submit = submit
+    return hold
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers",

@@ -41,15 +41,20 @@ async def _spawn_agent(server, cfg, tmp_path, name: str):
     return agent, task
 
 
-def test_fanin_8_agents_tpu_chunker(tmp_path, monkeypatch):
+def test_fanin_8_agents_tpu_chunker(tmp_path, monkeypatch, hold_requests):
     import pbs_plus_tpu.models.feeder as feeder_mod
     from pbs_plus_tpu.ops import rolling_hash as scan_ops
     from pbs_plus_tpu.ops import sha256 as sha_ops
 
     # fresh feeder with a wide linger so the concurrent writers' device
-    # work reliably coalesces (we assert on its stats below)
+    # work coalesces (we assert on its stats below).  A stream of under
+    # a scan segment asks for its one scan when it ends, so a session
+    # here makes two requests in all: the first of each of the four
+    # sessions the job slots admit is held until all four are queued,
+    # and the four ride in one dispatch for certain.
     feeder = feeder_mod.DeviceFeeder(linger_s=0.05)
     monkeypatch.setattr(feeder_mod, "_feeder", feeder)
+    hold_requests(feeder, 4)
 
     async def main():
         cfg = ServerConfig(
@@ -134,8 +139,10 @@ def test_fanin_8_agents_tpu_chunker(tmp_path, monkeypatch):
         # concurrently, the feeder coalesced different streams' segments
         # into at least one multi-row [B, S] device dispatch, and fewer
         # dispatches ran than requests were made
-        assert feeder.stats["max_mask_batch"] > 1, \
+        assert feeder.stats["max_mask_batch"] >= 4, \
             f"no cross-stream device batch formed: {feeder.stats}"
+        assert feeder.stats["mask_feeds"] > feeder.stats["mask_rows"] \
+            == 2 * N_AGENTS, feeder.stats
         assert feeder.stats["mask_dispatches"] \
             < feeder.stats["mask_rows"], feeder.stats
 

@@ -30,11 +30,15 @@ def _data(n, seed):
                                                 ).tobytes()
 
 
-def test_concurrent_streams_batch_with_bit_parity(wide_feeder):
+def test_concurrent_streams_batch_with_bit_parity(wide_feeder,
+                                                  hold_requests):
     """8 writer threads drive TpuChunkers through the feeder at once:
     cuts are bit-identical to the CPU chunker AND at least one device
-    dispatch carried B > 1 rows (the batch axis actually ran)."""
+    dispatch carried B > 1 rows (the batch axis actually ran).  A stream
+    shorter than a scan segment asks for one scan, at ``finalize``; the
+    eight are held until all are queued, so that they meet."""
     n_threads = 8
+    hold_requests(wide_feeder, n_threads)
     datas = [_data(200_000, seed=i) for i in range(n_threads)]
     cuts_tpu: dict[int, list] = {}
     errs: list[BaseException] = []
@@ -188,13 +192,15 @@ def test_failed_hash_round_is_retried_alone_and_counted(wide_feeder):
 
 
 def test_failed_scan_batch_is_retried_alone_and_counted(wide_feeder,
-                                                        monkeypatch):
+                                                        monkeypatch,
+                                                        hold_requests):
     """A batched scan dispatch that raises (a compile error, an HBM
     overflow) is retried request by request — every stream still gets
     its own hits — and each retry is counted; a request that fails alone
     too gets the exception, wrapped by name at the session."""
     from pbs_plus_tpu.models.dedup import DeviceDispatchError
     real = wide_feeder._mask_hits
+    hold_requests(wide_feeder, 4)       # one request a stream: one batch
 
     def batches_fail(key, group):
         if len(group) > 1:
@@ -219,15 +225,17 @@ def test_failed_scan_batch_is_retried_alone_and_counted(wide_feeder,
     for i in range(n):
         cpu = CpuChunker(P)
         assert cuts[i] == cpu.feed(datas[i]) + cpu.finalize()
-    assert wide_feeder.stats["mask_retried_alone"] >= 2, wide_feeder.stats
+    assert wide_feeder.stats["mask_retried_alone"] == n, wide_feeder.stats
     assert wide_feeder.stats["max_mask_batch"] <= 1     # no batch landed
 
     def all_fail(key, group):
         raise MemoryError("injected: device lost")
     monkeypatch.setattr(wide_feeder, "_mask_hits", all_fail)
+    ch = TpuChunker(P)
+    assert ch.feed(datas[0]) == []      # under a segment: no scan yet
     with pytest.raises(DeviceDispatchError, match="candidate scan failed: "
                                                   "MemoryError: injected"):
-        TpuChunker(P).feed(datas[0])
+        ch.finalize()
 
 
 def test_empty_sha_batch_is_noop(wide_feeder):
@@ -427,3 +435,141 @@ def test_linger_counts_stay_in_order(monkeypatch, linger_s, threads):
     # a round serves a scan group and a hash round at most: two spans
     assert st["linger_rounds"] <= sum(a["lingered"] for a in attrs) \
         <= 2 * st["linger_rounds"]
+
+
+# --- a stream's writes, gathered into scan segments (ISSUE 27) --------------
+
+MIB = 1 << 20
+
+
+@pytest.fixture
+def lone_feeder(monkeypatch):
+    """A fresh process-wide feeder that never lingers, its scan requests'
+    lengths and ``feeds`` noted as they are submitted."""
+    f = DeviceFeeder(linger_s=0.0)
+    monkeypatch.setattr(feeder_mod, "_feeder", f)
+    f.requests = []
+    real = f.candidate_hits
+
+    def noted(buf, history, params, *, feeds=1):
+        f.requests.append((len(buf), feeds))
+        return real(buf, history, params, feeds=feeds)
+    monkeypatch.setattr(f, "candidate_hits", noted)
+    return f
+
+
+def _feed_in(ch, data, sizes):
+    cuts, off = [], 0
+    for n in sizes:
+        cuts += ch.feed(data[off:off + n])
+        off += n
+    assert off == len(data)
+    return cuts + ch.finalize()
+
+
+@pytest.mark.parametrize("sizes", [
+    [16] * 2000, [50_000] * 200, [4 * MIB] * 2, [4 * MIB + 1],
+    [9 * MIB], [3 * MIB, 3 * MIB, 3 * MIB, 100], [100, 8 * MIB - 100]],
+    ids=["headers", "small_files", "full_blocks", "a_block_and_a_byte",
+         "one_9mib", "blocks_3mib", "a_header_then_two_segments"])
+def test_writes_reach_the_device_a_segment_at_a_time(lone_feeder, sizes):
+    """N feeds make at most ceil(bytes / segment) + 1 requests — one per
+    full segment, and what is left at ``finalize`` — none longer than
+    the segment, every one but the last a full one; and the segment is a
+    padded length of the scan, so a full one travels as it is."""
+    from pbs_plus_tpu.models.dedup import SCAN_SEGMENT
+    from pbs_plus_tpu.ops import rolling_hash
+    from pbs_plus_tpu.utils import conf
+    assert SCAN_SEGMENT == conf.STREAM_BUFFER_SIZE
+    assert SCAN_SEGMENT in rolling_hash._SEG_CLASSES
+    total = sum(sizes)
+    data = _data(total, seed=len(sizes))
+    params = ChunkerParams(avg_size=1 << 20)
+    cpu = CpuChunker(params)
+    assert _feed_in(TpuChunker(params), data, sizes) \
+        == cpu.feed(data) + cpu.finalize()
+    lens = [n for n, _ in lone_feeder.requests]
+    assert sum(lens) == total
+    # the bound asked for is one more: a remainder beside the full ones
+    assert len(lens) == -(-total // SCAN_SEGMENT)
+    assert all(n == SCAN_SEGMENT for n in lens[:-1])
+    assert 0 < lens[-1] <= SCAN_SEGMENT
+    # every write is in the request(s) that hold its bytes
+    assert sum(f for _, f in lone_feeder.requests) >= len(sizes)
+    assert all(f >= 1 for _, f in lone_feeder.requests)
+
+
+@pytest.mark.parametrize("sessions", [1, 4])
+def test_feeds_are_counted_where_rows_are(lone_feeder, sessions):
+    """``mask_feeds`` is summed where ``mask_rows`` is: at least one
+    write a row, all the writes of every stream, and the
+    ``feeder.dispatch`` spans' ``feeds`` add up to it."""
+    sizes = [16, 3000] * 40 + [5 * MIB]
+    errs: list[BaseException] = []
+
+    def work(i):
+        try:
+            _feed_in(TpuChunker(P), _data(sum(sizes), seed=50 + i), sizes)
+        except BaseException as e:
+            errs.append(e)
+
+    def run():
+        threads = [threading.Thread(target=work, args=(i,))
+                   for i in range(sessions)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+            assert not t.is_alive()
+    attrs = _dispatch_spans(run)
+    assert not errs, errs
+    st = lone_feeder.stats
+    assert st["mask_rows"] == 2 * sessions      # a segment and the rest
+    assert st["mask_feeds"] == (len(sizes) + 1) * sessions
+    assert st["mask_feeds"] >= st["mask_rows"]
+    assert sum(a["feeds"] for a in attrs) == st["mask_feeds"]
+    assert sum(a["reqs"] for a in attrs) == st["mask_rows"]
+
+
+@pytest.mark.parametrize("where", ["segment", "finalize"])
+def test_a_scan_that_fails_fails_its_own_session_only(
+        wide_feeder, monkeypatch, hold_requests, where):
+    """A request the device refuses — a full segment in the middle of a
+    stream, or what ``finalize`` sends — raises ``DeviceDispatchError``
+    in the session that made it and in no other, though the two rode in
+    one batch."""
+    from pbs_plus_tpu.models.dedup import SCAN_SEGMENT, DeviceDispatchError
+    bad_len = SCAN_SEGMENT if where == "segment" else 12_345
+    bad_sizes = [70_000, SCAN_SEGMENT - 70_000, 12_345] \
+        if where == "segment" else [12_000, 345]
+    real = wide_feeder._mask_hits
+
+    def refuses(key, group):
+        if any(len(r.buf) == bad_len for r in group):
+            raise MemoryError("injected: this row does not fit")
+        return real(key, group)
+    monkeypatch.setattr(wide_feeder, "_mask_hits", refuses)
+    hold_requests(wide_feeder, 2)       # the two first requests: one batch
+    good = _data(90_000, seed=61)
+    got: dict = {}
+
+    def session(name, data, sizes):
+        try:
+            got[name] = _feed_in(TpuChunker(P), data, sizes)
+        except BaseException as e:
+            got[name] = e
+    threads = [
+        threading.Thread(target=session, args=("good", good, [90_000])),
+        threading.Thread(target=session, args=(
+            "bad", _data(sum(bad_sizes), seed=62), bad_sizes))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(120)
+        assert not t.is_alive()
+    cpu = CpuChunker(P)
+    assert got["good"] == cpu.feed(good) + cpu.finalize()
+    assert isinstance(got["bad"], DeviceDispatchError), got["bad"]
+    assert "candidate scan failed: MemoryError: injected" in str(got["bad"])
+    assert wide_feeder.stats["mask_retried_alone"] == 2
+    assert wide_feeder.stats["mask_rows"] == 1      # the good one, alone

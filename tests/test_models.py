@@ -57,6 +57,84 @@ def test_tpu_chunker_drop_in():
         assert got_c == got_t
 
 
+# --- a stream reaches the device in scan segments (ISSUE 27) ---------------
+
+MIB = 1 << 20
+FLUSH = None        # in a list of writes: ``flush_chunker`` / ``sync`` here
+
+
+def _write_sizes(pattern: str, rng) -> list:
+    """The sizes of a stream's writes, as a session writer makes them:
+    16-byte headers, files under 64 KiB, read-ahead blocks of exactly
+    4 MiB, one write longer than two segments."""
+    files = [int(n) for n in rng.integers(1, 65537, 300)]
+    mix = [16, 70_000, 16, 4 * MIB, 16, 1, 16, 9 * MIB, 16, *files[:20],
+           4 * MIB, 16, 333]
+    return {
+        "headers": [16] * 3000,
+        "small_files": files,
+        "blocks_4mib": [4 * MIB] * 3,           # nothing left at finalize
+        "one_9mib": [9 * MIB],
+        "mix": mix,
+        "mix_flushed": mix[:7] + [FLUSH] + mix[7:12] + [FLUSH] + mix[12:],
+        "flushed_at_a_segment_end": [16, 4 * MIB - 16, FLUSH, 5 * MIB,
+                                     3 * MIB, FLUSH, 100],
+    }[pattern]
+
+
+class _Records:
+    """A store that keeps nothing: the stream's records are the result."""
+
+    def insert(self, digest, data, *, verify=True):
+        return True
+
+    def touch(self, digest):
+        pass
+
+
+def _stream_cuts(params, factory, data, sizes, end="finish"):
+    from pbs_plus_tpu.pxar.transfer import _ChunkedStream
+    s = _ChunkedStream(_Records(), params, chunker_factory=factory)
+    off = 0
+    for i, n in enumerate(sizes):
+        if n is FLUSH:      # its own assertion: nothing stays un-cut
+            s.flush_chunker() if i % 2 else s.sync()
+            continue
+        s.write(data[off:off + n])
+        off += n
+    assert off == len(data)
+    return [end for end, _ in s.finish()]
+
+
+@pytest.mark.parametrize("avg", [4 * MIB, 64 << 10], ids=["avg4m", "avg64k"])
+@pytest.mark.parametrize("pattern", [
+    "headers", "small_files", "blocks_4mib", "one_9mib", "mix",
+    "mix_flushed", "flushed_at_a_segment_end"])
+def test_cuts_do_not_depend_on_how_the_stream_is_written(pattern, avg):
+    """However the writes fall on the scan segments, ``TpuChunker``'s
+    cuts are ``CpuChunker``'s, and those of each run fed whole."""
+    rng = np.random.default_rng([27, len(pattern)])
+    sizes = _write_sizes(pattern, rng)
+    data = rng.integers(0, 256, sum(n for n in sizes if n is not FLUSH),
+                        dtype=np.uint8).tobytes()
+    params = ChunkerParams(avg_size=avg)
+    got = _stream_cuts(params, TpuChunker, data, sizes)
+    assert got == _stream_cuts(params, CpuChunker, data, sizes)
+    assert got[-1] == len(data)
+    # each run between two flushes, as one feed of a chunker of its own
+    whole, base, run = [], 0, 0
+    for n in sizes + [FLUSH]:
+        if n is not FLUSH:
+            run += n
+            continue
+        if run:
+            ch = TpuChunker(params)
+            whole += [base + c for c in ch.feed(data[base:base + run])
+                      + ch.finalize()]
+        base, run = base + run, 0
+    assert got == whole
+
+
 def test_tpu_chunker_in_session_writer(tmp_path):
     """chunker='tpu' is a one-line writer swap; archives are identical."""
     import io
