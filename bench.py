@@ -1,27 +1,11 @@
 #!/usr/bin/env python3
-"""Benchmark: chunk + fingerprint throughput, TPU pipeline vs CPU baseline.
+"""Host-side counts and rates of the data plane, as ONE JSON line.
 
-Prints ONE JSON line:
-    {"metric": "chunk+fingerprint MiB/s/chip", "value": N,
-     "unit": "MiB/s", "vs_baseline": R, ...detail...}
-
-- metric: aggregate content-defined-chunking + SHA-256 fingerprinting
-  throughput of the device pipeline over a batch of agent streams
-  (BASELINE.md: "MiB/s/chip chunk+fingerprint throughput").
-- vs_baseline: ratio vs the measured single-core CPU baseline (native C++
-  buzhash scan + OpenSSL sha256 — the reference's Go hot loop equivalent;
-  the reference publishes no numbers, SURVEY §6, so the baseline is
-  measured here on the same data).
-- Correctness gates run first: device cuts and digests must be
-  bit-identical to the CPU implementations on a parity sample.
-
-Workload: synthetic mixed-entropy agent streams generated ON DEVICE
-(BASELINE.json config #3 shape — batched fan-in).  It times kernels over
-resident data, not the served path; ROADMAP S1 replaces it with cells.
-
-Self-calibrating: sweeps the sha block-unroll and picks the best measured
-configuration; on the CPU backend it is a CPU-only run (vs_baseline
-computed against itself = 1.0).
+``value`` is the measured single-core CPU baseline (native C++ buzhash
+scan + OpenSSL sha256 — the reference's Go hot loop equivalent; SURVEY
+§6); ``detail`` carries the sections below it (pipeline, resume, read,
+fleet, indexes, delta …), each with its own in-run parity gates.  No
+chip number: that is ``BENCHMARK.json`` + ``benchmark/run.py`` (PERF.md).
 """
 
 from __future__ import annotations
@@ -265,193 +249,6 @@ def _observability_bench(mib: int = 48) -> dict:
     }
 
 
-def _ingest_fusion_bench(mib_per_session: float = 1.0,
-                         session_counts: tuple = (1, 8, 32)) -> dict:
-    """Fused cross-session ingest vs per-session staged (ISSUE 13 /
-    ROADMAP item 2, docs/data-plane.md "Fused ingest"): batched-stage
-    dispatches per flushed chunk at N concurrent sessions — the
-    fleetsim data-plane shape, N writer threads over ONE shared
-    dedup-indexed store.  "Dispatch" = one entry into a batched stage
-    implementation (CDC scan / SHA-256 / index probe / presketch — the
-    pack/dispatch/unpack boundary).  The staged baseline counts every
-    per-session stage call via wrappers; the fused path reads the
-    ops.ingest + ingestbatch counters.  Cuts and digests are asserted
-    bit-identical in-run, per session.  The ≥3x dispatch reduction at
-    N=32 is gated in tests/test_bench_harness.py; N=1 is reported
-    honestly (fusion trades per-flush stage deferral for the bounded
-    flush deadline, so a lone session pays MORE stage dispatches)."""
-    import hashlib
-    import shutil
-    import tempfile
-    import threading
-
-    import numpy as np
-    from pbs_plus_tpu.chunker import ChunkerParams, CpuChunker
-    from pbs_plus_tpu.ops import ingest as ingest_ops
-    from pbs_plus_tpu.pxar import ingestbatch
-    from pbs_plus_tpu.pxar.datastore import ChunkStore
-    from pbs_plus_tpu.pxar.ingestbackend import IngestCapabilities
-    from pbs_plus_tpu.pxar.similarityindex import SimilarityIndex
-    from pbs_plus_tpu.pxar.transfer import _ChunkedStream
-
-    params = ChunkerParams(avg_size=16 << 10)
-    feed = 128 << 10
-    rng = np.random.default_rng(13)
-
-    class _CountingChunker(CpuChunker):
-        calls = 0
-
-        def _scan(self, data, prefix, global_offset):
-            type(self).calls += 1
-            return super()._scan(data, prefix, global_offset)
-
-    class _CountingStore:
-        """Counting proxy over the shared store: probe/presketch
-        dispatch counters + declared capabilities passthrough."""
-
-        def __init__(self, inner):
-            self._inner = inner
-            self.probe_calls = 0
-            self.presketch_calls = 0
-
-        def ingest_capabilities(self):
-            return self._inner.ingest_capabilities()
-
-        def probe_batch(self, digests):
-            self.probe_calls += 1
-            return self._inner.probe_batch(digests)
-
-        def presketch_batch(self, digests, chunks, known):
-            self.presketch_calls += 1
-            return self._inner.presketch_batch(digests, chunks, known)
-
-        def __getattr__(self, name):
-            return getattr(self._inner, name)
-
-    sha_calls = [0]
-
-    def counting_hasher(chunks):
-        sha_calls[0] += 1
-        return [hashlib.sha256(c).digest() for c in chunks]
-
-    def payloads_for(n):
-        return [rng.integers(0, 256, int(mib_per_session * (1 << 20)),
-                             dtype=np.uint8).tobytes() for _ in range(n)]
-
-    per_n = {}
-    for n in session_counts:
-        payloads = payloads_for(n)
-        total_bytes = sum(len(p) for p in payloads)
-
-        # -- staged baseline: N sessions, each its own 4-stage ladder --
-        tmp1 = tempfile.mkdtemp(prefix="pbs-ingest-staged-")
-        tmp2 = tempfile.mkdtemp(prefix="pbs-ingest-fused-")
-        try:
-            inner1 = ChunkStore(tmp1)
-            inner1.similarity = SimilarityIndex()
-            store1 = _CountingStore(inner1)
-            assert store1.ingest_capabilities() == IngestCapabilities(
-                probe=True, presketch=True)
-            _CountingChunker.calls = 0
-            sha_calls[0] = 0
-            staged_records = []
-            t0 = time.perf_counter()
-            for p in payloads:
-                st = _ChunkedStream(store1, params,
-                                    chunker_factory=_CountingChunker,
-                                    batch_hasher=counting_hasher)
-                for i in range(0, len(p), feed):
-                    st.write(p[i:i + feed])
-                staged_records.append(st.finish())
-            dt_staged = time.perf_counter() - t0
-            staged_dispatches = (_CountingChunker.calls + sha_calls[0]
-                                 + store1.probe_calls
-                                 + store1.presketch_calls)
-            chunks_total = sum(len(r) for r in staged_records)
-
-            # -- fused: same payloads, N writer threads, one collector --
-            inner2 = ChunkStore(tmp2)
-            inner2.similarity = SimilarityIndex()
-            coll = ingestbatch.IngestCollector(inner2, max_wait=0.05)
-            ops_base = dict(ingest_ops.stats)
-            ib_base = ingestbatch.metrics_snapshot()
-            fused_records: list = [None] * n
-            errors: list = []
-
-            def run(k):
-                try:
-                    fu = ingestbatch.FusedIngestStream(inner2, params,
-                                                       coll)
-                    p = payloads[k]
-                    for i in range(0, len(p), feed):
-                        fu.write(p[i:i + feed])
-                    fused_records[k] = fu.finish()
-                except BaseException as e:     # surfaced after join
-                    errors.append(e)
-
-            threads = [threading.Thread(target=run, args=(k,))
-                       for k in range(n)]
-            t0 = time.perf_counter()
-            for t in threads:
-                t.start()
-            for t in threads:
-                t.join()
-            dt_fused = time.perf_counter() - t0
-            if errors:
-                raise errors[0]
-            ib_now = ingestbatch.metrics_snapshot()
-            fused_dispatches = (
-                ingest_ops.stats["scan_dispatches"]
-                - ops_base["scan_dispatches"]
-                + ingest_ops.stats["sha_dispatches"]
-                - ops_base["sha_dispatches"]
-                + ib_now["probe_dispatches"] - ib_base["probe_dispatches"]
-                + ib_now["presketch_dispatches"]
-                - ib_base["presketch_dispatches"])
-            packed = ib_now["bytes_packed"] - ib_base["bytes_packed"]
-            padding = ib_now["padding_bytes"] - ib_base["padding_bytes"]
-            flushes = ib_now["flushes"] - ib_base["flushes"]
-            sessions_packed = (ib_now["sessions_packed"]
-                               - ib_base["sessions_packed"])
-
-            parity = fused_records == staged_records
-            assert parity, "fused vs staged cut/digest divergence"
-            staged_dpc = staged_dispatches / chunks_total
-            fused_dpc = fused_dispatches / chunks_total
-            per_n[str(n)] = {
-                "chunks": chunks_total,
-                "staged_dispatches": staged_dispatches,
-                "fused_dispatches": fused_dispatches,
-                "staged_dispatches_per_chunk": round(staged_dpc, 5),
-                "fused_dispatches_per_chunk": round(fused_dpc, 5),
-                "dispatch_reduction": round(staged_dpc / fused_dpc, 2)
-                if fused_dpc else 0.0,
-                "flushes": flushes,
-                "mean_sessions_per_flush": round(sessions_packed
-                                                 / flushes, 2)
-                if flushes else 0.0,
-                "occupancy": round(packed / (packed + padding), 4)
-                if packed + padding else 0.0,
-                "staged_mib_s": round(total_bytes / (1 << 20)
-                                      / dt_staged, 1),
-                "fused_mib_s": round(total_bytes / (1 << 20)
-                                     / dt_fused, 1),
-                "parity": parity,
-            }
-        finally:
-            shutil.rmtree(tmp1, ignore_errors=True)
-            shutil.rmtree(tmp2, ignore_errors=True)
-
-    top = str(max(session_counts))
-    return {
-        "mib_per_session": mib_per_session,
-        "per_n": per_n,
-        "dispatch_reduction_at_max_n": per_n[top]["dispatch_reduction"],
-        "occupancy_at_max_n": per_n[top]["occupancy"],
-        "parity": all(v["parity"] for v in per_n.values()),
-    }
-
-
 def _resume_bench(mib: int = 64) -> dict | None:
     """Crash-at-50% resume benchmark (docs/data-plane.md "Checkpointed
     resumable backups"): back a tree up with per-file checkpointing,
@@ -648,6 +445,13 @@ def _read_bench(mib: int = 64, *, window_kib: int = 128,
         shutil.rmtree(tmp, ignore_errors=True)
 
 
+def _index_probe_passes() -> int:
+    """Vectorized filter passes every DedupIndex of this process has
+    made so far, host and device twin together (one per probe batch)."""
+    from pbs_plus_tpu.utils.jaxenv import twin_counts
+    return sum(twin_counts.get("index.probe", {}).values())
+
+
 def _dedup_index_bench(n: int | None = None, *,
                        stat_sample: int = 20_000) -> dict:
     """Dedup-index benchmark (docs/data-plane.md "Dedup index"):
@@ -683,9 +487,11 @@ def _dedup_index_bench(n: int | None = None, *,
     hits = idx.probe_batch(digests)
     dt_cold = time.perf_counter() - t0
     assert all(hits), "member probe missed"
+    passes0 = _index_probe_passes()
     t0 = time.perf_counter()
     hits = idx.probe_batch(digests)
     dt_probe = time.perf_counter() - t0
+    probe_passes = _index_probe_passes() - passes0
     assert all(hits), "member probe missed"
 
     # negative-path probe rate over n NON-member probes
@@ -736,6 +542,7 @@ def _dedup_index_bench(n: int | None = None, *,
         "negative_probe_per_s": round(n / dt_neg, 1),
         "per_digest_stat_per_s": round(stat_per_s, 1),
         "batched_vs_stat": round(batched_per_s / stat_per_s, 1),
+        "batched_probe_passes": probe_passes,
         "false_positives": int(fps),
         "fp_rate_bound": 2 * SLOTS / 2.0 ** fp_bits,
         "stat_sample": k,
@@ -930,6 +737,7 @@ def _digestlog_bench(n: int | None = None, *,
         idx = DedupIndex(budget_mb=filter_mb, spill_dir=tmp,
                          resident_mb=resident_mb)
         m0 = _dl.metrics_snapshot()
+        probe_passes = 0
 
         def batches(seed):
             rng = np.random.default_rng(seed)
@@ -962,10 +770,12 @@ def _digestlog_bench(n: int | None = None, *,
             pending: list[bytes] = []
 
             def run_pending():
-                nonlocal spent, wrong
+                nonlocal spent, wrong, probe_passes
+                probe_passes -= _index_probe_passes()
                 t0 = time.perf_counter()
                 out = idx.probe_batch(pending)
                 spent += time.perf_counter() - t0
+                probe_passes += _index_probe_passes()
                 wrong += sum(1 for o in out if o is not expect)
                 pending.clear()
 
@@ -1031,6 +841,7 @@ def _digestlog_bench(n: int | None = None, *,
             "negative_probe_per_s": round(n / dt_neg, 1),
             "per_digest_stat_per_s": round(stat_per_s, 1),
             "batched_vs_stat": round(probe_per_s / stat_per_s, 1),
+            "batched_probe_passes": probe_passes,
             "peak_resident_bytes": peak_resident,
             "resident_bytes": idx.resident_bytes,
             "resident_vs_budget": round(peak_resident / budget, 3),
@@ -1683,132 +1494,12 @@ def _mountserve_bench(*, n_snapshots: int | None = None,
         shutil.rmtree(tmp, ignore_errors=True)
 
 
-def _tpu_pipeline(seconds_budget: float = 120.0) -> dict | None:
-    """Device pipeline: on-device streams → candidate kernel → host greedy
-    (sparse) → device sha over the resulting bounds.  Returns None on the
-    CPU backend."""
-    try:
-        import jax
-        import jax.numpy as jnp
-        import numpy as np
-        if jax.default_backend() == "cpu":
-            return None
-        from pbs_plus_tpu.chunker import ChunkerParams
-        from pbs_plus_tpu.chunker.spec import select_cuts
-        from pbs_plus_tpu.ops.rolling_hash import (
-            _candidate_mask_impl, device_tables)
-        from pbs_plus_tpu.ops.sha256 import sha256_chunks_device
-
-        def device_digests(stream, bounds, unroll):
-            # the device program: this is its pipeline's measurement
-            # (digests elsewhere come from the host: ops/sha256.py)
-            host = np.asarray(stream)
-            return sha256_chunks_device([host[s:e] for s, e in bounds],
-                                        unroll=unroll)
-
-        params = ChunkerParams(avg_size=4 << 20)
-        tables = device_tables(params)
-        B, S = 8, 64 << 20                       # 512 MiB per step
-
-        @jax.jit
-        def gen(seed):
-            key = jax.random.PRNGKey(seed)
-            return jax.random.randint(key, (B, S), 0, 256, dtype=jnp.uint8)
-
-        # sparse on-device candidate extraction: the mask itself is B*S
-        # bools — only the ~B*S/avg positions leave the device
-        MAXC = 8 * (B * S // params.avg_size) + 64
-
-        @jax.jit
-        def cand_positions(d):
-            m = _candidate_mask_impl(d, tables, jnp.uint32(params.mask),
-                                     jnp.uint32(params.magic))
-            idx = jnp.nonzero(m.reshape(-1), size=MAXC, fill_value=-1)[0]
-            return idx.astype(jnp.int32)
-
-        deadline = time.time() + seconds_budget
-
-        def bounds_from_positions(pos):
-            pos = pos[pos >= 0].astype(np.int64)
-            assert len(pos) < MAXC, "candidate buffer overflow"
-            fb = []
-            for b in range(B):
-                sel = pos[(pos >= b * S) & (pos < (b + 1) * S)]
-                ends = sel - b * S + 1
-                s = 0
-                for e in select_cuts(ends, S, params):
-                    fb.append((b * S + s, b * S + e))
-                    s = e
-            return fb
-
-        d = gen(1)
-        jax.block_until_ready(d)
-        pos0 = np.asarray(cand_positions(d))
-        flat_bounds = bounds_from_positions(pos0)
-        dflat = d.reshape(-1)
-
-        # --- calibration: sha unroll sweep (compile + steady run each) ----
-        best_unroll, best_dt = 16, float("inf")
-        for unroll in (8, 16, 32):
-            if time.time() > deadline:
-                break
-            try:
-                device_digests(dflat, flat_bounds, unroll=unroll)
-                t0 = time.perf_counter()
-                device_digests(dflat, flat_bounds, unroll=unroll)
-                dt = time.perf_counter() - t0
-                if dt < best_dt:
-                    best_unroll, best_dt = unroll, dt
-            except Exception:
-                continue
-
-        # --- parity gates -------------------------------------------------
-        import hashlib
-        from pbs_plus_tpu.chunker import candidates as cpu_candidates
-        host0 = np.asarray(d[0])
-        cpu_ends = cpu_candidates(host0, params)
-        p0 = pos0[(pos0 >= 0)].astype(np.int64)
-        dev_ends = p0[p0 < S] + 1
-        assert np.array_equal(cpu_ends, dev_ends), "cut parity failed"
-        digests = device_digests(dflat, flat_bounds[:4],
-                                       unroll=best_unroll)
-        for i, (s0, e0) in enumerate(flat_bounds[:4]):
-            b, off = divmod(s0, S)
-            want = hashlib.sha256(
-                np.asarray(d[b])[off:off + (e0 - s0)].tobytes()).digest()
-            assert digests[i] == want, "digest parity failed"
-
-        # --- timed steps (fresh data each iteration) ----------------------
-        times = []
-        it = 2
-        while len(times) < 3 and time.time() < deadline:
-            dd = gen(it)
-            jax.block_until_ready(dd)
-            t0 = time.perf_counter()
-            pos = np.asarray(cand_positions(dd))     # dense pass 1, sparse out
-            fb = bounds_from_positions(pos)          # host greedy (O(chunks))
-            device_digests(dd.reshape(-1), fb, unroll=best_unroll)
-            times.append(time.perf_counter() - t0)
-            it += 1
-        if not times:
-            return None
-        dt = min(times)
-        return {"mib_s": (B * S >> 20) / dt, "seconds": dt,
-                "chunks": len(flat_bounds), "streams": B,
-                "sha_unroll": best_unroll,
-                "backend": jax.default_backend()}
-    except Exception as e:
-        sys.stderr.write(f"[bench] tpu pipeline unavailable: {e}\n")
-        return None
-
-
 def main() -> None:
     from pbs_plus_tpu.utils import jaxenv
     jaxenv.configure_compile_cache()
     # this process may hold the chip from here on; the only children the
     # sections below start are fleetproc workers, which fleetsim starts
     # with JAX_PLATFORMS=cpu in their environment
-    tpu = _tpu_pipeline()
     cpu = _cpu_baseline()
     try:
         pipe = _pipeline_bench()
@@ -1820,25 +1511,14 @@ def main() -> None:
     except Exception as e:
         sys.stderr.write(f"[bench] pipeline bench unavailable: {e}\n")
         pipe = None
-    if tpu is not None:
-        value = tpu["mib_s"]
-        result = {
-            "metric": "chunk+fingerprint MiB/s/chip",
-            "value": round(value, 1),
-            "unit": "MiB/s",
-            "vs_baseline": round(value / cpu["mib_s"], 2),
-            "cpu_baseline_mib_s": round(cpu["mib_s"], 1),
-            "detail": tpu,
-        }
-    else:
-        result = {
-            "metric": "chunk+fingerprint MiB/s/chip",
-            "value": round(cpu["mib_s"], 1),
-            "unit": "MiB/s",
-            "vs_baseline": 1.0,
-            "cpu_baseline_mib_s": round(cpu["mib_s"], 1),
-            "detail": {"note": "CPU backend; CPU-only run", "cpu": cpu},
-        }
+    result = {
+        "metric": "chunk+fingerprint MiB/s/chip",
+        "value": round(cpu["mib_s"], 1),
+        "unit": "MiB/s",
+        "vs_baseline": 1.0,
+        "cpu_baseline_mib_s": round(cpu["mib_s"], 1),
+        "detail": {"note": "CPU backend; CPU-only run", "cpu": cpu},
+    }
     if pipe is not None:
         result["pipelined_mib_s"] = pipe["pipelined_mib_s"]
         result["detail"]["pipeline"] = pipe
@@ -1919,13 +1599,6 @@ def main() -> None:
         obs = None
     if obs is not None:
         result["detail"]["observability"] = obs
-    try:
-        ing = _ingest_fusion_bench()
-    except Exception as e:
-        sys.stderr.write(f"[bench] ingest fusion bench unavailable: {e}\n")
-        ing = None
-    if ing is not None:
-        result["detail"]["ingest"] = ing
     result["machine"] = _machine_context()
     print(json.dumps(result))
 

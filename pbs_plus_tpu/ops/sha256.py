@@ -3,7 +3,7 @@
 ``sha256_chunks`` hashes with the host's SHA-256 (hashlib / OpenSSL, on
 the calling thread, interpreter lock released), and every caller that
 wants digests — the ``chunker="tpu"`` batch hasher, the sidecar,
-verification, the fused ingest path — goes through it.
+verification — goes through it.
 ``sha256_chunks_device`` is the jax program described below; as measured
 it loses to one host thread on every batch shape (PERF.md section 6,
 PR 25: 16 MiB/s at a hash batch's 4-6 chunks, 755 MB/s at its best, 512

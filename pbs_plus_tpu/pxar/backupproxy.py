@@ -77,10 +77,6 @@ class BackupSession:
             pipeline_workers=(getattr(store, "pipeline_workers", 0)
                               if pipeline_workers is None
                               else pipeline_workers),
-            # cross-session fused ingest: one collector per chunk store
-            # = one batching domain shared by every concurrent session
-            # (pxar/ingestbatch.py; PBS_PLUS_FUSED_INGEST)
-            ingest_collector=store.ingest_collector(),
             # PBS layout ⇒ stock pxar v2 entries so PBS tools can decode
             # the archive content too, not just serve its chunks/indexes
             entry_codec="pxar2" if store.datastore.pbs_format else "tpxar",
@@ -94,9 +90,8 @@ class BackupSession:
                             f"{id(self):x}"
             os.makedirs(self._tmp_dir)
         except BaseException:
-            # the writer may hold pipeline threads and a fused-ingest
-            # collector registration (process-lifetime) — a failed
-            # session open must release both, not leak them
+            # the writer may hold pipeline threads — a failed session
+            # open must release them, not leak them
             try:
                 self.writer.close()
             except Exception as e:
@@ -210,7 +205,6 @@ class LocalStore:
                  delta_tier: "bool | None" = None,
                  delta_threshold: "int | None" = None,
                  delta_max_chain: "int | None" = None,
-                 fused_ingest: "bool | None" = None,
                  shared_instance: "str | None" = None):
         self.datastore = Datastore(base_dir, pbs_format=pbs_format,
                                    store_shards=store_shards,
@@ -226,18 +220,6 @@ class LocalStore:
         # >=1 pipelines each session's payload stream (pxar/pipeline.py);
         # 0 keeps the sequential writer (cut/digest output is identical)
         self.pipeline_workers = pipeline_workers
-        if fused_ingest is None:
-            from ..utils import conf as _conf
-            fused_ingest = _conf.env().fused_ingest
-        self.fused_ingest = bool(fused_ingest)
-
-    def ingest_collector(self):
-        """The store-wide cross-session fused-ingest collector, or None
-        when the fused path is disabled (pxar/ingestbatch.py)."""
-        if not self.fused_ingest:
-            return None
-        from .ingestbatch import collector_for
-        return collector_for(self.datastore.chunks)
 
     def start_session(self, *, backup_type: str, backup_id: str,
                       backup_time: float | None = None,

@@ -49,8 +49,7 @@ NO_CAPABILITIES = IngestCapabilities(probe=False, presketch=False)
 @runtime_checkable
 class IngestBackend(Protocol):
     """The batched-stage surface writers consume (transfer.py
-    ``_flush_hashes``, pipeline.py's batch committer, the
-    ingestbatch.py collector)."""
+    ``_flush_hashes``, pipeline.py's batch committer)."""
 
     @property
     def capabilities(self) -> IngestCapabilities: ...
@@ -113,7 +112,7 @@ class InlineIngestBackend:
 
 def resolve_ingest_backend(store) -> IngestBackend:
     """Resolve a store's declared ingest capabilities into a typed
-    backend (one declaration lookup, at stream/collector open)."""
+    backend (one declaration lookup, at stream open)."""
     decl = getattr(store, "ingest_capabilities", None)
     if callable(decl):
         return StoreIngestBackend(store)

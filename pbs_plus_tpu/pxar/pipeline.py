@@ -188,22 +188,9 @@ class PipelinedStream(_ChunkedStream):
     def __init__(self, store, params: ChunkerParams,
                  chunker_factory: ChunkerFactory = _default_chunker_factory,
                  batch_hasher: BatchHasher | None = None,
-                 workers: int = 2, max_inflight: int | None = None,
-                 collector=None):
+                 workers: int = 2, max_inflight: int | None = None):
         super().__init__(locked_store(store), params, chunker_factory,
-                         batch_hasher=batch_hasher, collector=collector)
-        try:
-            self._init_pipeline(workers, max_inflight)
-        except BaseException:
-            # the base __init__ registered us with the process-lifetime
-            # collector; a half-built stream must not stay counted in
-            # its all-deposited trigger (or strong-referenced) forever
-            if collector is not None:
-                collector.deregister(self)
-            raise
-
-    def _init_pipeline(self, workers: int,
-                       max_inflight: "int | None") -> None:
+                         batch_hasher=batch_hasher)
         self.workers = max(1, int(workers))
         # chunk-count backpressure (per-chunk hash mode); batch mode
         # bounds whole batches instead — a >max_inflight batch of small
@@ -262,7 +249,7 @@ class PipelinedStream(_ChunkedStream):
         self._buf_base = end
         self.records.append((end, b""))      # slot filled by the committer
         idx = len(self.records) - 1
-        if self._hasher is not None or self._collector is not None:
+        if self._hasher is not None:
             # batch mode reuses the sequential writer's pending-batch
             # fields; whole batches dispatch to the pool at the same
             # thresholds, so the device feeder sees identical batches
@@ -312,14 +299,6 @@ class PipelinedStream(_ChunkedStream):
         nbytes, self._pending_bytes = self._pending_bytes, 0
         self._batch_slots.acquire()
         self._hash_inflight += len(batch)
-        if self._collector is not None:
-            # fused-ingest mode: the committer deposits the raw batch
-            # with the cross-session collector (which runs sha + probe +
-            # presketch fused over every concurrent session) — the pool
-            # stays out of the hash path, but the caller thread still
-            # overlaps its scan with the committer's blocking deposit
-            self._commit_q.put(("cparcel", batch))
-            return
         fut = self._pool.submit(self._hash_batch,
                                 [c for _, c in batch], nbytes)
         self._commit_q.put(("batch", batch, fut))
@@ -341,7 +320,7 @@ class PipelinedStream(_ChunkedStream):
             return               # committer gone; records already final
         if self._buf:
             self.flush_chunker()
-        if self._hasher is not None or self._collector is not None:
+        if self._hasher is not None:
             self._flush_batch()
         done = threading.Event()
         self._commit_q.put(("drain", done))
@@ -362,8 +341,7 @@ class PipelinedStream(_ChunkedStream):
             return self.records
         if self._buf:
             self.flush_chunker()
-        if self._exc is None and (self._hasher is not None
-                                  or self._collector is not None):
+        if self._exc is None and self._hasher is not None:
             self._flush_batch()
         self._shutdown()
         if self._exc is not None:
@@ -384,8 +362,6 @@ class PipelinedStream(_ChunkedStream):
         self._commit_q.put(_DONE)
         self._committer.join()
         self._pool.shutdown(wait=True)
-        if self._collector is not None:
-            self._collector.deregister(self)
 
     # -- committer thread --------------------------------------------------
     def _commit_loop(self) -> None:
@@ -409,16 +385,6 @@ class PipelinedStream(_ChunkedStream):
                         self._commit(idx, fut.result(), chunk)
                     finally:
                         self._slots.release()
-                elif slot[0] == "cparcel":
-                    _, batch = slot
-                    try:
-                        # blocking cross-session deposit: the collector
-                        # fills this stream's record slots and runs the
-                        # inserts before returning (deadline-bounded)
-                        self._collector.ingest_chunks(self, batch)
-                        self._hash_inflight -= len(batch)
-                    finally:
-                        self._batch_slots.release()
                 else:
                     _, batch, fut = slot
                     try:
@@ -453,7 +419,7 @@ class PipelinedStream(_ChunkedStream):
                     slot[1].set()
                 elif slot[0] == "chunk":
                     self._slots.release()
-                else:            # "batch" and "cparcel" share the permit
+                else:
                     self._batch_slots.release()
 
     def _commit(self, idx: int, digest: bytes, chunk,

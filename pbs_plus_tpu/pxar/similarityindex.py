@@ -131,7 +131,7 @@ class SimilarityIndex:
         # digest -> (pool digests, distances, pool set) precomputed by
         # the batched candidate preselect (one locked pass + one
         # vectorized popcount per hash batch — the delta-ENCODE half of
-        # the fused ingest batch, ISSUE 13); consumed by take_candidate
+        # the hash batch, ISSUE 13); consumed by take_candidate
         self._pending_cand: dict = {}                  # guarded-by: self._lock
         METRICS.register(self)
 

@@ -156,17 +156,12 @@ class _ChunkedStream:
 
     def __init__(self, store: ChunkStore, params: ChunkerParams,
                  chunker_factory: ChunkerFactory = _default_chunker_factory,
-                 batch_hasher: BatchHasher | None = None,
-                 collector=None):
+                 batch_hasher: BatchHasher | None = None):
         self.store = store
         self.params = params
         # the store's DECLARED batched-ingest surface, resolved once at
         # stream open (pxar/ingestbackend.py; pbslint ingest-discipline)
         self._ingest = resolve_ingest_backend(store)
-        # cross-session fused-ingest collector (pxar/ingestbatch.py):
-        # when set, whole hash batches deposit there instead of
-        # dispatching per-session sha/probe/presketch stages
-        self._collector = collector
         # a factory exposing bind_stream() pins its backend decision ONCE
         # per stream (sidecar ResilientSidecarFactory: sidecar-vs-CPU
         # degradation happens at stream open only, never at the
@@ -203,11 +198,6 @@ class _ChunkedStream:
         self._cdc_bytes = 0
         self._sha_ns = 0
         self._sha_chunks = 0
-        # register LAST — a fallible factory bind above must not leak a
-        # half-built stream into the process-lifetime collector's
-        # all-deposited trigger (deregistered at finish/close)
-        if collector is not None:
-            collector.register(self)
 
     def write(self, data: bytes) -> None:
         if not data:
@@ -234,7 +224,7 @@ class _ChunkedStream:
         n = end - start
         chunk = self._buf.take(n)      # memoryview when seam-free
         self._buf_base = end
-        if self._hasher is None and self._collector is None:
+        if self._hasher is None:
             if trace.enabled():
                 t0 = time.perf_counter_ns()
                 digest = hashlib.sha256(chunk).digest()
@@ -296,16 +286,6 @@ class _ChunkedStream:
 
     def _flush_hashes(self) -> None:
         if not self._pending:
-            return
-        if self._collector is not None:
-            # cross-session fused path: the whole pending batch deposits
-            # with the collector, which runs sha → probe → presketch over
-            # EVERY concurrent session's chunks in one fused pass and
-            # completes this stream's records/inserts before returning
-            # (pxar/ingestbatch.py — blocking, deadline-bounded)
-            batch, self._pending = self._pending, []
-            self._pending_bytes = 0
-            self._collector.ingest_chunks(self, batch)
             return
         assert self._hasher is not None
         with trace.span("ingest.sha", chunks=len(self._pending)):
@@ -372,8 +352,6 @@ class _ChunkedStream:
             self.flush_chunker()
         self._flush_hashes()
         self._emit_stage_spans()
-        if self._collector is not None:
-            self._collector.deregister(self)
         return self.records
 
     def sync(self) -> None:
@@ -386,13 +364,6 @@ class _ChunkedStream:
             self.flush_chunker()
         self._flush_hashes()
         self._emit_stage_spans()
-
-    def close(self) -> None:
-        """Abort-path release: stop counting this stream toward the
-        collector's all-deposited trigger (idempotent; no-op without a
-        collector, safe after ``finish``)."""
-        if self._collector is not None:
-            self._collector.deregister(self)
 
 
 class SessionWriter:
@@ -407,8 +378,7 @@ class SessionWriter:
                  chunker_factory: ChunkerFactory = _default_chunker_factory,
                  batch_hasher: BatchHasher | None = None,
                  entry_codec: str = "tpxar",
-                 pipeline_workers: int = 0,
-                 ingest_collector=None):
+                 pipeline_workers: int = 0):
         """``entry_codec='pxar2'`` writes stock pxar v2 binary items in
         the meta stream (with per-file payload headers + start marker in
         the payload stream) so stock PBS tools can decode the archive;
@@ -419,23 +389,14 @@ class SessionWriter:
         ``pipeline_workers >= 1`` runs the payload stream through
         ``pipeline.PipelinedStream`` (scan ∥ hash ∥ insert with N hash
         workers); 0 (default) keeps the sequential writer.  Cut/digest
-        output is bit-identical either way (tests/test_pipeline.py).
-
-        ``ingest_collector`` (pxar/ingestbatch.py) routes the payload
-        stream's batched stages through the cross-session fused ingest
-        op: the sequential writer becomes a ``FusedIngestStream`` (CDC
-        scan included in the fused batch), a pipelined writer's batch
-        committer deposits its hash batches there.  Cuts/digests stay
-        bit-identical (tests/test_ingest_fused.py)."""
+        output is bit-identical either way (tests/test_pipeline.py)."""
         if entry_codec not in ("tpxar", "pxar2"):
             raise ValueError(f"unknown entry codec {entry_codec!r}")
-        if (pipeline_workers and pipeline_workers > 0) \
-                or ingest_collector is not None:
-            # the payload committer / collector-flusher thread and this
-            # (writer) thread both call store.insert once the meta
-            # stream cuts a chunk, and neither built-in store is
-            # thread-safe — share ONE locked proxy across both streams
-            # (pipeline.py module docstring)
+        if pipeline_workers and pipeline_workers > 0:
+            # the payload committer thread and this (writer) thread both
+            # call store.insert once the meta stream cuts a chunk, and
+            # neither built-in store is thread-safe — share ONE locked
+            # proxy across both streams (pipeline.py module docstring)
             from .pipeline import locked_store
             store = locked_store(store)
         self.store = store
@@ -449,21 +410,7 @@ class SessionWriter:
             from .pipeline import PipelinedStream
             self.payload = PipelinedStream(
                 store, payload_params, chunker_factory,
-                batch_hasher=batch_hasher, workers=pipeline_workers,
-                collector=ingest_collector)
-        elif ingest_collector is not None:
-            from .ingestbatch import FusedIngestStream
-            if chunker_factory is not _default_chunker_factory:
-                # the collector's packed scan is the one scan backend
-                # for fused sequential streams; a configured per-session
-                # factory is overridden (cuts stay bit-identical — every
-                # backend is parity-gated — but say so)
-                L.info("fused ingest: session chunker factory %s "
-                       "overridden by the collector's packed scan",
-                       getattr(chunker_factory, "__name__",
-                               type(chunker_factory).__name__))
-            self.payload = FusedIngestStream(
-                store, payload_params, ingest_collector)
+                batch_hasher=batch_hasher, workers=pipeline_workers)
         else:
             self.payload = _ChunkedStream(
                 store, payload_params, chunker_factory,
@@ -714,15 +661,13 @@ class DedupWriter(SessionWriter):
                  chunker_factory: ChunkerFactory = _default_chunker_factory,
                  batch_hasher: BatchHasher | None = None,
                  entry_codec: str = "tpxar",
-                 pipeline_workers: int = 0,
-                 ingest_collector=None):
+                 pipeline_workers: int = 0):
         super().__init__(store, payload_params=payload_params,
                          meta_params=meta_params,
                          chunker_factory=chunker_factory,
                          batch_hasher=batch_hasher,
                          entry_codec=entry_codec,
-                         pipeline_workers=pipeline_workers,
-                         ingest_collector=ingest_collector)
+                         pipeline_workers=pipeline_workers)
         self.previous = previous
         # pending coalesced old-payload range [A, B) and the new-stream
         # offset N0 where it will land

@@ -494,27 +494,6 @@ class MetricsRegistry:
               [({"queue": q}, float(v))
                for q, v in snap["queues"].items()])
 
-        # -- fused cross-session ingest (pxar/ingestbatch.py;
-        #    docs/data-plane.md "Fused ingest") ------------------------------
-        from ..pxar import ingestbatch as _ingestbatch
-        ib = _ingestbatch.metrics_snapshot()
-        gauge("pbs_plus_ingest_batch_flushes_total",
-              "Fused ingest flushes (one fused scan/sha/probe/presketch "
-              "pass each)", [({}, float(ib["flushes"]))])
-        gauge("pbs_plus_ingest_batch_sessions_packed_total",
-              "Per-flush distinct sessions, summed (divide by flushes "
-              "for mean packing factor)",
-              [({}, float(ib["sessions_packed"]))])
-        gauge("pbs_plus_ingest_batch_rows_total",
-              "Ragged scan rows packed across fused flushes",
-              [({}, float(ib["rows"]))])
-        gauge("pbs_plus_ingest_batch_padding_bytes_total",
-              "Halo/alignment overhead bytes in packed scan buffers",
-              [({}, float(ib["padding_bytes"]))])
-        gauge("pbs_plus_ingest_batch_occupancy",
-              "Payload fraction of packed scan buffers (1.0 = zero "
-              "packing overhead)", [({}, float(ib["occupancy"]))])
-
         # -- device round trips and the cross-session batcher
         #    (utils/trace.py DEVICE_STATS; docs/observability.md "Device
         #    round trips").  An op or a feeder this process never loaded

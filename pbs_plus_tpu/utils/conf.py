@@ -73,9 +73,6 @@ ENV_VARS = {
     "PBS_PLUS_DELTA_TIER": "enable the similarity-dedup delta tier",
     "PBS_PLUS_DELTA_THRESHOLD": "max sketch Hamming distance for a base",
     "PBS_PLUS_DELTA_MAX_CHAIN": "max delta-chain depth (base hops)",
-    "PBS_PLUS_FUSED_INGEST": "cross-session fused ingest batching",
-    "PBS_PLUS_INGEST_BATCH_BYTES": "fused-ingest flush size threshold",
-    "PBS_PLUS_INGEST_MAX_WAIT_MS": "fused-ingest flush deadline (ms)",
     "PBS_PLUS_AGENT_RATE": "per-client token bucket rate (req/s)",
     "PBS_PLUS_AGENT_BURST": "per-client token bucket burst",
     "PBS_PLUS_AGENT_OPEN_RATE": "global session-open rate (0 = off)",
@@ -171,15 +168,6 @@ class Env:
     delta_tier: bool = False
     delta_threshold: int = 14
     delta_max_chain: int = 3
-    # cross-session fused ingest (pxar/ingestbatch.py, docs/data-plane.md
-    # "Fused ingest"): pack every concurrent session's pending buffers
-    # into one ragged batch and run CDC scan -> sha -> probe -> presketch
-    # as ONE fused pass per flush.  fused_ingest 0 keeps the per-session
-    # staged path; ingest_batch_bytes is the flush size threshold and
-    # ingest_max_wait_ms bounds how long a lone depositor can wait.
-    fused_ingest: bool = False
-    ingest_batch_bytes: int = 16 << 20
-    ingest_max_wait_ms: int = 25
     # fleet admission control (arpc/agents_manager.py, docs/fleet.md):
     # per-client token bucket (the old hardcoded 10/s burst 20), a
     # global session-open rate bucket, and a hard ceiling on concurrent
@@ -267,12 +255,6 @@ def env() -> Env:
         in ("1", "true", "yes"),
         delta_threshold=_int_env(e, "PBS_PLUS_DELTA_THRESHOLD", "14"),
         delta_max_chain=_int_env(e, "PBS_PLUS_DELTA_MAX_CHAIN", "3"),
-        fused_ingest=e.get("PBS_PLUS_FUSED_INGEST", "").lower()
-        in ("1", "true", "yes"),
-        ingest_batch_bytes=_int_env(e, "PBS_PLUS_INGEST_BATCH_BYTES",
-                                    str(16 << 20)),
-        ingest_max_wait_ms=_int_env(e, "PBS_PLUS_INGEST_MAX_WAIT_MS",
-                                    "25"),
         agent_rate=_float_env(e, "PBS_PLUS_AGENT_RATE",
                               str(CLIENT_RATE_LIMIT_PER_SEC)),
         agent_burst=_int_env(e, "PBS_PLUS_AGENT_BURST",

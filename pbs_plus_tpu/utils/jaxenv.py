@@ -13,7 +13,7 @@ Three helpers:
   The path is part of the cache key's surroundings — a directory that
   moves (a tmpdir, a pid, a timestamp) never hits.
 - ``pick_twin`` is the one decision point of the host/device twins
-  (``ops/ingest.py``, ``pxar/chunkindex.py``, ``pxar/similarityindex.py``,
+  (``pxar/chunkindex.py``, ``pxar/similarityindex.py``,
   ``models/verify.py``): device when the backend is an accelerator, host
   on the CPU backend, counted per twin so a run can say which side did
   the work.

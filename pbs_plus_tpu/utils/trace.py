@@ -87,9 +87,7 @@ SPANS = {
     "backup.publish": None,
     "session.open": ("pbs_plus_session_open_seconds",
                      {"phase": "connect"}),
-    # batched ingest stages (pxar/transfer.py, pxar/pipeline.py,
-    # pxar/ingestbatch.py)
-    "ingest.fused": ("pbs_plus_ingest_stage_seconds", {"stage": "fused"}),
+    # batched ingest stages (pxar/transfer.py, pxar/pipeline.py)
     "ingest.cdc": ("pbs_plus_ingest_stage_seconds", {"stage": "cdc"}),
     "ingest.sha": ("pbs_plus_ingest_stage_seconds", {"stage": "sha"}),
     "ingest.probe": ("pbs_plus_ingest_stage_seconds", {"stage": "probe"}),

@@ -573,3 +573,71 @@ def test_a_scan_that_fails_fails_its_own_session_only(
     assert "candidate scan failed: MemoryError: injected" in str(got["bad"])
     assert wide_feeder.stats["mask_retried_alone"] == 2
     assert wide_feeder.stats["mask_rows"] == 1      # the good one, alone
+
+
+# --- N sessions through the one batcher equal the lone scalar session -------
+
+@pytest.mark.parametrize("sessions", [1, 3, 8])
+def test_sessions_at_random_write_splits_equal_the_scalar_session(
+        monkeypatch, tmp_path, sessions):
+    """N writer threads, each a ``_ChunkedStream`` over the ``TpuChunker``
+    factory and the ``chunker="tpu"`` batch hasher, sharing one store and
+    the one DeviceFeeder, writes split at seeded random sizes from 1 B to
+    1.5 scan segments: every session's records (ends and digests), its
+    ``WriterStats`` and the sketches the store keeps equal those of one
+    ``_ChunkedStream`` at a time over ``CpuChunker`` and inline hashlib."""
+    from pbs_plus_tpu.models.dedup import SCAN_SEGMENT
+    from pbs_plus_tpu.pxar.datastore import ChunkStore
+    from pbs_plus_tpu.pxar.similarityindex import SimilarityIndex
+    from pbs_plus_tpu.pxar.transfer import _ChunkedStream
+    from pbs_plus_tpu.server.backup_job import make_batch_hasher
+    params = ChunkerParams(avg_size=64 << 10)
+    feeder = DeviceFeeder()
+    monkeypatch.setattr(feeder_mod, "_feeder", feeder)
+    rng = np.random.default_rng(280 + sessions)
+    payloads = [_data(int(rng.integers(SCAN_SEGMENT + 1, 9 * MIB)),
+                      seed=2800 + k) for k in range(sessions)]
+
+    def drive(stream, k):
+        p, off = payloads[k], 0
+        r = np.random.default_rng(28_000 + k)      # the same splits
+        while off < len(p):
+            step = int(np.exp(r.uniform(0, np.log(1.5 * SCAN_SEGMENT))))
+            stream.write(p[off:off + step])
+            off += step
+        return stream.finish(), stream.stats
+
+    def store(name):
+        s = ChunkStore(str(tmp_path / name))
+        s.similarity = SimilarityIndex()
+        return s
+    scalar = store("scalar")
+    want = [drive(_ChunkedStream(scalar, params), k)
+            for k in range(sessions)]
+    shared = store("device")
+    got: list = [None] * sessions
+    errs: list[BaseException] = []
+
+    def work(k):
+        try:
+            got[k] = drive(_ChunkedStream(
+                shared, params, TpuChunker,
+                batch_hasher=make_batch_hasher("tpu")), k)
+        except BaseException as e:
+            errs.append(e)
+    threads = [threading.Thread(target=work, args=(k,))
+               for k in range(sessions)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(300)
+        assert not t.is_alive()
+    assert not errs, errs
+    assert got == want
+    assert all(d for recs, _ in got for _, d in recs)
+    # the scans really went through the one batcher, a segment a row
+    assert feeder.stats["mask_rows"] == sum(
+        -(-len(p) // SCAN_SEGMENT) for p in payloads)
+    a = {d: s for d, (s, _dp) in scalar.similarity._entries.items()}
+    b = {d: s for d, (s, _dp) in shared.similarity._entries.items()}
+    assert a == b and len(a) > 0

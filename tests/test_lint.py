@@ -324,7 +324,7 @@ def test_ingest_discipline_declared_backend_clean():
 
 
 def test_ingest_discipline_scoped_to_stream_modules():
-    # the collector and the sync plane legitimately call probe_batch
+    # the sync plane legitimately calls probe_batch
     v = run_lint("""
         def negotiate(self, digests):
             return self.store.probe_batch(digests)

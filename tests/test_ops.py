@@ -103,6 +103,71 @@ def test_scan_shapes_come_from_two_short_lists(monkeypatch):
                     for s in (1 << 16, 1 << 18)}, seen
 
 
+_P1K = ChunkerParams(avg_size=1 << 10)     # densest candidates allowed
+
+
+def _row_with_phantom(rng, n: int) -> np.ndarray:
+    """``n`` seeded bytes whose zero padding WOULD show a candidate at
+    or beyond ``n`` (a window over the row's last bytes and pad zeros):
+    the row that catches an unpack which forgets a row's real length."""
+    while True:
+        row = rng.integers(0, 256, n, dtype=np.uint8)
+        padded = np.concatenate([row, np.zeros(63, dtype=np.uint8)])
+        if (candidates(padded, _P1K, force_numpy=True) > n).any():
+            return row
+
+
+def _ragged_rows(case: str):
+    """(rows, tails) of one dispatch; a tail is the 63 bytes before the
+    row in its stream, or None at a stream's start."""
+    rng = np.random.default_rng(sum(case.encode()))
+
+    def tail():
+        return rng.integers(0, 256, 63, dtype=np.uint8)
+
+    def row(n):
+        return rng.integers(0, 256, n, dtype=np.uint8)
+    if case == "short-beside-long":
+        # 40 B is shorter than the 64-byte window: with a tail it can
+        # still end a candidate, without one it cannot
+        return ([row(40), row(200_000), row(40), _row_with_phantom(rng, 900)],
+                [tail(), tail(), None, None])
+    if case == "1B-4KiB-300KiB":
+        lens = (1, 4 << 10, 300 << 10)
+        return ([row(n) for n in lens] + [row(n) for n in lens]
+                + [_row_with_phantom(rng, 5_000)],
+                [None] * 3 + [tail() for _ in lens] + [tail()])
+    if case == "exact-segment-class":
+        # the first row fills its padded segment to the last byte
+        return ([row(1 << 16), _row_with_phantom(rng, 777), row(1 << 16)],
+                [tail(), None, None])
+    assert case == "seventeen-rows"     # one past a row class: B_pad pads
+    return ([_row_with_phantom(rng, 300 + 50 * i) for i in range(17)],
+            [tail() if i % 2 else None for i in range(17)])
+
+
+@pytest.mark.parametrize("case", ["short-beside-long", "1B-4KiB-300KiB",
+                                  "exact-segment-class", "seventeen-rows"])
+def test_batched_hits_over_ragged_rows_in_one_dispatch(case):
+    """The one device scan entry over rows of unequal length, some with
+    a previous tail: each row's hits are the scalar scan's candidates
+    over that row's own tail + bytes, whatever stands beside it, and no
+    hit lies at or beyond a row's real length (pad bytes and pad rows
+    never leak)."""
+    from pbs_plus_tpu.ops import rolling_hash as rh
+    rows, tails = _ragged_rows(case)
+    d0 = rh.stats["dispatches"]
+    got = rh.batched_candidate_hits(rows, tails, device_tables(_P1K), _P1K)
+    assert rh.stats["dispatches"] == d0 + 1
+    assert len(got) == len(rows)
+    for r, t, hits in zip(rows, tails, got):
+        stream = r if t is None else np.concatenate([t, r])
+        hist = 0 if t is None else len(t)
+        want = candidates(stream, _P1K, force_numpy=True) - hist - 1
+        assert np.array_equal(hits, want), (case, len(r), hist)
+        assert not len(hits) or hits[-1] < len(r)
+
+
 def test_device_cuts_match_cpu_cuts():
     data = _data(300_000, seed=3)
     assert chunk_stream_device(data, P) == [e for _, e in chunk_bounds(data, P)]

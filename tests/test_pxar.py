@@ -14,6 +14,11 @@ from pbs_plus_tpu.pxar import (
     Datastore, DynamicIndex, Entry, KIND_DIR, KIND_FILE, KIND_HARDLINK,
     KIND_SYMLINK, LocalStore, SnapshotRef, SplitReader,
 )
+from pbs_plus_tpu.pxar.datastore import ChunkStore
+from pbs_plus_tpu.pxar.ingestbackend import (
+    IngestCapabilities, InlineIngestBackend, NO_CAPABILITIES,
+    StoreIngestBackend, resolve_ingest_backend)
+from pbs_plus_tpu.pxar.similarityindex import SimilarityIndex
 from pbs_plus_tpu.pxar.walker import backup_tree, iter_tree
 
 P = ChunkerParams(avg_size=4 << 10)  # reference test scale: 4 KiB chunks
@@ -362,3 +367,135 @@ def test_zip_hardlinks_and_single_file(tmp_path, tree):
     zf2 = zipfile.ZipFile(zip_subtree(r, "docs/readme.txt"))
     assert zf2.namelist() == ["readme.txt"]
     assert zf2.read("readme.txt") == want
+
+
+# ------------------------------------------------- typed ingest backend
+# (pxar/ingestbackend.py: the probe/presketch seam _ChunkedStream and
+# PipelinedStream resolve once at stream open)
+
+
+def test_resolve_backend_declared_capabilities(tmp_path):
+    indexed = ChunkStore(str(tmp_path / "indexed"))
+    be = resolve_ingest_backend(indexed)
+    assert isinstance(be, StoreIngestBackend)
+    assert be.capabilities == IngestCapabilities(probe=True,
+                                                 presketch=False)
+    indexed.similarity = SimilarityIndex()
+    assert be.capabilities.presketch is True      # live re-read
+
+    legacy = ChunkStore(str(tmp_path / "legacy"), index_budget_mb=0)
+    assert resolve_ingest_backend(legacy).capabilities == \
+        IngestCapabilities(probe=False, presketch=False)
+
+
+def test_resolve_backend_undeclared_store_is_inline():
+    class Double:
+        def insert(self, digest, data, *, verify=True):
+            return True
+
+    be = resolve_ingest_backend(Double())
+    assert isinstance(be, InlineIngestBackend)
+    assert be.capabilities == NO_CAPABILITIES
+    with pytest.raises(TypeError):
+        be.probe_batch([b"x" * 32])
+    with pytest.raises(TypeError):
+        be.presketch_batch([], [], None)
+
+
+def test_pbs_sink_declares_no_capabilities():
+    from pbs_plus_tpu.pxar.pbsstore import PBSChunkSink
+    sink = PBSChunkSink.__new__(PBSChunkSink)
+    assert sink.ingest_capabilities() == NO_CAPABILITIES
+
+
+# ----------------------------------------- the stream's edges, both factories
+
+
+@pytest.mark.parametrize("kind", ["cpu", "tpu"])
+def test_stream_edges_under_either_chunker_factory(tmp_path, kind):
+    """write, ``sync`` (every record's digest resolved and its chunk in
+    the store), ``append_ref`` of an existing chunk in mid-stream (the
+    splice seam restarts the scan run), write, ``flush_chunker``, write,
+    ``finish``: the records are those of a whole-buffer scalar scan over
+    each run on its own, under the server's factory and batch hasher for
+    either chunker kind (the second run crosses a 4 MiB scan segment)."""
+    from pbs_plus_tpu.chunker import chunk_bounds
+    from pbs_plus_tpu.pxar.transfer import _ChunkedStream
+    from pbs_plus_tpu.server.backup_job import (make_batch_hasher,
+                                                make_chunker_factory)
+    params = ChunkerParams(avg_size=64 << 10)
+    runs = [_blob(300_000, seed=31), _blob(5 << 20, seed=32),
+            _blob(200_000, seed=33)]
+    spliced = _blob(70_000, seed=34)
+    d_spliced = hashlib.sha256(spliced).digest()
+    store = ChunkStore(str(tmp_path / "s"))
+    store.insert(d_spliced, spliced, verify=False)
+
+    want, base = [], 0
+    for i, run in enumerate(runs):
+        want += [(base + e, hashlib.sha256(run[s:e]).digest())
+                 for s, e in chunk_bounds(run, params)]
+        base += len(run)
+        if i == 0:
+            base += len(spliced)
+            want.append((base, d_spliced))
+
+    st = _ChunkedStream(store, params, make_chunker_factory(kind),
+                        batch_hasher=make_batch_hasher(kind))
+    assert st.bound_backend == kind
+    st.write(runs[0])
+    st.sync()
+    assert st.records and st.records[-1][0] == len(runs[0])
+    assert all(d and store.has(d) for _, d in st.records)
+    st.append_ref(d_spliced, len(spliced))
+    # two writes, so that the run's cuts are not one feed's
+    st.write(runs[1][:3 << 20])
+    st.write(runs[1][3 << 20:])
+    st.flush_chunker()
+    assert st.records[-1][0] == st.offset
+    st.write(runs[2])
+    assert st.finish() == want
+    assert (st.stats.ref_chunks, st.stats.bytes_reffed) == (1, len(spliced))
+    assert st.stats.bytes_streamed == sum(map(len, runs))
+    assert st.stats.new_chunks == len(want) - 1
+
+
+def test_hash_batch_that_raises_reaches_the_flushing_caller(tmp_path):
+    """A ``batch_hasher`` that throws: its own error reaches the caller
+    of the ``write``, ``sync`` or ``finish`` that flushed the batch, and
+    ``finish`` never returns a record whose digest slot was left
+    unfilled — it raises while the hasher does, and returns the scalar
+    stream's records once it no longer does (the batch stays pending)."""
+    from pbs_plus_tpu.pxar.transfer import _ChunkedStream, _HASH_BATCH_COUNT
+
+    class Boom(RuntimeError):
+        pass
+    down = [True]
+
+    def hasher(chunks):
+        if down[0]:
+            raise Boom("sha stage down")
+        return [hashlib.sha256(c).digest() for c in chunks]
+    store = ChunkStore(str(tmp_path / "s"))
+    small = _blob(100_000, seed=41)
+    # enough chunks that the count threshold flushes inside the write
+    big = _blob(P.max_size * (_HASH_BATCH_COUNT + 8), seed=42)
+
+    st = _ChunkedStream(store, P, batch_hasher=hasher)
+    with pytest.raises(Boom):
+        st.write(big)
+    st = _ChunkedStream(store, P, batch_hasher=hasher)
+    st.write(small)
+    with pytest.raises(Boom):
+        st.sync()
+    st = _ChunkedStream(store, P, batch_hasher=hasher)
+    st.write(small)
+    for _ in range(2):
+        with pytest.raises(Boom):
+            st.finish()
+    assert any(d == b"" for _, d in st.records)     # still owed
+    down[0] = False
+    scalar = _ChunkedStream(ChunkStore(str(tmp_path / "ref")), P)
+    scalar.write(small)
+    assert st.finish() == scalar.finish()
+    assert all(d and store.has(d) for _, d in st.records)

@@ -688,3 +688,73 @@ def test_sweep_resaves_snapshot_with_surviving_sketches(tmp_path):
     assert not reopened.similarity.has(dd)
     assert reopened.index.contains(dk)
     assert not reopened.index.contains(dd)
+
+
+# -------------------------------------- batched delta-candidate preselect
+
+
+def test_precandidate_batch_matches_live_candidate():
+    """The vectorized per-batch candidate preselect (consumed by
+    ``take_candidate``) returns exactly what a live ``candidate()``
+    walk would, including depth rejects and misses."""
+    rng = np.random.default_rng(21)
+    live, batched = SimilarityIndex(), SimilarityIndex()
+    for _ in range(300):
+        d = rng.bytes(32)
+        s = int(rng.integers(0, 2 ** 63))
+        dp = int(rng.integers(0, 4))
+        live.add(d, s, dp)
+        batched.add(d, s, dp)
+    digests, sketches = [], []
+    entries = list(live._entries.items())
+    for _ in range(48):
+        base = entries[int(rng.integers(0, len(entries)))][1][0]
+        s = base
+        for _ in range(int(rng.integers(0, 22))):
+            s ^= 1 << int(rng.integers(0, 64))
+        digests.append(rng.bytes(32))
+        sketches.append(s)
+    with batched._lock:
+        batched._precandidate_locked(digests, sketches)
+    for d, s in zip(digests, sketches):
+        assert batched.take_candidate(d, s, exclude=d) == \
+            live.candidate(s, exclude=d)
+    # consumed stashes fall back to the live walk
+    assert batched.take_candidate(digests[0], sketches[0],
+                                  exclude=digests[0]) == \
+        live.candidate(sketches[0], exclude=digests[0])
+
+
+def test_take_candidate_sees_band_adds_past_recency_window():
+    """A base inserted after the preselect stays visible via its LIVE
+    band bucket even after >128 unrelated inserts rotate it out of the
+    recency window (the 512-chunk-batch regression: the stash must
+    never see LESS than a live candidate() walk)."""
+    rng = np.random.default_rng(22)
+    idx = SimilarityIndex()
+    sketch = 0x0123_4567_89AB_CDEF
+    d_new = b"n" * 32
+    with idx._lock:
+        idx._precandidate_locked([d_new], [sketch])    # empty pool
+    d_base = b"b" * 32
+    idx.add(d_base, sketch ^ 0b101, 0)                 # post-stash add
+    for _ in range(200):                               # rotate it out
+        idx.add(rng.bytes(32), int(rng.integers(0, 2 ** 63)) | 1 << 63,
+                0)
+    assert d_base not in idx._recent
+    assert idx.take_candidate(d_new, sketch, exclude=d_new) == \
+        idx.candidate(sketch, exclude=d_new) == (d_base, 0)
+
+
+def test_take_candidate_sees_intra_batch_adds():
+    """A base inserted AFTER the preselect (an earlier chunk of the
+    same batch) is still offered via the live recency re-check."""
+    idx = SimilarityIndex()
+    sketch = 0x5A5A_5A5A_5A5A_5A5A
+    d_new = b"n" * 32
+    with idx._lock:
+        idx._precandidate_locked([d_new], [sketch])    # empty pool
+    d_base = b"b" * 32
+    idx.add(d_base, sketch ^ 0b11, 0)                  # post-stash add
+    got = idx.take_candidate(d_new, sketch, exclude=d_new)
+    assert got == (d_base, 0)

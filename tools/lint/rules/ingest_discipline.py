@@ -1,11 +1,10 @@
 """ingest-discipline — batched ingest stages stay on the typed seam.
 
-Invariant (pxar/ingestbackend.py + pxar/ingestbatch.py,
-docs/data-plane.md "Fused ingest"): the write-path stream classes —
+Invariant (pxar/ingestbackend.py): the write-path stream classes —
 ``pxar/transfer.py`` and ``pxar/pipeline.py`` — reach the batched
 probe/presketch/fingerprint stages only through the declared ingest
-backend (``resolve_ingest_backend`` → ``capabilities`` branch) or the
-fused collector.  Two hazards are flagged:
+backend (``resolve_ingest_backend`` → ``capabilities`` branch).  Two
+hazards are flagged:
 
 - **Resurrected duck-typing**: ``getattr(store, "probe_batch", None)``
   / ``"presketch_batch"`` etc. — the silent-attribute-miss pattern the
@@ -17,9 +16,8 @@ fused collector.  Two hazards are flagged:
   ingest backend, and direct calls into the batched fingerprint
   kernels (``sha256_chunks`` / ``sha256_chunks_device`` /
   ``sha256_stream_chunks`` / ``sha256_streams_chunks``) — chunk
-  fingerprinting flows through the injected ``batch_hasher`` seam or
-  the collector's fused pass, never a per-stage kernel dispatch of the
-  stream's own.
+  fingerprinting flows through the injected ``batch_hasher`` seam,
+  never a per-stage kernel dispatch of the stream's own.
 
 Receivers whose source text mentions the resolved backend
 (``self._ingest`` / a local named ``backend``) are the sanctioned seam.
@@ -46,8 +44,8 @@ class IngestDiscipline(Rule):
     name = "ingest-discipline"
     invariant = ("transfer.py/pipeline.py reach probe/presketch/"
                  "fingerprint only through the declared ingest backend "
-                 "or the fused collector — no getattr duck-typing, no "
-                 "resurrected per-stage store/kernel calls")
+                 "— no getattr duck-typing, no resurrected per-stage "
+                 "store/kernel calls")
 
     def begin_file(self, ctx):
         return ctx.path in _SCOPES
@@ -75,18 +73,16 @@ class IngestDiscipline(Rule):
                                f"`{recv}.{func.attr}(...)` is a "
                                "per-stage store call: batched ingest "
                                "stages go through the resolved ingest "
-                               "backend or the fused collector "
-                               "(docs/data-plane.md \"Fused ingest\")")
+                               "backend (pxar/ingestbackend.py)")
                 return
             if func.attr in _FP_KERNELS:
                 ctx.report(self, node,
                            f"direct `{func.attr}` kernel dispatch in a "
                            "stream class: chunk fingerprinting flows "
-                           "through the batch_hasher seam or the fused "
-                           "collector")
+                           "through the batch_hasher seam")
                 return
         if isinstance(func, ast.Name) and func.id in _FP_KERNELS:
             ctx.report(self, node,
                        f"direct `{func.id}` kernel dispatch in a stream "
                        "class: chunk fingerprinting flows through the "
-                       "batch_hasher seam or the fused collector")
+                       "batch_hasher seam")
