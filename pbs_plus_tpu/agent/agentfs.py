@@ -16,6 +16,11 @@ hot loop, SURVEY §3.2):
     agentfs.read_link {path}                      → {target}
     agentfs.xattrs    {path}                      → {xattrs: {name: bytes}}
     agentfs.open      {path}                      → {handle}
+    agentfs.open      {path, read: n}             → 213 raw stream of the
+                                                    first n bytes, with
+                                                    {handle, n, eof}; at
+                                                    eof the file is closed
+                                                    and handle is 0
     agentfs.read_at   {handle, off, n}            → 213 raw stream
     agentfs.lseek     {handle, off, whence}       → {pos}
     agentfs.close     {handle}                    → {}
@@ -28,7 +33,9 @@ import os
 import stat as statmod
 from typing import Any
 
-from ..arpc.call import RawStreamHandler
+from ..arpc.call import (
+    STATUS_ERROR, STATUS_RAW_STREAM, CallError, RawStreamHandler,
+)
 from ..arpc.router import HandlerError, Router
 from ..arpc.binary_stream import send_data_from_reader
 from ..pxar.format import read_xattrs
@@ -76,7 +83,8 @@ class AgentFSServer:
         self._realroot = os.path.realpath(self.root)
         self._handles: dict[int, Any] = {}
         self._next_handle = 1
-        self.stats = {"reads": 0, "bytes": 0, "opens": 0}
+        self.stats = {"reads": 0, "bytes": 0, "opens": 0,
+                      "open_reads": 0, "closed_at_eof": 0}
 
     def _resolve(self, rel: str) -> str:
         rel = rel.strip("/")
@@ -204,6 +212,13 @@ class AgentFSServer:
 
     async def _open(self, req, ctx):
         p = self._resolve(req.payload["path"])
+        # `read`: the file's first read rides on its open (the backup
+        # pump's one call for a file of one block).  A peer that does
+        # not send it gets the bare handle.
+        n = req.payload.get("read")
+        if n is not None and not (isinstance(n, int)
+                                  and 0 <= n <= MAX_READ):
+            raise HandlerError(f"read size {n!r} out of range", status=400)
         if len(self._handles) >= MAX_HANDLES:
             raise HandlerError(
                 f"too many open handles ({MAX_HANDLES})", status=429)
@@ -235,11 +250,39 @@ class AgentFSServer:
         except OSError as e:
             os.close(fd)
             raise HandlerError(f"open: {e}", status=400)
+        self.stats["opens"] += 1
+        if n is None:
+            return {"handle": self._keep(f)}
+        # every gate above has passed before a byte is read
+        try:
+            data = os.pread(f.fileno(), n, 0)
+        except OSError as e:
+            f.close()
+            raise HandlerError(f"pread: {e}", status=500)
+        self.stats["open_reads"] += 1
+        self.stats["bytes"] += len(data)
+        # only regular files are opened, so a short pread is the end:
+        # the agent closes the file itself and no handle is left for a
+        # crashed server to leak
+        eof = len(data) < n
+        if eof:
+            f.close()
+            self.stats["closed_at_eof"] += 1
+        return self._raw_bytes(
+            data, {"handle": 0 if eof else self._keep(f),
+                   "n": len(data), "eof": eof})
+
+    def _keep(self, f) -> int:
         h = self._next_handle
         self._next_handle += 1
         self._handles[h] = f
-        self.stats["opens"] += 1
-        return {"handle": h}
+        return h
+
+    @staticmethod
+    def _raw_bytes(data: bytes, meta: dict) -> RawStreamHandler:
+        async def pump(stream):
+            await send_data_from_reader(stream, data, len(data))
+        return RawStreamHandler(pump, data=meta)
 
     def _file(self, handle: int):
         f = self._handles.get(handle)
@@ -259,10 +302,7 @@ class AgentFSServer:
             raise HandlerError(f"pread: {e}", status=500)
         self.stats["reads"] += 1
         self.stats["bytes"] += len(data)
-
-        async def pump(stream):
-            await send_data_from_reader(stream, data, len(data))
-        return RawStreamHandler(pump, data={"n": len(data)})
+        return self._raw_bytes(data, {"n": len(data)})
 
     async def _lseek(self, req, ctx):
         f = self._file(req.payload["handle"])
@@ -288,6 +328,12 @@ class AgentFSServer:
             except OSError:
                 pass
         self._handles.clear()
+
+
+class FirstReadError(RuntimeError):
+    """``open_read``: the file opened and its first read failed.  The
+    peer has closed the file; the caller ends the file as it ends a
+    failed ``read_at``, not as a failed open."""
 
 
 class AgentFSClient:
@@ -326,6 +372,25 @@ class AgentFSClient:
 
     async def open(self, path: str) -> int:
         return (await self.s.call("agentfs.open", {"path": path})).data["handle"]
+
+    async def open_read(self, path: str, n: int) -> tuple[int, bytes, bool]:
+        """Open ``path`` and read its first ``n`` bytes in one call:
+        ``(handle, data, eof)``.  At ``eof`` the agent has closed the
+        file and ``handle`` is 0.  An agent that predates the ``read``
+        key ignores it and answers 200 with a bare handle: that comes
+        back as ``(handle, b"", False)`` and the caller goes on with
+        ``read_at`` — what the peer answered decides, nothing else."""
+        buf = bytearray()
+        try:
+            resp, _ = await self.s.call_binary_into(
+                "agentfs.open", {"path": path, "read": n}, buf)
+        except CallError as e:
+            if e.response.status == STATUS_ERROR:
+                raise FirstReadError(e.response.message) from e
+            raise
+        if resp.status != STATUS_RAW_STREAM:
+            return resp.data["handle"], b"", False
+        return resp.data["handle"], bytes(buf), bool(resp.data["eof"])
 
     async def read_at(self, handle: int, off: int, n: int) -> bytes:
         buf = bytearray()

@@ -210,7 +210,7 @@ class AgentLifecycle:
                 await asyncio.get_running_loop().run_in_executor(
                     None, self.snapshots.cleanup, job.snapshot)
             self.jobs.pop(job.job_id, None)
-            self.log.info("backup job session closed")
+            self.log.info("backup job session closed (agentfs: %s)", fs.stats)
 
     @staticmethod
     def _remove_handoff(proc) -> None:

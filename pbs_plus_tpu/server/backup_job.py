@@ -16,7 +16,10 @@ backend is the pluggable CPU/TPU pipeline).  Dataflow:
 
 The async side prefetches up to ``queue_depth`` file blocks ahead (the
 reference's readahead/buffer-pool role); the writer thread runs the
-synchronous dedup writer without blocking the event loop.
+synchronous dedup writer without blocking the event loop.  A file's
+first read rides on its open (``agentfs.open`` with ``read``), and a
+block shorter than ``READ_BLOCK`` is the file's end: a file of one block
+is one call and one queue item (docs/data-plane.md "The pump").
 """
 
 from __future__ import annotations
@@ -31,7 +34,7 @@ import uuid
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
-from ..agent.agentfs import AgentFSClient
+from ..agent.agentfs import AgentFSClient, FirstReadError
 from ..arpc import Session
 from ..arpc.agents_manager import AgentsManager
 from ..chunker import ChunkerParams, CpuChunker
@@ -50,6 +53,13 @@ QUEUE_DEPTH = 8               # prefetched blocks in flight
 
 _SENTINEL = object()
 _ABORTED = object()
+
+# Process-wide totals of the pump, rendered on /metrics beside the
+# device batcher's (server/metrics.py): files it began to stream, those
+# that crossed the wire in one call, and its agentfs calls (open_read,
+# read_at, close).  A job's own counts are the attrs of its backup.pump
+# span.  Written on the event loop's thread alone.
+PUMP_TOTALS = {"files": 0, "one_call_files": 0, "calls": 0}
 
 
 def _get_abortable(q: "queue.Queue", abort: "threading.Event | None"):
@@ -186,13 +196,16 @@ class BackupResult:
 
 class _QueuePumpReader:
     """File-like .read(n) fed by a thread-safe queue of blocks (async
-    producer / sync writer-thread consumer)."""
+    producer / sync writer-thread consumer).  ``first`` is the block that
+    came with the open; with no queue it is the whole file."""
 
-    def __init__(self, q: "queue.Queue", abort: "threading.Event | None" = None):
+    def __init__(self, q: "queue.Queue | None",
+                 abort: "threading.Event | None" = None, *,
+                 first: bytes = b""):
         self._q = q
         self._abort = abort
-        self._buf = b""
-        self._eof = False
+        self._buf = first
+        self._eof = q is None
         # set by the writer thread when it dies: the async producer checks
         # it before each fq.put so a >64 MB file can't wedge the job on a
         # dead consumer (advisor finding r1)
@@ -240,6 +253,8 @@ class RemoteTreeBackup:
         # run fully committed splice via write_entry_ref with ZERO agent
         # reads — only the tail of the tree re-streams
         self.resume = getattr(session, "resume_plan", None)
+        # this job's share of PUMP_TOTALS
+        self.pump = dict.fromkeys(PUMP_TOTALS, 0)
         self._wq: queue.Queue = queue.Queue(maxsize=QUEUE_DEPTH)
         self._writer_exc: BaseException | None = None
         self._seen_inodes: dict[tuple[int, int], str] = {}
@@ -263,6 +278,15 @@ class RemoteTreeBackup:
         )
 
     async def run(self) -> BackupResult:
+        with trace.span("backup.pump") as sp:
+            try:
+                return await self._run()
+            finally:
+                sp.set(**self.pump)
+                for k, v in self.pump.items():
+                    PUMP_TOTALS[k] += v
+
+    async def _run(self) -> BackupResult:
         # hand the job's trace context to the writer thread: ingest
         # stage spans emitted there parent under the job span
         self._tctx = trace.capture()
@@ -368,52 +392,73 @@ class RemoteTreeBackup:
             self.result.entries += 1
 
     async def _stream_file(self, rel: str, entry: Entry) -> None:
-        """Prefetch file blocks over aRPC into the writer queue."""
-        try:
-            handle = await self.fs.open(rel)
-        except ConnectionError:
-            raise                       # dead transport: fail the job
-        except Exception as e:
-            self.result.errors.append(f"{rel}: open: {e}")
-            return
-        fq: queue.Queue = queue.Queue(maxsize=QUEUE_DEPTH)
-        reader = _QueuePumpReader(fq, self._abort)
-        await self._put(("file", entry, reader))
-        off = 0
+        """Prefetch file blocks over aRPC into the writer queue.  The
+        first block comes with the open, and a block shorter than
+        READ_BLOCK is the end (the agent opens regular files only): a
+        file of one block is one call and one item of the writer's
+        queue, with no block queue, sentinel or close of its own."""
+        loop = asyncio.get_running_loop()
+        pump = self.pump
+        pump["files"] += 1
+        handle, fq, reader, off = 0, None, None, 0
         try:
             while True:
-                if reader.dead:      # writer died; its drain empties fq
-                    break
                 await failpoints.ahit("backup.file.stream")
-                block = await self.fs.read_at(handle, off, READ_BLOCK)
-                if not block:
-                    break
-                await asyncio.get_running_loop().run_in_executor(
-                    None, fq.put, block)
+                pump["calls"] += 1
+                if reader is None:
+                    try:
+                        handle, block, eof = await self.fs.open_read(
+                            rel, READ_BLOCK)
+                    except (ConnectionError, FirstReadError):
+                        raise
+                    except Exception as e:
+                        self.result.errors.append(f"{rel}: open: {e}")
+                        return
+                    if eof:
+                        pump["one_call_files"] += 1
+                    else:
+                        fq = queue.Queue(maxsize=QUEUE_DEPTH)
+                    reader = _QueuePumpReader(fq, self._abort, first=block)
+                    await self._put(("file", entry, reader))
+                else:
+                    block = await self.fs.read_at(handle, off, READ_BLOCK)
+                    eof = len(block) < READ_BLOCK
+                    if block:
+                        await loop.run_in_executor(None, fq.put, block)
                 off += len(block)
                 self.result.bytes_total += len(block)
                 if self.resume is not None:
                     self.resume.note_reread(len(block))
-        except ConnectionError as e:
-            # dead transport: fail the writer's file AND the job (the
-            # job-level retry re-runs incrementally — committed chunks
-            # are already in the store)
-            await asyncio.get_running_loop().run_in_executor(
-                None, fq.put, RuntimeError(f"read {rel}: {e}"))
-            self.result.errors.append(f"{rel}: read: {e}")
-            raise
+                if eof or reader.dead:
+                    # dead: the writer died; its drain empties fq
+                    break
         except Exception as e:
-            await asyncio.get_running_loop().run_in_executor(
-                None, fq.put, RuntimeError(f"read {rel}: {e}"))
+            # the writer's file fails with the read; a dead transport
+            # fails the job too (the job-level retry re-runs
+            # incrementally — committed chunks are already in the store)
+            err = RuntimeError(f"read {rel}: {e}")
+            if reader is None:
+                # the first read: the writer gets the file all the same,
+                # as one whose read raises
+                failed: queue.Queue = queue.Queue(maxsize=1)
+                failed.put_nowait(err)
+                await self._put(
+                    ("file", entry, _QueuePumpReader(failed, self._abort)))
+            elif fq is not None:
+                await loop.run_in_executor(None, fq.put, err)
             self.result.errors.append(f"{rel}: read: {e}")
+            if isinstance(e, ConnectionError):
+                raise
             return
         finally:
-            await asyncio.get_running_loop().run_in_executor(
-                None, fq.put, _SENTINEL)
-            try:
-                await self.fs.close(handle)
-            except Exception as e:
-                self.log.debug("agentfs close failed for %s: %s", rel, e)
+            if fq is not None:
+                await loop.run_in_executor(None, fq.put, _SENTINEL)
+            if handle:
+                pump["calls"] += 1
+                try:
+                    await self.fs.close(handle)
+                except Exception as e:
+                    self.log.debug("agentfs close failed for %s: %s", rel, e)
         self.result.files += 1
         if self.resume is not None:
             self.resume.note_reread(0, files=1)
@@ -440,8 +485,8 @@ class RemoteTreeBackup:
     def _nowait_drain_all(self, current) -> None:
         """Abort path: free every blocked executor-thread put without
         waiting for producers that were cancelled mid-flight."""
-        def drain_q(q: "queue.Queue") -> None:
-            while True:
+        def drain_q(q: "queue.Queue | None") -> None:
+            while q is not None:        # None: a file of one queue item
                 try:
                     q.get_nowait()
                 except queue.Empty:

@@ -581,6 +581,22 @@ class MetricsRegistry:
                ({"outcome": "alone"}, float(fd["linger_rounds"]
                                             - fd["linger_joined"]))]
               if "linger_rounds" in fd else [])
+        # the pump under the batcher (server/backup_job.py PUMP_TOTALS):
+        # calls a file is what the agent's side and the one event loop
+        # pay per file, whatever its size
+        from . import backup_job as _backup_job
+        pt = dict(_backup_job.PUMP_TOTALS)
+        gauge("pbs_plus_pump_files_total",
+              "Files the backup pumps began to stream from agents, by "
+              "how they crossed the wire: in one call (the first read "
+              "came with the open and was the whole file) or in several",
+              [({"calls": "one"}, float(pt["one_call_files"])),
+               ({"calls": "several"},
+                float(pt["files"] - pt["one_call_files"]))])
+        gauge("pbs_plus_pump_calls_total",
+              "agentfs calls the backup pumps made for file content "
+              "(open with its first read, read_at, close)",
+              [({}, float(pt["calls"]))])
         gauge("pbs_plus_device_compilations_total",
               "Programs jax built or loaded from its cache since the "
               "device ops were loaded; one that moves while backups run "

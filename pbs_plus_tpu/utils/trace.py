@@ -85,6 +85,9 @@ SPANS = {
     "backup.session_open": ("pbs_plus_session_open_seconds",
                             {"phase": "job"}),
     "backup.publish": None,
+    # the pump's whole walk (RemoteTreeBackup.run); attrs: its files,
+    # those of one call, and its agentfs calls
+    "backup.pump": None,
     "session.open": ("pbs_plus_session_open_seconds",
                      {"phase": "connect"}),
     # batched ingest stages (pxar/transfer.py, pxar/pipeline.py)

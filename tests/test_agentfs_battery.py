@@ -327,3 +327,168 @@ def test_statfs_and_raced_unlink(pki, tmp_path):
             names = [e["name"] for e in await c.read_dir("d")]
             assert names == ["k0", "k1", "k3", "k4"]
     asyncio.run(main())
+
+
+# ------------------------------------------------- open with its first read
+
+BLOCK = 4096        # the `read` these tests ask for
+
+
+@pytest.mark.parametrize("size", [0, 1, BLOCK - 1, BLOCK, BLOCK + 1])
+def test_open_read_data_eof_and_handle(pki, tmp_path, size):
+    """`agentfs.open` with `read`: the first block and whether it was the
+    last; at eof the agent has closed the file and hands out no handle."""
+    body = bytes(i % 251 for i in range(size))
+    (tmp_path / "f").write_bytes(body)
+
+    async def main():
+        h = Harness(pki, tmp_path)
+        async with h as c:
+            handle, data, eof = await c.open_read("f", BLOCK)
+            assert data == body[:BLOCK]
+            assert eof == (size < BLOCK)
+            one_block = size < BLOCK
+            assert (handle == 0) == one_block
+            assert len(h.fs._handles) == (0 if one_block else 1)
+            assert h.fs.stats["opens"] == h.fs.stats["open_reads"] == 1
+            assert h.fs.stats["closed_at_eof"] == int(one_block)
+            assert h.fs.stats["reads"] == 0
+            assert h.fs.stats["bytes"] == len(data)
+            if not one_block:
+                # the handle is a plain one: the rest follows by read_at
+                assert await c.read_at(handle, BLOCK, BLOCK) == body[BLOCK:]
+                await c.close(handle)
+                assert len(h.fs._handles) == 0
+    asyncio.run(main())
+
+
+@pytest.mark.parametrize("path,n,status", [
+    ("evil", BLOCK, 400),           # symlink out of the root
+    ("pipe", BLOCK, 400),           # fifo: not a regular file, no hang
+    ("missing", BLOCK, 404),
+    ("f", -1, 400),
+    ("f", (32 << 20) + 1, 400),     # beyond MAX_READ
+    ("f", "many", 400),
+])
+def test_open_read_refused_before_a_byte_is_read(pki, tmp_path, path, n,
+                                                 status):
+    snap = tmp_path / "snap"
+    snap.mkdir()
+    (snap / "f").write_bytes(b"inside")
+    (tmp_path / "secret").write_bytes(b"outside")
+    os.symlink(str(tmp_path / "secret"), snap / "evil")
+    os.mkfifo(snap / "pipe")
+
+    async def main():
+        h = Harness(pki, snap)
+        async with h as c:
+            buf = bytearray()
+            with pytest.raises(CallError) as ei:
+                await asyncio.wait_for(c.s.call_binary_into(
+                    "agentfs.open", {"path": path, "read": n}, buf), 10)
+            assert ei.value.response.status == status
+            assert not buf
+            assert h.fs.stats["bytes"] == h.fs.stats["open_reads"] == 0
+            assert len(h.fs._handles) == 0
+    asyncio.run(main())
+
+
+def test_open_without_read_answers_as_before(pki, tmp_path):
+    (tmp_path / "f").write_bytes(b"payload")
+
+    async def main():
+        h = Harness(pki, tmp_path)
+        async with h as c:
+            resp = await c.s.call("agentfs.open", {"path": "f"})
+            assert resp.status == 200 and list(resp.data) == ["handle"]
+            assert h.fs.stats["open_reads"] == 0
+            assert await c.read_at(resp.data["handle"], 0, 99) == b"payload"
+    asyncio.run(main())
+
+
+def test_open_read_ten_thousand_small_files_never_429(pki, tmp_path):
+    """Files of one block leave no handle behind, so a tree of any
+    length never meets MAX_HANDLES — and a server that crashes mid-tree
+    leaks none."""
+    d = tmp_path / "d"
+    d.mkdir()
+    n_files = 10_000
+    assert n_files > MAX_HANDLES
+    for i in range(n_files):
+        (d / f"f{i:05d}").write_bytes(b"%05d" % i)
+
+    async def main():
+        h = Harness(pki, tmp_path)
+        async with h as c:
+            for i in range(n_files):
+                handle, data, eof = await c.open_read(f"d/f{i:05d}", BLOCK)
+                assert (handle, data, eof) == (0, b"%05d" % i, True)
+            assert len(h.fs._handles) == 0
+            assert h.fs.stats["closed_at_eof"] == n_files
+    asyncio.run(main())
+
+
+def _skew_tree(root) -> None:
+    import numpy as np
+    rng = np.random.default_rng(29)
+    (root / "sub").mkdir(parents=True)
+    for i, size in enumerate([0, 1, 700, BLOCK - 1, BLOCK, BLOCK + 1,
+                              3 * BLOCK, 5 * BLOCK + 17]):
+        (root / "sub" / f"f{i}.bin").write_bytes(
+            rng.integers(0, 256, size, dtype=np.uint8).tobytes())
+
+
+def test_agent_that_ignores_read_publishes_identically(pki, tmp_path,
+                                                       monkeypatch):
+    """Version skew needs no switch: the pump reads what the peer
+    answered.  Both agents' snapshots have identical index records and
+    file digests; only the calls differ."""
+    from pbs_plus_tpu.chunker import ChunkerParams
+    from pbs_plus_tpu.pxar.backupproxy import LocalStore
+    from pbs_plus_tpu.server import backup_job as bj
+    from tools.pump_cost import IgnoresRead
+    monkeypatch.setattr(bj, "READ_BLOCK", BLOCK)
+    src = tmp_path / "src"
+    _skew_tree(src)
+
+    async def backup(agent_cls, name):
+        store = LocalStore(str(tmp_path / name),
+                           ChunkerParams(avg_size=4096, min_size=1024,
+                                         max_size=16384))
+        h = Harness(pki, src)
+        h.fs = agent_cls(str(src))
+        async with h as c:
+            loop = asyncio.get_running_loop()
+            session = await loop.run_in_executor(
+                None, lambda: store.start_session(
+                    backup_type="host", backup_id="skew"))
+            pump = bj.RemoteTreeBackup(c, session)
+            res = await asyncio.wait_for(pump.run(), 60)
+            assert res.errors == [] and res.files == 8
+            await loop.run_in_executor(None, session.finish, {})
+            assert len(h.fs._handles) == 0
+        r = store.open_snapshot(session.ref)
+        # every record of both indexes (a file's header carries a random
+        # uuid, so the files themselves never compare equal)
+        idx = [[(ix.chunk_bounds(i), ix.digest(i)) for i in range(len(ix))]
+               for ix in (r.meta_index, r.payload_index)]
+        digests = {f"sub/f{i}.bin": r.lookup(f"sub/f{i}.bin").digest
+                   for i in range(8)}
+        return idx, digests, dict(pump.pump), dict(h.fs.stats)
+
+    async def main():
+        return (await backup(AgentFSServer, "new"),
+                await backup(IgnoresRead, "old"))
+
+    (idx_new, dig_new, pump_new, st_new), \
+        (idx_old, dig_old, pump_old, st_old) = asyncio.run(main())
+    assert idx_new == idx_old and all(idx_new)
+    assert dig_new == dig_old and all(dig_new.values())
+    # four files of one block: one call each, closed by the agent
+    assert pump_new == {"files": 8, "one_call_files": 4,
+                        "calls": 4 + 3 + 3 + 5 + 7}
+    assert st_new["open_reads"] == 8 and st_new["closed_at_eof"] == 4
+    # the old agent: open, read_at until a short block, close
+    assert pump_old["one_call_files"] == 0
+    assert pump_old["calls"] == 4 * 3 + 4 + 4 + 6 + 8
+    assert st_old["open_reads"] == 0 and st_old["opens"] == 8

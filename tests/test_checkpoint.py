@@ -23,6 +23,7 @@ import time
 import numpy as np
 import pytest
 
+from agentfs_fakes import OpenReadViaCalls
 from pbs_plus_tpu.agent.agentfs import _entry_map
 from pbs_plus_tpu.chunker import ChunkerParams
 from pbs_plus_tpu.pxar.backupproxy import LocalStore
@@ -42,7 +43,7 @@ def _clean_failpoints():
     failpoints.disarm_all()
 
 
-class CountingAgentFS:
+class CountingAgentFS(OpenReadViaCalls):
     """AgentFSClient duck-type over a local directory that COUNTS the
     bytes handed out by read_at — the 'agent bytes read' meter the
     resume bound is asserted against."""

@@ -29,6 +29,7 @@ import os
 import numpy as np
 import pytest
 
+from agentfs_fakes import OpenReadViaCalls
 from pbs_plus_tpu.agent.agentfs import _entry_map
 from pbs_plus_tpu.chunker import ChunkerParams
 from pbs_plus_tpu.pxar.backupproxy import LocalStore
@@ -51,7 +52,7 @@ def _clean_failpoints():
     failpoints.disarm_all()
 
 
-class LocalAgentFS:
+class LocalAgentFS(OpenReadViaCalls):
     """AgentFSClient duck-type over a local directory."""
 
     def __init__(self, root: str):

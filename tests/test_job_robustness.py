@@ -6,13 +6,14 @@ import asyncio
 
 import pytest
 
+from agentfs_fakes import OpenReadViaCalls
 from pbs_plus_tpu.pxar.datastore import parse_snapshot_ref
 from pbs_plus_tpu.pxar.format import KIND_DIR, KIND_FILE
 from pbs_plus_tpu.server import backup_job as bj
 from pbs_plus_tpu.server.backup_job import RemoteTreeBackup
 
 
-class _FakeAgentFS:
+class _FakeAgentFS(OpenReadViaCalls):
     """Serves one directory containing one very large file (many blocks)."""
 
     def __init__(self, blocks: int, block: bytes):

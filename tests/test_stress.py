@@ -209,6 +209,7 @@ def test_writer_queue_full_then_slow_consumer(pki, tmp_path):
     fast producer never deadlocks and never drops bytes."""
     import queue as q
 
+    from agentfs_fakes import OpenReadViaCalls
     from pbs_plus_tpu.server import backup_job as bj
     from pbs_plus_tpu.server.backup_job import RemoteTreeBackup
     from pbs_plus_tpu.pxar.format import KIND_DIR, KIND_FILE
@@ -229,7 +230,7 @@ def test_writer_queue_full_then_slow_consumer(pki, tmp_path):
                 self.bytes += len(b)
                 time.sleep(0.001)
 
-    class FS:
+    class FS(OpenReadViaCalls):
         async def attr(self, rel):
             return {"kind": KIND_DIR, "mode": 0o755, "uid": 0, "gid": 0,
                     "mtime_ns": 0, "size": 0}
