@@ -306,6 +306,62 @@ def test_queue_waits_grow_and_thread_clocks_partition_its_life(wide_feeder):
     assert all(last[k] > 0 for k in STATES), last
 
 
+@pytest.mark.parametrize("n, batch_fails",
+                         [(1, False), (3, False), (3, True)],
+                         ids=["one-row", "batch-lands", "retried-alone"])
+def test_wait_and_busy_clocks_bound_what_writers_stood(
+        wide_feeder, monkeypatch, hold_requests, n, batch_fails):
+    """No counter times a writer's stay in ``candidate_hits``; the
+    benchmark's ``scan_turnaround_pct`` takes it as ``mask_wait_s`` +
+    ``mask_busy_s``.  A request's stay is its queue wait and then its
+    dispatch: the writers stood no less than their waits and the device
+    call, and no more than their waits and a ``mask_busy_s`` each — with
+    one row a round the two meet — on the batch path and, when the batch
+    fails and each request is retried alone, there too."""
+    import time
+
+    from pbs_plus_tpu.utils import trace
+    call_s, slack_s = 0.3, 0.5     # a slow device call; wake-ups, the GIL
+    real = wide_feeder._mask_hits
+    hold_requests(wide_feeder, n)       # one round carries them all
+
+    def slow(key, group):
+        if batch_fails and len(group) > 1:
+            raise MemoryError("injected: batch does not fit")
+        time.sleep(call_s)
+        return real(key, group)
+    monkeypatch.setattr(wide_feeder, "_mask_hits", slow)
+    stood: list[float] = []
+    barrier = threading.Barrier(n)
+
+    def work(i):
+        data = np.frombuffer(_data(40_000, seed=300 + i), np.uint8)
+        barrier.wait()
+        t = time.perf_counter()
+        wide_feeder.candidate_hits(data, np.zeros(63, np.uint8), P)
+        stood.append(time.perf_counter() - t)
+    spans: list[dict] = []
+    trace.subscribe(spans.append)
+    try:
+        threads = [threading.Thread(target=work, args=(i,))
+                   for i in range(n)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+            assert not t.is_alive()
+    finally:
+        trace.unsubscribe(spans.append)
+    stats = wide_feeder.stats
+    assert stats["mask_retried_alone"] == (n if batch_fails else 0)
+    assert stats["mask_rows"] == n and stats["rounds"] == 1
+    waits, busy = stats["mask_wait_s"], stats["mask_busy_s"]
+    assert waits > 0 and busy >= call_s * (n if batch_fails else 1)
+    assert waits + n * call_s <= sum(stood) <= waits + n * busy + slack_s
+    (dispatch,) = [r for r in spans if r["name"] == "feeder.dispatch"]
+    assert dispatch["attrs"]["retried"] == (n if batch_fails else 0)
+
+
 def test_one_dispatch_span_per_round_links_its_submitters(wide_feeder):
     """Every mask group and hash round is one ``feeder.dispatch`` span
     whose ``links`` are the contexts the writers captured at submit, and
