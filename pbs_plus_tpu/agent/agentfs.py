@@ -22,6 +22,11 @@ hot loop, SURVEY §3.2):
                                                     eof the file is closed
                                                     and handle is 0
     agentfs.read_at   {handle, off, n}            → 213 raw stream
+    agentfs.read_many {paths, budget}             → 213 raw stream of whole
+                                                    files back to back, with
+                                                    {files: [{n} | {status,
+                                                    message}]} for a prefix
+                                                    of paths; no handle
     agentfs.lseek     {handle, off, whence}       → {pos}
     agentfs.close     {handle}                    → {}
 """
@@ -34,7 +39,8 @@ import stat as statmod
 from typing import Any
 
 from ..arpc.call import (
-    STATUS_ERROR, STATUS_RAW_STREAM, CallError, RawStreamHandler,
+    STATUS_ERROR, STATUS_NOT_FOUND, STATUS_RAW_STREAM, CallError,
+    RawStreamHandler, Response,
 )
 from ..arpc.router import HandlerError, Router
 from ..arpc.binary_stream import send_data_from_reader
@@ -84,7 +90,8 @@ class AgentFSServer:
         self._handles: dict[int, Any] = {}
         self._next_handle = 1
         self.stats = {"reads": 0, "bytes": 0, "opens": 0,
-                      "open_reads": 0, "closed_at_eof": 0}
+                      "open_reads": 0, "closed_at_eof": 0,
+                      "read_many": 0, "read_many_files": 0}
 
     def _resolve(self, rel: str) -> str:
         rel = rel.strip("/")
@@ -122,6 +129,7 @@ class AgentFSServer:
         router.handle("agentfs.xattrs", self._xattrs)
         router.handle("agentfs.open", self._open)
         router.handle("agentfs.read_at", self._read_at)
+        router.handle("agentfs.read_many", self._read_many)
         router.handle("agentfs.lseek", self._lseek)
         router.handle("agentfs.close", self._close)
 
@@ -210,18 +218,11 @@ class AgentFSServer:
         self._check_contained(p, req.payload["path"], follow_final=False)
         return {"xattrs": read_xattrs(p)}
 
-    async def _open(self, req, ctx):
-        p = self._resolve(req.payload["path"])
-        # `read`: the file's first read rides on its open (the backup
-        # pump's one call for a file of one block).  A peer that does
-        # not send it gets the bare handle.
-        n = req.payload.get("read")
-        if n is not None and not (isinstance(n, int)
-                                  and 0 <= n <= MAX_READ):
-            raise HandlerError(f"read size {n!r} out of range", status=400)
-        if len(self._handles) >= MAX_HANDLES:
-            raise HandlerError(
-                f"too many open handles ({MAX_HANDLES})", status=429)
+    def _open_regular(self, rel: str):
+        """Open ``rel`` for reading behind every gate a content read
+        has: both ``_open`` and ``_read_many`` come through here, so a
+        byte is never read that one of them would have refused."""
+        p = self._resolve(rel)
         # O_NONBLOCK: an open() on a fifo blocks until a writer appears —
         # a raced or hostile path must not hang the agent's event loop
         try:
@@ -241,8 +242,8 @@ class AgentFSServer:
             rp = os.path.realpath(proc) if os.path.exists(proc) \
                 else os.path.realpath(p)
             if not self._within_realroot(rp):
-                raise HandlerError(f"symlink escapes root: "
-                                   f"{req.payload['path']!r}", status=400)
+                raise HandlerError(f"symlink escapes root: {rel!r}",
+                                   status=400)
             f = os.fdopen(fd, "rb", buffering=0)
         except HandlerError:
             os.close(fd)
@@ -251,6 +252,20 @@ class AgentFSServer:
             os.close(fd)
             raise HandlerError(f"open: {e}", status=400)
         self.stats["opens"] += 1
+        return f
+
+    async def _open(self, req, ctx):
+        # `read`: the file's first read rides on its open (the backup
+        # pump's one call for a file of one block).  A peer that does
+        # not send it gets the bare handle.
+        n = req.payload.get("read")
+        if n is not None and not (isinstance(n, int)
+                                  and 0 <= n <= MAX_READ):
+            raise HandlerError(f"read size {n!r} out of range", status=400)
+        if len(self._handles) >= MAX_HANDLES:
+            raise HandlerError(
+                f"too many open handles ({MAX_HANDLES})", status=429)
+        f = self._open_regular(req.payload["path"])
         if n is None:
             return {"handle": self._keep(f)}
         # every gate above has passed before a byte is read
@@ -303,6 +318,47 @@ class AgentFSServer:
         self.stats["reads"] += 1
         self.stats["bytes"] += len(data)
         return self._raw_bytes(data, {"n": len(data)})
+
+    async def _read_many(self, req, ctx):
+        """A run of small files in one answer (the backup pump's one
+        call for a directory's consecutive small files): each file of
+        ``paths`` in turn is opened as ``_open`` opens it, read whole —
+        a short pread is the end — and closed, until the next would take
+        the bytes past ``budget``.  That file and those after it are not
+        served and have no record; a file that fails answers with its
+        own error and the run goes on.  No handle outlives the call."""
+        paths, budget = req.payload.get("paths"), req.payload.get("budget")
+        if not (isinstance(budget, int) and 1 <= budget <= MAX_READ):
+            raise HandlerError(f"budget {budget!r} out of range", status=400)
+        if not (isinstance(paths, list)
+                and all(isinstance(rel, str) for rel in paths)):
+            raise HandlerError("paths must be a list of strings", status=400)
+        self.stats["read_many"] += 1
+        files, parts, left = [], [], budget
+        for rel in paths:
+            try:
+                f = self._open_regular(rel)
+            except HandlerError as e:
+                files.append({"status": e.status, "message": str(e)})
+                continue
+            try:
+                # one byte more than may be sent tells a file that ends
+                # inside the budget from one that passes it
+                data = os.pread(f.fileno(), left + 1, 0)
+            except OSError as e:
+                files.append({"status": STATUS_ERROR,
+                              "message": f"pread: {e}"})
+                continue
+            finally:
+                f.close()
+            if len(data) > left:
+                break       # it grew since the listing: ends the prefix
+            left -= len(data)
+            parts.append(data)
+            files.append({"n": len(data)})
+        self.stats["read_many_files"] += len(parts)
+        self.stats["bytes"] += budget - left
+        return self._raw_bytes(b"".join(parts), {"files": files})
 
     async def _lseek(self, req, ctx):
         f = self._file(req.payload["handle"])
@@ -391,6 +447,40 @@ class AgentFSClient:
         if resp.status != STATUS_RAW_STREAM:
             return resp.data["handle"], b"", False
         return resp.data["handle"], bytes(buf), bool(resp.data["eof"])
+
+    async def read_many(self, paths: list[str],
+                        budget: int) -> "list | None":
+        """Read a run of small files whole in one call, at most
+        ``budget`` bytes in all.  One item for each of ``paths``: the
+        file's bytes; or the exception ``open_read`` would have raised
+        for it (a ``CallError`` for a refused open, a ``FirstReadError``
+        for a failed read); or None for a file the agent did not serve —
+        it would have passed the budget, and so has every path after it.
+        None instead of the list: the agent does not know the method
+        (404 from the router; a file's own 404 is in its item, never in
+        the call's status) and the caller reads file by file — what the
+        peer answered decides, nothing else."""
+        buf = bytearray()
+        try:
+            resp, _ = await self.s.call_binary_into(
+                "agentfs.read_many", {"paths": paths, "budget": budget},
+                buf)
+        except CallError as e:
+            if e.response.status == STATUS_NOT_FOUND:
+                return None
+            raise
+        out: list = []
+        view, off = memoryview(buf), 0
+        for rec in resp.data["files"]:
+            if "n" in rec:
+                out.append(bytes(view[off:off + rec["n"]]))
+                off += rec["n"]
+            elif rec["status"] == STATUS_ERROR:
+                out.append(FirstReadError(rec["message"]))
+            else:
+                out.append(CallError(Response(rec["status"],
+                                              rec["message"])))
+        return out + [None] * (len(paths) - len(out))
 
     async def read_at(self, handle: int, off: int, n: int) -> bytes:
         buf = bytearray()

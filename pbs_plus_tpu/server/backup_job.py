@@ -19,7 +19,10 @@ reference's readahead/buffer-pool role); the writer thread runs the
 synchronous dedup writer without blocking the event loop.  A file's
 first read rides on its open (``agentfs.open`` with ``read``), and a
 block shorter than ``READ_BLOCK`` is the file's end: a file of one block
-is one call and one queue item (docs/data-plane.md "The pump").
+is one call and one queue item.  A listing's consecutive small files
+cross the wire together: one ``agentfs.read_many`` for the run, and its
+files reach the writer's queue in one step (docs/data-plane.md "The
+pump").
 """
 
 from __future__ import annotations
@@ -49,6 +52,10 @@ from ..utils.resilience import CircuitBreaker, with_retry
 from . import checkpoint, database
 
 READ_BLOCK = 8 << 20          # agentfs read granularity
+READ_MANY_FILES = 256         # files a read_many at most: paths of any
+                              # length and their records stay far inside
+                              # aRPC's envelope limit, and a directory of
+                              # empty files is still a call per 256
 QUEUE_DEPTH = 8               # prefetched blocks in flight
 
 _SENTINEL = object()
@@ -56,10 +63,13 @@ _ABORTED = object()
 
 # Process-wide totals of the pump, rendered on /metrics beside the
 # device batcher's (server/metrics.py): files it began to stream, those
-# that crossed the wire in one call, and its agentfs calls (open_read,
-# read_at, close).  A job's own counts are the attrs of its backup.pump
-# span.  Written on the event loop's thread alone.
-PUMP_TOTALS = {"files": 0, "one_call_files": 0, "calls": 0}
+# that crossed the wire in one call of their own, its agentfs calls
+# (open_read, read_at, close, read_many), the files whose bytes came in
+# a read_many answer, and those answers.  A job's own counts are the
+# attrs of its backup.pump span.  Written on the event loop's thread
+# alone.
+PUMP_TOTALS = {"files": 0, "one_call_files": 0, "calls": 0,
+               "batched_files": 0, "batch_calls": 0}
 
 
 def _get_abortable(q: "queue.Queue", abort: "threading.Event | None"):
@@ -255,6 +265,8 @@ class RemoteTreeBackup:
         self.resume = getattr(session, "resume_plan", None)
         # this job's share of PUMP_TOTALS
         self.pump = dict.fromkeys(PUMP_TOTALS, 0)
+        # until the agent answers that it does not know read_many
+        self._batching = True
         self._wq: queue.Queue = queue.Queue(maxsize=QUEUE_DEPTH)
         self._writer_exc: BaseException | None = None
         self._seen_inodes: dict[tuple[int, int], str] = {}
@@ -331,8 +343,30 @@ class RemoteTreeBackup:
         await asyncio.get_running_loop().run_in_executor(
             None, self._wq.put, item)
 
+    async def _put_many(self, items: list) -> None:
+        """Hand the writer several items in order with one hop at most:
+        what the queue has room for goes in from here, the rest from
+        one executor call."""
+        for i, item in enumerate(items):
+            try:
+                self._wq.put_nowait(item)
+            except queue.Full:
+                await asyncio.get_running_loop().run_in_executor(
+                    None, self._put_rest, items[i:])
+                return
+
+    def _put_rest(self, items: list) -> None:
+        # an aborted writer stops taking (its one drain empties the
+        # queue once): give up then, not block a pool thread for good
+        for item in items:
+            while not self._abort.is_set():
+                try:
+                    self._wq.put(item, timeout=0.25)
+                    break
+                except queue.Full:
+                    pass
+
     async def _walk(self, rel: str) -> None:
-        seen_inodes = self._seen_inodes
         try:
             entries = await self.fs.read_dir(rel)
         except ConnectionError:
@@ -343,53 +377,150 @@ class RemoteTreeBackup:
         except Exception as e:
             self.result.errors.append(f"{rel}: {e}")
             return
+        # consecutive small files of this listing, read with one call
+        run: list[tuple[str, Entry]] = []
+        run_bytes = 0
         for m in entries:
             child = f"{rel}/{m['name']}" if rel else m["name"]
             if self._excluded(child):
                 continue
             kind = m["kind"]
             e = self._to_entry(child, m)
-            if kind == KIND_DIR:
-                await self._put(("entry", e, None))
-                await self._walk(child)
-            elif kind == KIND_FILE:
-                key = (m.get("dev", 0), m.get("ino", 0))
-                if m.get("nlink", 1) > 1 and key in seen_inodes:
-                    e.kind = KIND_HARDLINK
-                    e.link_target = seen_inodes[key]
-                    e.size = 0
-                    await self._put(("entry", e, None))
-                else:
-                    if m.get("nlink", 1) > 1:
-                        seen_inodes[key] = child
-                    src_e = (self.resume.skip_ref(child, e.size, e.mtime_ns)
-                             if self.resume is not None else None)
-                    if src_e is not None:
-                        # digest rides along from the checkpoint entry so
-                        # verification sees the whole-file sha256 (the
-                        # mount commit engine's ref discipline)
-                        e.digest = src_e.digest
-                        await self._put(
-                            ("ref", e, (src_e.payload_offset, src_e.size)))
-                        # spliced files count as completed files, same
-                        # as the local walker's skip branch
-                        self.result.files += 1
-                    else:
-                        await self._stream_file(child, e)
-            elif kind == KIND_SYMLINK:
-                # multiply-linked symlinks are hardlink entries here too
-                # (same rsync -H parity as pxar/walker.py's local walk)
-                key = (m.get("dev", 0), m.get("ino", 0))
-                if m.get("nlink", 1) > 1 and key in seen_inodes:
-                    e.kind = KIND_HARDLINK
-                    e.link_target = seen_inodes[key]
-                elif m.get("nlink", 1) > 1:
-                    seen_inodes[key] = child
-                await self._put(("entry", e, None))
-            elif kind in (KIND_FIFO, KIND_SOCKET, KIND_DEVICE,
-                          KIND_BLOCKDEV):
-                await self._put(("entry", e, None))
+            item = self._item_without_read(child, m, e)
+            if item is None and kind == KIND_FILE and self._batching \
+                    and e.size < READ_BLOCK:
+                # the bytes in flight are what one block is, and the
+                # request and its answer stay small
+                if run_bytes + e.size > READ_BLOCK \
+                        or len(run) == READ_MANY_FILES:
+                    await self._stream_run(run)
+                    run, run_bytes = [], 0
+                run.append((child, e))
+                run_bytes += e.size
+            else:
+                # anything else goes to the writer after the run
+                await self._stream_run(run)
+                run, run_bytes = [], 0
+                if item is not None:
+                    await self._put(item)
+                    if kind == KIND_DIR:
+                        await self._walk(child)
+                elif kind == KIND_FILE:
+                    await self._stream_file(child, e)
             self.result.entries += 1
+        await self._stream_run(run)
+
+    def _item_without_read(self, child: str, m: dict, e: Entry):
+        """The writer's item for an entry that costs no read of file
+        content, or None: a regular file whose bytes are to be read (or
+        a kind the archive does not carry)."""
+        seen_inodes = self._seen_inodes
+        kind = m["kind"]
+        if kind == KIND_FILE:
+            key = (m.get("dev", 0), m.get("ino", 0))
+            if m.get("nlink", 1) > 1 and key in seen_inodes:
+                e.kind = KIND_HARDLINK
+                e.link_target = seen_inodes[key]
+                e.size = 0
+                return ("entry", e, None)
+            if m.get("nlink", 1) > 1:
+                seen_inodes[key] = child
+            src_e = (self.resume.skip_ref(child, e.size, e.mtime_ns)
+                     if self.resume is not None else None)
+            if src_e is None:
+                return None
+            # digest rides along from the checkpoint entry so
+            # verification sees the whole-file sha256 (the mount commit
+            # engine's ref discipline)
+            e.digest = src_e.digest
+            # spliced files count as completed files, same as the local
+            # walker's skip branch
+            self.result.files += 1
+            return ("ref", e, (src_e.payload_offset, src_e.size))
+        if kind == KIND_SYMLINK:
+            # multiply-linked symlinks are hardlink entries here too
+            # (same rsync -H parity as pxar/walker.py's local walk)
+            key = (m.get("dev", 0), m.get("ino", 0))
+            if m.get("nlink", 1) > 1 and key in seen_inodes:
+                e.kind = KIND_HARDLINK
+                e.link_target = seen_inodes[key]
+            elif m.get("nlink", 1) > 1:
+                seen_inodes[key] = child
+        elif kind not in (KIND_DIR, KIND_FIFO, KIND_SOCKET, KIND_DEVICE,
+                          KIND_BLOCKDEV):
+            return None
+        return ("entry", e, None)
+
+    async def _stream_run(self, run: list[tuple[str, Entry]]) -> None:
+        """A run of small files: one ``read_many``, and every file it
+        served becomes what a one-call file is, a queue item that holds
+        the whole file, all of them handed to the writer in one step.
+        A file's own error ends that file as ``_stream_file`` ends it;
+        what the agent left unserved starts the next run, and a run of
+        one — or a first file left unserved — goes the one-file way, so
+        every turn of the loop takes a file off the run."""
+        pump = self.pump
+        while len(run) > 1 and self._batching:
+            pump["calls"] += 1
+            pump["batch_calls"] += 1
+            answer = await self.fs.read_many([rel for rel, _ in run],
+                                             READ_BLOCK)
+            if answer is None:
+                self._batching = False
+                break
+            if answer[0] is None:
+                await self._stream_file(*run[0])
+                run = run[1:]
+                continue
+            items, taken, dropped = [], 0, None
+            for (rel, entry), got in zip(run, answer):
+                if got is None:
+                    break
+                taken += 1
+                pump["files"] += 1
+                if isinstance(got, bytes):
+                    pump["batched_files"] += 1
+                # the sides _stream_file gives open_read's errors
+                side = "read" if isinstance(got, FirstReadError) else "open"
+                try:
+                    await self._before_handing_on()
+                except Exception as e:
+                    got, side = e, "read"
+                if isinstance(got, bytes):
+                    items.append(("file", entry,
+                                  _QueuePumpReader(None, first=got)))
+                    self.result.bytes_total += len(got)
+                    self.result.files += 1
+                    if self.resume is not None:
+                        self.resume.note_reread(len(got), files=1)
+                    continue
+                self.result.errors.append(f"{rel}: {side}: {got}")
+                if side == "read":
+                    items.append(("file", entry,
+                                  self._failed_reader(rel, got)))
+                    if isinstance(got, ConnectionError):
+                        dropped = got
+                        break
+            await self._put_many(items)
+            if dropped is not None:
+                raise dropped
+            run = run[taken:]
+        for rel, entry in run:
+            await self._stream_file(rel, entry)
+
+    def _failed_reader(self, rel: str, e: Exception) -> _QueuePumpReader:
+        """The writer gets a file whose first read failed all the same,
+        as one whose read raises."""
+        failed: queue.Queue = queue.Queue(maxsize=1)
+        failed.put_nowait(RuntimeError(f"read {rel}: {e}"))
+        return _QueuePumpReader(failed, self._abort)
+
+    @staticmethod
+    async def _before_handing_on() -> None:
+        """The one site of the file-stream failpoint: before each read
+        of ``_stream_file``, and once for each file of a ``read_many``
+        answer before it is handed to the writer."""
+        await failpoints.ahit("backup.file.stream")
 
     async def _stream_file(self, rel: str, entry: Entry) -> None:
         """Prefetch file blocks over aRPC into the writer queue.  The
@@ -403,7 +534,7 @@ class RemoteTreeBackup:
         handle, fq, reader, off = 0, None, None, 0
         try:
             while True:
-                await failpoints.ahit("backup.file.stream")
+                await self._before_handing_on()
                 pump["calls"] += 1
                 if reader is None:
                     try:
@@ -436,16 +567,12 @@ class RemoteTreeBackup:
             # the writer's file fails with the read; a dead transport
             # fails the job too (the job-level retry re-runs
             # incrementally — committed chunks are already in the store)
-            err = RuntimeError(f"read {rel}: {e}")
             if reader is None:
-                # the first read: the writer gets the file all the same,
-                # as one whose read raises
-                failed: queue.Queue = queue.Queue(maxsize=1)
-                failed.put_nowait(err)
                 await self._put(
-                    ("file", entry, _QueuePumpReader(failed, self._abort)))
+                    ("file", entry, self._failed_reader(rel, e)))
             elif fq is not None:
-                await loop.run_in_executor(None, fq.put, err)
+                await loop.run_in_executor(
+                    None, fq.put, RuntimeError(f"read {rel}: {e}"))
             self.result.errors.append(f"{rel}: read: {e}")
             if isinstance(e, ConnectionError):
                 raise

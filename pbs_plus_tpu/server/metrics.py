@@ -589,14 +589,21 @@ class MetricsRegistry:
         gauge("pbs_plus_pump_files_total",
               "Files the backup pumps began to stream from agents, by "
               "how they crossed the wire: in one call (the first read "
-              "came with the open and was the whole file) or in several",
+              "came with the open and was the whole file), batched (in "
+              "a read_many answer beside their neighbours) or in several",
               [({"calls": "one"}, float(pt["one_call_files"])),
+               ({"calls": "batched"}, float(pt["batched_files"])),
                ({"calls": "several"},
-                float(pt["files"] - pt["one_call_files"]))])
+                float(pt["files"] - pt["one_call_files"]
+                      - pt["batched_files"]))])
         gauge("pbs_plus_pump_calls_total",
               "agentfs calls the backup pumps made for file content "
-              "(open with its first read, read_at, close)",
+              "(open with its first read, read_at, close, read_many)",
               [({}, float(pt["calls"]))])
+        gauge("pbs_plus_pump_batch_calls_total",
+              "read_many calls among them: each asks for a run of one "
+              "listing's consecutive small files",
+              [({}, float(pt["batch_calls"]))])
         gauge("pbs_plus_device_compilations_total",
               "Programs jax built or loaded from its cache since the "
               "device ops were loaded; one that moves while backups run "

@@ -428,6 +428,245 @@ def test_open_read_ten_thousand_small_files_never_429(pki, tmp_path):
     asyncio.run(main())
 
 
+# ------------------------------------------ a run of small files, one call
+
+async def _read_many_raw(c, paths, budget):
+    """The call as the wire has it: (status, envelope data, bytes)."""
+    buf = bytearray()
+    resp, n = await asyncio.wait_for(c.s.call_binary_into(
+        "agentfs.read_many", {"paths": paths, "budget": budget}, buf), 10)
+    assert n == len(buf)
+    return resp.status, resp.data, bytes(buf)
+
+
+def _bodies(root, sizes: dict) -> dict:
+    out = {}
+    for name, size in sizes.items():
+        out[name] = bytes((i + len(name)) % 251 for i in range(size))
+        (root / name).write_bytes(out[name])
+    return out
+
+
+@pytest.mark.parametrize("size", [0, 1, BLOCK - 1, BLOCK])
+def test_read_many_serves_whole_files_up_to_the_budget(pki, tmp_path, size):
+    """Sizes 0, 1, budget-1 and the budget exactly, each between two
+    neighbours of 0 bytes: all served, back to back, in order."""
+    want = _bodies(tmp_path, {"a": 0, "f": size, "z": 0})
+
+    async def main():
+        h = Harness(pki, tmp_path)
+        async with h as c:
+            status, data, raw = await _read_many_raw(
+                c, ["a", "f", "z"], BLOCK)
+            assert status == 213
+            assert data == {"files": [{"n": 0}, {"n": size}, {"n": 0}]}
+            assert raw == want["f"]
+            assert await c.read_many(["a", "f", "z"], BLOCK) == \
+                [b"", want["f"], b""]
+            assert len(h.fs._handles) == 0
+            assert h.fs.stats["read_many"] == 2
+            assert h.fs.stats["read_many_files"] == 6
+            assert h.fs.stats["opens"] == 6
+            assert h.fs.stats["bytes"] == 2 * size
+            assert h.fs.stats["open_reads"] == h.fs.stats["reads"] == 0
+    asyncio.run(main())
+
+
+def test_read_many_answers_a_prefix_when_the_budget_is_passed(pki,
+                                                              tmp_path):
+    """The file that would pass the budget ends the prefix: nothing of
+    it is sent, nor of a later one that would have fit."""
+    want = _bodies(tmp_path, {"a": 1000, "b": 2000, "c": 1500, "d": 10})
+
+    async def main():
+        h = Harness(pki, tmp_path)
+        async with h as c:
+            status, data, raw = await _read_many_raw(
+                c, ["a", "b", "c", "d"], BLOCK)
+            assert data == {"files": [{"n": 1000}, {"n": 2000}]}
+            assert raw == want["a"] + want["b"]
+            assert await c.read_many(["a", "b", "c", "d"], BLOCK) == \
+                [want["a"], want["b"], None, None]
+            # asked again from the unserved file on, it is served
+            assert await c.read_many(["c", "d"], BLOCK) == \
+                [want["c"], want["d"]]
+            assert h.fs.stats["read_many_files"] == 2 + 2 + 2
+            assert h.fs.stats["bytes"] == 2 * 3000 + 1510
+            assert len(h.fs._handles) == 0
+    asyncio.run(main())
+
+
+def test_read_many_leaves_a_grown_first_file_unserved(pki, tmp_path):
+    """The first path is always tried, whatever the budget; alone past
+    the budget it is not served, and neither is anything after it."""
+    _bodies(tmp_path, {"grown": BLOCK + 1, "b": 10})
+
+    async def main():
+        h = Harness(pki, tmp_path)
+        async with h as c:
+            status, data, raw = await _read_many_raw(c, ["grown", "b"],
+                                                     BLOCK)
+            assert (status, data, raw) == (213, {"files": []}, b"")
+            assert await c.read_many(["grown", "b"], BLOCK) == [None, None]
+            assert h.fs.stats["read_many_files"] == 0
+            assert h.fs.stats["bytes"] == 0
+            assert len(h.fs._handles) == 0
+    asyncio.run(main())
+
+
+@pytest.mark.parametrize("bad,status,words", [
+    ("evil", 400, "symlink escapes root"),      # symlink out of the root
+    ("pipe", 400, "not a regular file"),        # fifo: no hang
+    ("missing", 404, "open: "),
+    ("dir", 400, "not a regular file"),
+    ("../secret", 400, "path escapes root"),
+])
+def test_read_many_refuses_a_file_and_serves_its_neighbours(
+        pki, tmp_path, bad, status, words):
+    """Every gate of `_open` holds per file: the refused file answers
+    with the status and message `agentfs.open` gives it, no byte of it
+    is read, and the call goes on."""
+    snap = tmp_path / "snap"
+    snap.mkdir()
+    want = _bodies(snap, {"a": 100, "z": 200})
+    (tmp_path / "secret").write_bytes(b"outside")
+    os.symlink(str(tmp_path / "secret"), snap / "evil")
+    os.mkfifo(snap / "pipe")
+    (snap / "dir").mkdir()
+
+    async def main():
+        h = Harness(pki, snap)
+        async with h as c:
+            with pytest.raises(CallError) as ei:
+                await asyncio.wait_for(c.open_read(bad, BLOCK), 10)
+            alone = ei.value.response
+            assert alone.status == status and words in alone.message
+            status_, data, raw = await _read_many_raw(c, ["a", bad, "z"],
+                                                      BLOCK)
+            assert status_ == 213
+            assert data["files"] == [
+                {"n": 100},
+                {"status": alone.status, "message": alone.message},
+                {"n": 200}]
+            assert raw == want["a"] + want["z"] and b"outside" not in raw
+            got = await c.read_many(["a", bad, "z"], BLOCK)
+            assert got[0] == want["a"] and got[2] == want["z"]
+            # what open_read would have raised, word for word
+            assert isinstance(got[1], CallError)
+            assert str(got[1]) == str(ei.value)
+            assert len(h.fs._handles) == 0
+            assert h.fs.stats["read_many_files"] == 4
+    asyncio.run(main())
+
+
+def test_read_many_answers_a_failed_pread_as_a_first_read_error(
+        pki, tmp_path, monkeypatch):
+    from pbs_plus_tpu.agent import agentfs
+    want = _bodies(tmp_path, {"a": 10, "b": 10, "c": 10})
+    real = os.pread
+    bad_ino = os.stat(tmp_path / "b").st_ino
+
+    def pread(fd, n, off):
+        if os.fstat(fd).st_ino == bad_ino:
+            raise OSError(5, "Input/output error")
+        return real(fd, n, off)
+    monkeypatch.setattr(agentfs.os, "pread", pread)
+
+    async def main():
+        h = Harness(pki, tmp_path)
+        async with h as c:
+            got = await c.read_many(["a", "b", "c"], BLOCK)
+            assert got[0] == want["a"] and got[2] == want["c"]
+            assert isinstance(got[1], agentfs.FirstReadError)
+            assert str(got[1]) == "pread: [Errno 5] Input/output error"
+            assert len(h.fs._handles) == 0
+    asyncio.run(main())
+
+
+@pytest.mark.parametrize("payload", [
+    {"paths": ["f"], "budget": -1},
+    {"paths": ["f"], "budget": 0},
+    {"paths": ["f"], "budget": (32 << 20) + 1},     # beyond MAX_READ
+    {"paths": ["f"], "budget": "many"},
+    {"paths": ["f"]},
+    {"paths": "f", "budget": BLOCK},
+    {"paths": ["f", 7], "budget": BLOCK},
+    {"budget": BLOCK},
+], ids=["minus_one", "zero", "over_max_read", "not_an_int", "no_budget",
+        "paths_a_string", "a_path_not_a_string", "no_paths"])
+def test_read_many_refused_before_a_byte_is_read(pki, tmp_path, payload):
+    (tmp_path / "f").write_bytes(b"inside")
+
+    async def main():
+        h = Harness(pki, tmp_path)
+        async with h as c:
+            buf = bytearray()
+            with pytest.raises(CallError) as ei:
+                await asyncio.wait_for(c.s.call_binary_into(
+                    "agentfs.read_many", payload, buf), 10)
+            assert ei.value.response.status == 400
+            assert not buf
+            assert h.fs.stats["bytes"] == h.fs.stats["opens"] == 0
+            assert h.fs.stats["read_many"] == 0
+            assert len(h.fs._handles) == 0
+    asyncio.run(main())
+
+
+def test_read_many_not_supported_is_not_a_files_404(pki, tmp_path):
+    """An agent without the method answers 404 for the call: the client
+    reports "not supported" (None).  A missing file's 404 is that file's
+    item and the call itself succeeds."""
+    from tools.pump_cost import NoReadMany
+    (tmp_path / "f").write_bytes(b"inside")
+
+    async def main():
+        h = Harness(pki, tmp_path)
+        h.fs = NoReadMany(str(tmp_path))
+        async with h as c:
+            assert await c.read_many(["f", "missing"], BLOCK) is None
+            # exactly what the router says of a method nobody registered
+            with pytest.raises(CallError) as e1:
+                await c.s.call("agentfs.read_many", {"paths": ["f"]})
+            with pytest.raises(CallError) as e2:
+                await c.s.call("agentfs.no_such", {"paths": ["f"]})
+            assert e1.value.response.status == e2.value.response.status == 404
+            assert e1.value.response.message == \
+                e2.value.response.message.replace("no_such", "read_many")
+        async with Harness(pki, tmp_path) as c:
+            got = await c.read_many(["f", "missing"], BLOCK)
+            assert got[0] == b"inside"
+            assert isinstance(got[1], CallError)
+            assert got[1].response.status == 404
+    asyncio.run(main())
+
+
+def test_read_many_ten_thousand_small_files_never_429(pki, tmp_path):
+    """No handle outlives a call, so runs over a tree of any length
+    never meet MAX_HANDLES — also with every slot of the table taken."""
+    d = tmp_path / "d"
+    d.mkdir()
+    n_files, run = 10_000, 250
+    assert n_files > MAX_HANDLES
+    for i in range(n_files):
+        (d / f"f{i:05d}").write_bytes(b"%05d" % i)
+
+    async def main():
+        h = Harness(pki, tmp_path)
+        async with h as c:
+            held = [await c.open("d/f00000") for _ in range(MAX_HANDLES)]
+            for lo in range(0, n_files, run):
+                got = await c.read_many(
+                    [f"d/f{i:05d}" for i in range(lo, lo + run)], BLOCK)
+                assert got == [b"%05d" % i for i in range(lo, lo + run)]
+                assert len(h.fs._handles) == MAX_HANDLES
+            for handle in held:
+                await c.close(handle)
+            assert len(h.fs._handles) == 0
+            assert h.fs.stats["read_many"] == n_files // run
+            assert h.fs.stats["read_many_files"] == n_files
+    asyncio.run(main())
+
+
 def _skew_tree(root) -> None:
     import numpy as np
     rng = np.random.default_rng(29)
@@ -438,15 +677,18 @@ def _skew_tree(root) -> None:
             rng.integers(0, 256, size, dtype=np.uint8).tobytes())
 
 
-def test_agent_that_ignores_read_publishes_identically(pki, tmp_path,
-                                                       monkeypatch):
+@pytest.mark.parametrize("older", ["no_read_many", "ignores_read"])
+def test_an_older_agent_publishes_identically(pki, tmp_path, monkeypatch,
+                                              older):
     """Version skew needs no switch: the pump reads what the peer
-    answered.  Both agents' snapshots have identical index records and
-    file digests; only the calls differ."""
+    answered.  The real agent's snapshot and that of an agent without
+    `read_many` — or of one that ignores `read` on the open as well —
+    have identical index records and file digests; only the calls
+    differ."""
     from pbs_plus_tpu.chunker import ChunkerParams
     from pbs_plus_tpu.pxar.backupproxy import LocalStore
     from pbs_plus_tpu.server import backup_job as bj
-    from tools.pump_cost import IgnoresRead
+    from tools.pump_cost import IgnoresRead, NoReadMany
     monkeypatch.setattr(bj, "READ_BLOCK", BLOCK)
     src = tmp_path / "src"
     _skew_tree(src)
@@ -478,17 +720,30 @@ def test_agent_that_ignores_read_publishes_identically(pki, tmp_path,
 
     async def main():
         return (await backup(AgentFSServer, "new"),
-                await backup(IgnoresRead, "old"))
+                await backup({"no_read_many": NoReadMany,
+                              "ignores_read": IgnoresRead}[older], "old"))
 
     (idx_new, dig_new, pump_new, st_new), \
         (idx_old, dig_old, pump_old, st_old) = asyncio.run(main())
     assert idx_new == idx_old and all(idx_new)
     assert dig_new == dig_old and all(dig_new.values())
-    # four files of one block: one call each, closed by the agent
-    assert pump_new == {"files": 8, "one_call_files": 4,
-                        "calls": 4 + 3 + 3 + 5 + 7}
-    assert st_new["open_reads"] == 8 and st_new["closed_at_eof"] == 4
-    # the old agent: open, read_at until a short block, close
-    assert pump_old["one_call_files"] == 0
-    assert pump_old["calls"] == 4 * 3 + 4 + 4 + 6 + 8
-    assert st_old["open_reads"] == 0 and st_old["opens"] == 8
+    # sizes 0, 1, 700 and BLOCK-1 make two runs (the fourth would pass
+    # the budget, and is a run of one); the four larger go block by block
+    big = 3 + 3 + 5 + 7
+    assert pump_new == {"files": 8, "one_call_files": 1, "calls": 1 + 1 + big,
+                        "batched_files": 3, "batch_calls": 1}
+    assert st_new["read_many"] == 1 and st_new["read_many_files"] == 3
+    assert st_new["open_reads"] == 5 and st_new["closed_at_eof"] == 1
+    assert st_new["opens"] == 8
+    assert pump_old["batched_files"] == 0 and pump_old["batch_calls"] == 1
+    assert st_old["read_many"] == st_old["read_many_files"] == 0
+    if older == "no_read_many":
+        # the parent's calls a file, and the one refused read_many
+        assert pump_old["one_call_files"] == 4
+        assert pump_old["calls"] == 1 + 4 + big
+        assert st_old["open_reads"] == 8 and st_old["closed_at_eof"] == 4
+    else:
+        # open, read_at until a short block, close
+        assert pump_old["one_call_files"] == 0
+        assert pump_old["calls"] == 1 + 4 * 3 + 4 + 4 + 6 + 8
+        assert st_old["open_reads"] == 0 and st_old["opens"] == 8

@@ -1,9 +1,10 @@
 """The backup pump's calls per file (server/backup_job.py
-``RemoteTreeBackup._stream_file``): a file's first read rides on its
-open, a short block is its end, and a file of one block is one call and
-one item of the writer's queue.  Driven against in-memory file systems
-that count every call; the real agent's side is in
-tests/test_agentfs_battery.py."""
+``RemoteTreeBackup``).  ``_stream_file``: a file's first read rides on
+its open, a short block is its end, and a file of one block is one call
+and one item of the writer's queue.  ``_stream_run``: a listing's
+consecutive small files are one ``read_many`` and reach the writer's
+queue in one step.  Driven against in-memory file systems that count
+every call; the real agent's side is in tests/test_agentfs_battery.py."""
 
 import asyncio
 import threading
@@ -11,7 +12,7 @@ import threading
 import pytest
 
 from agentfs_fakes import OpenReadViaCalls
-from pbs_plus_tpu.pxar.format import KIND_DIR, KIND_FILE
+from pbs_plus_tpu.pxar.format import KIND_DIR, KIND_FILE, KIND_SYMLINK
 from pbs_plus_tpu.server import backup_job as bj
 from pbs_plus_tpu.server.backup_job import RemoteTreeBackup
 from pbs_plus_tpu.utils import failpoints, trace
@@ -31,15 +32,27 @@ def _body(name: str, size: int) -> bytes:
     return bytes((i + len(name)) % 251 for i in range(size))
 
 
+def _counts(files=0, one_call_files=0, calls=0, batched_files=0,
+            batch_calls=0) -> dict:
+    return {"files": files, "one_call_files": one_call_files,
+            "calls": calls, "batched_files": batched_files,
+            "batch_calls": batch_calls}
+
+
 class CountingFS(OpenReadViaCalls):
     """One flat directory of in-memory files; counts the calls the pump
-    pays for (``open_read`` counts as the one call it is on the wire,
-    the ``open``/``read_at``/``close`` it is made of here do not)."""
+    pays for (``open_read`` and ``read_many`` count as the one call each
+    is on the wire, the ``open``/``read_at``/``close`` they are made of
+    here do not).  ``knows_read_many=False``: the agent of the one-file
+    path, which answers "not supported" once."""
 
-    def __init__(self, sizes: dict, *, honours_read: bool = True):
+    def __init__(self, sizes: dict, *, honours_read: bool = True,
+                 knows_read_many: bool = True):
         self.files = {name: _body(name, size)
                       for name, size in sizes.items()}
         self.honours_read = honours_read
+        self.knows_read_many = knows_read_many
+        self.listed: dict = {}          # name -> the size the listing gives
         self.calls: list = []           # (method, name) as the wire sees it
         self.open_handles: dict = {}
         self._next = 1
@@ -56,20 +69,29 @@ class CountingFS(OpenReadViaCalls):
         if rel:
             return []
         return [{"name": name, "kind": KIND_FILE, "mode": 0o644, "uid": 0,
-                 "gid": 0, "mtime_ns": 0, "size": len(body)}
+                 "gid": 0, "mtime_ns": 0,
+                 "size": self.listed.get(name, len(body))}
                 for name, body in sorted(self.files.items())]
 
     def _note(self, method, name):
         if not self._inside_open_read:
             self.calls.append((method, name))
 
-    async def open_read(self, rel, n):
-        self.calls.append(("open_read", rel))
-        self._inside_open_read = True
+    async def _as_one_call(self, method, arg, inner):
+        self._note(method, arg)
+        was, self._inside_open_read = self._inside_open_read, True
         try:
-            return await super().open_read(rel, n)
+            return await inner
         finally:
-            self._inside_open_read = False
+            self._inside_open_read = was
+
+    async def open_read(self, rel, n):
+        return await self._as_one_call(
+            "open_read", rel, super().open_read(rel, n))
+
+    async def read_many(self, paths, budget):
+        return await self._as_one_call(
+            "read_many", tuple(paths), super().read_many(paths, budget))
 
     async def open(self, rel):
         self._note("open", rel)
@@ -142,8 +164,8 @@ def test_calls_per_file(size):
     assert w.got == {"f.bin": _body("f.bin", size)}
     assert len(fs.calls) == CALLS[size], fs.calls
     one_call = size < BLOCK
-    assert pump.pump == {"files": 1, "one_call_files": int(one_call),
-                         "calls": CALLS[size]}
+    assert pump.pump == _counts(files=1, one_call_files=int(one_call),
+                                calls=CALLS[size])
     assert fs.calls[0] == ("open_read", "f.bin")
     if not one_call:
         assert fs.calls[-1] == ("close", "f.bin")
@@ -161,37 +183,50 @@ def test_calls_per_file_against_an_agent_that_ignores_read(size):
     fs = CountingFS({"f.bin": size}, honours_read=False)
     pump, res, w = _run(fs)
     assert w.got == {"f.bin": _body("f.bin", size)}
-    assert pump.pump == {"files": 1, "one_call_files": 0,
-                         "calls": 2 + size // BLOCK + 1}
+    assert pump.pump == _counts(files=1, calls=2 + size // BLOCK + 1)
     assert len(fs.calls) == pump.pump["calls"]
     assert not fs.open_handles
     assert (res.files, res.bytes_total) == (1, size)
 
 
 def test_a_tree_of_small_files_is_one_call_a_file():
+    """Against an agent without read_many: it is asked once, answers
+    "not supported", and every file goes the one-file way from there."""
     sizes = {f"f{i:03d}": (i * 37) % BLOCK for i in range(200)}
     sizes["big"] = 4 * BLOCK + 5
-    fs = CountingFS(sizes)
+    fs = CountingFS(sizes, knows_read_many=False)
     pump, res, w = _run(fs)
     assert w.got == fs.files
-    assert pump.pump == {"files": 201, "one_call_files": 200,
-                         "calls": 200 + 6}
+    assert pump.pump == _counts(files=201, one_call_files=200,
+                                calls=1 + 200 + 6, batch_calls=1)
+    assert [c[0] for c in fs.calls].count("read_many") == 1
+    assert fs.calls[6][0] == "read_many"       # after big's six
     assert res.files == 201 and res.bytes_total == sum(sizes.values())
 
 
-def test_open_failure_skips_the_file():
-    fs = CountingFS({"a": 10, "b": 10, "c": 2 * BLOCK})
+BOTH_WAYS = pytest.mark.parametrize(
+    "batched", [False, True], ids=["one_file", "read_many"])
+
+
+@BOTH_WAYS
+def test_open_failure_skips_the_file(batched):
+    """A file that vanished between the listing and its read."""
+    fs = CountingFS({"a": 10, "b": 10, "c": 2 * BLOCK},
+                    knows_read_many=batched)
     fs.fail_open = {"b"}
     pump, res, w = _run(fs)
     assert sorted(w.got) == ["a", "c"]          # the writer never saw b
     assert len(res.errors) == 1 and res.errors[0].startswith("b: open: ")
     assert res.files == 2
+    assert pump.pump["batched_files"] == (1 if batched else 0)
+    assert pump.pump["files"] == 3
 
 
-def test_first_read_failure_fails_as_a_read_does():
+@BOTH_WAYS
+def test_first_read_failure_fails_as_a_read_does(batched):
     """The writer gets the file and its read raises: the error is the
     file's `read:` error and the job fails with the writer's."""
-    fs = CountingFS({"a": 10, "b": 10, "c": 10})
+    fs = CountingFS({"a": 10, "b": 10, "c": 10}, knows_read_many=batched)
     fs.fail_read = {"b"}
     pump, exc, w = _run(fs)
     assert isinstance(exc, RuntimeError) and "read b:" in str(exc)
@@ -221,7 +256,7 @@ def test_failpoint_fires_before_the_first_read():
     assert fs.calls == []                       # no byte was asked for
     assert isinstance(exc, RuntimeError) and "read a:" in str(exc)
     assert pump.result.errors[0].startswith("a: read: ")
-    assert pump.pump == {"files": 1, "one_call_files": 0, "calls": 0}
+    assert pump.pump == _counts(files=1)
 
 
 def test_failpoint_is_hit_once_per_read():
@@ -232,13 +267,15 @@ def test_failpoint_is_hit_once_per_read():
     assert fp.hits == 3 and fp.fires == 0
 
 
-def test_dropped_transport_at_the_first_read_fails_the_job():
-    fs = CountingFS({"a": 10, "b": 10})
+@BOTH_WAYS
+def test_dropped_transport_at_the_first_read_fails_the_job(batched):
+    fs = CountingFS({"a": 10, "b": 10, "c": 10}, knows_read_many=batched)
     with failpoints.armed("backup.file.stream", "drop", nth=2):
         pump, exc, w = _run(fs)
     assert isinstance(exc, ConnectionError)
-    assert "a" in w.got and "b" not in w.got
+    assert "a" in w.got and "b" not in w.got and "c" not in w.got
     assert pump.result.errors[0].startswith("b: read: ")
+    assert len(pump.result.errors) == 1
 
 
 def test_abort_mid_file_does_not_hang():
@@ -274,10 +311,14 @@ def test_abort_mid_file_does_not_hang():
     assert not left
 
 
-def test_writer_death_with_one_item_files_queued_does_not_wedge():
+@BOTH_WAYS
+def test_writer_death_with_one_item_files_queued_does_not_wedge(batched):
     """Files of one queue item wait behind the file the writer dies on:
-    its drain must pass them (they have no block queue to empty)."""
-    fs = CountingFS({f"f{i:02d}": 10 for i in range(40)})
+    its drain must pass them (they have no block queue to empty) — also
+    when a whole read_many answer, more than the queue holds, is on its
+    way in from the pool's thread."""
+    fs = CountingFS({f"f{i:02d}": 10 for i in range(40)},
+                    knows_read_many=batched)
 
     class Exploding(RecordingWriter):
         def write_entry_reader(self, entry, reader):
@@ -293,7 +334,7 @@ def test_pump_counters_on_the_span_and_in_the_totals():
     before = dict(bj.PUMP_TOTALS)
     trace.clear()
     pump, res, w = _run(fs)
-    want = {"files": 3, "one_call_files": 2, "calls": 1 + 3 + 1}
+    want = _counts(files=3, one_call_files=2, calls=1 + 3 + 1)
     assert pump.pump == want
     assert {k: bj.PUMP_TOTALS[k] - before[k] for k in want} == want
     spans = [r for r in trace.recent() if r["name"] == "backup.pump"]
@@ -312,6 +353,313 @@ def test_pump_totals_on_metrics(tmp_path):
     assert (f'pbs_plus_pump_files_total{{calls="one"}} '
             f'{float(t["one_call_files"])}') in expo
     assert (f'pbs_plus_pump_files_total{{calls="several"}} '
-            f'{float(t["files"] - t["one_call_files"])}') in expo
+            f'{float(t["files"] - t["one_call_files"] - t["batched_files"])}'
+            ) in expo
     assert f'pbs_plus_pump_calls_total {float(t["calls"])}' in expo
     assert t["files"] >= 2 and t["calls"] >= 4
+
+
+# ------------------------------------------------ a run of small files
+
+class TreeFS(CountingFS):
+    """CountingFS with listings given as they are: {dir: [entry maps]}.
+    A file's body is made from its path and the size ``bodies`` gives
+    it, else the size its listing gives."""
+
+    def __init__(self, dirs: dict, *, bodies: dict | None = None, **kw):
+        sizes = {}
+        for rel, entries in dirs.items():
+            for m in entries:
+                if m["kind"] == KIND_FILE:
+                    path = f"{rel}/{m['name']}" if rel else m["name"]
+                    sizes[path] = m["size"]
+        sizes.update(bodies or {})
+        super().__init__(sizes, **kw)
+        self.dirs = dirs
+
+    async def read_dir(self, rel):
+        return self.dirs.get(rel, [])
+
+
+def _ent(name, kind=KIND_FILE, size=0, **kw):
+    return {"name": name, "kind": kind, "mode": 0o644, "uid": 0, "gid": 0,
+            "mtime_ns": 7, "size": size, **kw}
+
+
+class SequenceWriter(RecordingWriter):
+    """Keeps the archive's order: what the writer was handed, in turn."""
+
+    def __init__(self):
+        super().__init__()
+        self.seq: list = []
+
+    def write_entry(self, entry):
+        self.seq.append(("entry", entry.path, entry.kind, entry.link_target))
+
+    def write_entry_ref(self, entry, off, size):
+        self.seq.append(("ref", entry.path, off, size, entry.digest))
+
+    def write_entry_reader(self, entry, reader):
+        super().write_entry_reader(entry, reader)
+        self.seq.append(("file", entry.path, self.got[entry.path]))
+
+
+class _Plan:
+    """A resume plan that splices one path."""
+
+    class _Src:
+        digest, payload_offset, size = b"\x11" * 32, 4096, 33
+
+    def __init__(self, spliced):
+        self.spliced = spliced
+        self.reread = [0, 0]
+
+    def skip_ref(self, path, size, mtime_ns):
+        return self._Src if path == self.spliced else None
+
+    def note_reread(self, nbytes, *, files=0):
+        self.reread[0] += nbytes
+        self.reread[1] += files
+
+
+def _mixed_tree():
+    return {
+        "": [_ent("a", size=10), _ent("b", size=0), _ent("c", size=BLOCK - 20),
+             _ent("cache.tmp", size=5),             # excluded
+             _ent("d", size=33),                    # spliced by the plan
+             _ent("e", size=20),
+             _ent("f", size=BLOCK),                 # a file of one block
+             _ent("g", size=30, nlink=2, dev=1, ino=77),
+             _ent("h", size=30, nlink=2, dev=1, ino=77),    # second link
+             _ent("i", size=40),
+             _ent("lnk", KIND_SYMLINK, target="a"),
+             _ent("m", size=50), _ent("n", size=60),
+             _ent("sub", KIND_DIR),
+             _ent("y", size=70), _ent("z", size=80)],
+        "sub": [_ent("p", size=5), _ent("q", size=6), _ent("r", size=7)],
+    }
+
+
+def _run_mixed(batched):
+    fs = TreeFS(_mixed_tree(), knows_read_many=batched)
+    sess = Sess(SequenceWriter())
+    sess.resume_plan = _Plan("d")
+
+    async def main():
+        pump = RemoteTreeBackup(fs, sess, exclusions=["*.tmp"])
+        return pump, await asyncio.wait_for(pump.run(), 20)
+    pump, res = asyncio.run(main())
+    return fs, pump, res, sess
+
+
+def test_a_mixed_listing_archives_as_the_one_file_path_does():
+    """Small files, a file of one block, a subdirectory, a symlink, a
+    second hard link, an excluded name and a spliced file inside the
+    runs: the same items in the same order with the same bytes."""
+    fs1, pump1, res1, sess1 = _run_mixed(False)
+    fs2, pump2, res2, sess2 = _run_mixed(True)
+    assert sess2.writer.seq == sess1.writer.seq
+    paths = [s[1] for s in sess2.writer.seq]
+    assert paths == ["", "a", "b", "c", "d", "e", "f", "g", "h", "i", "lnk",
+                     "m", "n", "sub", "sub/p", "sub/q", "sub/r", "y", "z"]
+    assert sess2.writer.seq[4][0] == "ref"
+    assert sess2.writer.seq[8][2:] == ("h", "g")        # hard link to g
+    assert (res2.files, res2.bytes_total, res2.entries, res2.errors) == \
+        (res1.files, res1.bytes_total, res1.entries, res1.errors)
+    assert sess2.resume_plan.reread == sess1.resume_plan.reread
+    # the runs: a b c | e | g | i | m n | sub: p q r | y z
+    runs = [c[1] for c in fs2.calls if c[0] == "read_many"]
+    assert runs == [("a", "b", "c"), ("m", "n"),
+                    ("sub/p", "sub/q", "sub/r"), ("y", "z")]
+    assert pump2.pump == _counts(files=14, one_call_files=3, calls=4 + 3 + 3,
+                                 batched_files=10, batch_calls=4)
+    assert pump1.pump == _counts(files=14, one_call_files=13,
+                                 calls=1 + 13 + 3, batch_calls=1)
+
+
+def test_sixty_four_small_files_in_one_directory_are_one_call():
+    fs = CountingFS({f"f{i:02d}": 1 + i % 9 for i in range(64)})
+    pump, res, w = _run(fs)
+    assert w.got == fs.files
+    assert len(fs.calls) == 1 and fs.calls[0][0] == "read_many"
+    assert pump.pump == _counts(files=64, calls=1, batched_files=64,
+                                batch_calls=1)
+    assert pump.pump["calls"] / pump.pump["files"] < 0.02
+    assert (res.files, res.bytes_total) == (64, sum(map(len,
+                                                        fs.files.values())))
+
+
+def test_the_budget_splits_a_run():
+    """Listed sizes add up to one block a run at most."""
+    third = BLOCK // 3
+    fs = CountingFS({f"f{i}": third for i in range(7)})
+    pump, res, w = _run(fs)
+    assert w.got == fs.files
+    runs = [c[1] for c in fs.calls if c[0] == "read_many"]
+    assert runs == [("f0", "f1", "f2"), ("f3", "f4", "f5")]
+    # the seventh is a run of one: the one-file way
+    assert fs.calls[-1] == ("open_read", "f6")
+    assert pump.pump == _counts(files=7, one_call_files=1, calls=3,
+                                batched_files=6, batch_calls=2)
+
+
+def test_the_file_count_splits_a_run(monkeypatch):
+    """A directory of empty files is a call per READ_MANY_FILES, not one
+    of any length."""
+    monkeypatch.setattr(bj, "READ_MANY_FILES", 16)
+    fs = CountingFS({f"f{i:03d}": 0 for i in range(100)})
+    pump, res, w = _run(fs)
+    assert w.got == fs.files
+    runs = [c[1] for c in fs.calls if c[0] == "read_many"]
+    assert [len(r) for r in runs] == [16] * 6 + [4]
+    assert pump.pump == _counts(files=100, calls=7, batched_files=100,
+                                batch_calls=7)
+
+
+@pytest.mark.parametrize("grown", ["f0", "f2", "f3"])
+def test_a_file_that_grew_since_the_listing(grown):
+    """The agent serves what fits the budget and nothing of the file
+    that passes it: that file starts the next run, and a first file left
+    unserved goes the one-file way — whole, at its new length."""
+    fs = CountingFS({f"f{i}": 100 for i in range(4)})
+    fs.listed = {grown: 100}
+    fs.files[grown] = _body(grown, BLOCK + 50)
+    pump, res, w = _run(fs)
+    assert w.got == fs.files
+    assert list(w.got) == ["f0", "f1", "f2", "f3"]
+    assert res.errors == [] and res.files == 4
+    assert res.bytes_total == 300 + BLOCK + 50
+    methods = [(c[0], c[1]) for c in fs.calls]
+    if grown == "f0":
+        # unserved first: its own three calls, then the rest as a run
+        assert methods[0] == ("read_many", ("f0", "f1", "f2", "f3"))
+        assert methods[1] == ("open_read", "f0")
+        assert methods[-1] == ("read_many", ("f1", "f2", "f3"))
+        assert pump.pump["batched_files"] == 3
+    elif grown == "f2":
+        assert methods[0] == ("read_many", ("f0", "f1", "f2", "f3"))
+        assert methods[1] == ("read_many", ("f2", "f3"))
+        assert methods[2] == ("open_read", "f2")
+        assert methods[-1] == ("open_read", "f3")   # a run of one
+        assert pump.pump["batched_files"] == 2
+    else:
+        assert methods[0] == ("read_many", ("f0", "f1", "f2", "f3"))
+        assert methods[1] == ("open_read", "f3")    # a run of one
+        assert pump.pump["batched_files"] == 3
+    assert pump.pump["files"] == 4
+
+
+def test_per_file_errors_keep_their_sides_inside_a_run():
+    """An open error is recorded and skipped, a read error hands the
+    writer a reader that raises; the neighbours of both are served."""
+    fs = CountingFS({"a": 10, "b": 10, "c": 10, "d": 10, "e": 10})
+    fs.fail_open = {"b"}
+    fs.fail_read = {"d"}
+    pump, exc, w = _run(fs)
+    assert isinstance(exc, RuntimeError) and "read d:" in str(exc)
+    assert pump.result.errors[0].startswith("b: open: ")
+    assert pump.result.errors[1] == "d: read: [Errno 5] Input/output error"
+    assert list(w.got) == ["a", "c"]        # the writer died on d
+    assert len(fs.calls) == 1
+    assert pump.pump == _counts(files=5, calls=1, batched_files=3,
+                                batch_calls=1)
+    assert not fs.open_handles
+
+
+def test_failpoint_is_hit_once_per_file_of_a_run_and_in_order():
+    fs = CountingFS({f"f{i}": 10 for i in range(6)})
+    with failpoints.armed("backup.file.stream", "raise", nth=4) as fp:
+        pump, exc, w = _run(fs)
+    assert fp.hits == 6 and fp.fires == 1
+    # the fourth file's read fails with it, and only that one's
+    assert isinstance(exc, RuntimeError) and "read f3:" in str(exc)
+    assert len(pump.result.errors) == 1
+    assert pump.result.errors[0].startswith("f3: read: ")
+    assert list(w.got) == ["f0", "f1", "f2"]
+
+
+def test_not_supported_falls_back_once_and_stays_there():
+    dirs = {"": [_ent("a", size=1), _ent("b", size=2), _ent("sub", KIND_DIR),
+                 _ent("y", size=3), _ent("z", size=4)],
+            "sub": [_ent("p", size=5), _ent("q", size=6)]}
+    fs = TreeFS(dirs, knows_read_many=False)
+    pump, res, w = _run(fs)
+    assert w.got == fs.files
+    assert [c[0] for c in fs.calls] == ["read_many"] + ["open_read"] * 6
+    assert pump.pump == _counts(files=6, one_call_files=6, calls=7,
+                                batch_calls=1)
+
+
+def test_dropped_transport_inside_read_many_fails_the_job():
+    fs = CountingFS({"a": 10, "b": 10})
+
+    async def read_many(paths, budget):
+        raise ConnectionResetError("session lost")
+    fs.read_many = read_many
+    pump, exc, w = _run(fs)
+    assert isinstance(exc, ConnectionResetError)
+    assert w.got == {}
+
+
+def test_abort_mid_run_does_not_hang():
+    """The job is cancelled while a read_many answer of more items than
+    the queue holds is on its way to a slow writer: run() ends, the
+    writer's thread ends, and no pool thread is left putting."""
+    fs = CountingFS({f"f{i:03d}": 10 for i in range(120)})
+    started = threading.Event()
+
+    class SlowWriter(RecordingWriter):
+        def write_entry_reader(self, entry, reader):
+            started.set()
+            while reader.read(100):
+                pass
+            threading.Event().wait(0.05)
+
+    async def main():
+        pump = RemoteTreeBackup(fs, Sess(SlowWriter()))
+        task = asyncio.ensure_future(pump.run())
+        loop = asyncio.get_running_loop()
+        await asyncio.wait_for(loop.run_in_executor(None, started.wait), 20)
+        task.cancel()
+        with pytest.raises(asyncio.CancelledError):
+            await asyncio.wait_for(task, 20)
+        return pump
+    before = {t.ident for t in threading.enumerate()}
+    pump = asyncio.run(main())
+    left = [t for t in threading.enumerate()
+            if t.name == "backup-writer" and t.ident not in before]
+    assert not left
+    # the pool's thread gave up: a put on the full queue would block
+    assert pump._abort.is_set()
+    pump._put_rest([object()] * 50)             # returns at once
+
+
+def test_batch_counters_on_the_span_and_in_the_totals():
+    fs = CountingFS({"a": 10, "b": 20, "c": 0, "d": 2 * BLOCK})
+    before = dict(bj.PUMP_TOTALS)
+    trace.clear()
+    pump, res, w = _run(fs)
+    want = _counts(files=4, calls=1 + 4, batched_files=3, batch_calls=1)
+    assert pump.pump == want
+    assert {k: bj.PUMP_TOTALS[k] - before[k] for k in want} == want
+    spans = [r for r in trace.recent() if r["name"] == "backup.pump"]
+    assert len(spans) == 1 and spans[0]["attrs"] == want
+
+
+def test_batch_totals_on_metrics(tmp_path):
+    from pbs_plus_tpu.server import metrics
+    from pbs_plus_tpu.server.store import Server, ServerConfig
+    _run(CountingFS({"a": 10, "b": 10, "c": BLOCK}))
+    server = Server(ServerConfig(state_dir=str(tmp_path / "state"),
+                                 cert_dir=str(tmp_path / "certs"),
+                                 datastore_dir=str(tmp_path / "ds")))
+    expo = metrics.MetricsRegistry(server).render()
+    t = bj.PUMP_TOTALS
+    assert (f'pbs_plus_pump_files_total{{calls="batched"}} '
+            f'{float(t["batched_files"])}') in expo
+    assert (f'pbs_plus_pump_files_total{{calls="several"}} '
+            f'{float(t["files"] - t["one_call_files"] - t["batched_files"])}'
+            ) in expo
+    assert (f'pbs_plus_pump_batch_calls_total '
+            f'{float(t["batch_calls"])}') in expo
+    assert t["batched_files"] >= 2 and t["batch_calls"] >= 1

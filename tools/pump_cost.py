@@ -7,14 +7,19 @@ writer that only reads (PERF.md section 5; ROADMAP S8, the pump).
 
     python3 tools/pump_cost.py          # on the bench host: no device used
 
-Two trees — 1,024 files of 13 KB (the distribution's median) and 8
-files of 32 MiB — each pumped REPEATS times against the agent as it is
-and against one that ignores ``read`` on ``agentfs.open`` (the answer of
-an agent from before PR 29).  Per tree and agent, the median run: wall
-and process CPU seconds, milliseconds a file and a MiB, and the pump's
-own count of calls a file.  One JSON line on stdout, the same in
-``chiprun_out/pump_cost.json``.  It never imports jax; the numbers are
-the host's and only mean something on the host they were taken on.
+Three trees — 1,024 files of 13 KB (the distribution's median) in one
+directory, the same in 16 directories of 64 (single-tree's shape: a run
+of small files ends with its directory) and 8 files of 32 MiB — each
+pumped REPEATS times against the agent as it is, against one without
+``agentfs.read_many`` (the answer of an agent from before PR 31) and
+against one that also ignores ``read`` on ``agentfs.open`` (one from
+before PR 29).  Per tree
+and agent, the median run: wall and process CPU seconds, milliseconds a
+file and a MiB, and the pump's own counts: calls a file, the files that
+came in a ``read_many`` answer and those answers.  One JSON line on
+stdout, the same in ``chiprun_out/pump_cost.json``.  It never imports
+jax; the numbers are the host's and only mean something on the host they
+were taken on.
 """
 
 from __future__ import annotations
@@ -32,15 +37,28 @@ sys.path.insert(0, ROOT)
 from pbs_plus_tpu.agent.agentfs import (  # noqa: E402
     AgentFSClient, AgentFSServer,
 )
+from pbs_plus_tpu.arpc.call import (  # noqa: E402
+    STATUS_NOT_FOUND, Response,
+)
 
 MIB = 1 << 20
 REPEATS = 3
-TREES = {"small": (1024, 13_000), "large": (8, 32 * MIB)}
+# name: (files, bytes a file, files a directory)
+TREES = {"small": (1024, 13_000, 1024), "small_dirs64": (1024, 13_000, 64),
+         "large": (8, 32 * MIB, 8)}
 
 
-class IgnoresRead(AgentFSServer):
-    """An agent from before the ``read`` key: the unknown key is ignored
-    and the answer is the bare handle."""
+class NoReadMany(AgentFSServer):
+    """An agent from before ``agentfs.read_many``: the router's answer
+    to a method it does not know."""
+
+    async def _read_many(self, req, ctx):
+        return Response(STATUS_NOT_FOUND, f"unknown method {req.method!r}")
+
+
+class IgnoresRead(NoReadMany):
+    """An agent from before the ``read`` key as well: the unknown key is
+    ignored and the answer is the bare handle."""
 
     async def _open(self, req, ctx):
         req.payload.pop("read", None)
@@ -62,11 +80,13 @@ class _NullSession:
     writer = _NullWriter()
 
 
-def _make_tree(root: str, files: int, size: int) -> None:
-    os.makedirs(root)
+def _make_tree(root: str, files: int, size: int, per_dir: int) -> None:
     body = os.urandom(size)
     for i in range(files):
-        with open(os.path.join(root, f"f{i:05d}.bin"), "wb") as f:
+        d = root if per_dir >= files \
+            else os.path.join(root, f"d{i // per_dir:03d}")
+        os.makedirs(d, exist_ok=True)
+        with open(os.path.join(d, f"f{i:05d}.bin"), "wb") as f:
             f.write(body)
 
 
@@ -124,10 +144,11 @@ def main() -> int:
     rows = []
     with tempfile.TemporaryDirectory(prefix="pump_cost.") as work:
         pki = _make_pki(os.path.join(work, "pki"))
-        for tree, (files, size) in TREES.items():
+        for tree, (files, size, per_dir) in TREES.items():
             root = os.path.join(work, tree)
-            _make_tree(root, files, size)
+            _make_tree(root, files, size, per_dir)
             for agent, cls in (("honours_read", AgentFSServer),
+                               ("no_read_many", NoReadMany),
                                ("ignores_read", IgnoresRead)):
                 runs = [asyncio.run(_pump_once(pki, root, cls))
                         for _ in range(REPEATS)]
@@ -142,6 +163,8 @@ def main() -> int:
                     "ms_per_mib": 1e3 * mid["wall_s"] / mib,
                     "calls_per_file": mid["pump"]["calls"]
                     / mid["pump"]["files"],
+                    "batched_files": mid["pump"]["batched_files"],
+                    "batch_calls": mid["pump"]["batch_calls"],
                     "pump": mid["pump"], "agent_stats": mid["agent"]})
     line = json.dumps({"host_cores": os.cpu_count(), "repeats": REPEATS,
                        "rows": rows})
