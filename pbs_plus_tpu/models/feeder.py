@@ -16,7 +16,10 @@ of the scan's padded lengths), and once more for what is left when the
 stream is flushed or ends.  So a request is a full row, or a stream's
 last; ``feeds`` on a request says how many writes it holds, summed into
 ``stats["mask_feeds"]`` beside ``mask_rows`` and onto the
-``feeder.dispatch`` span.
+``feeder.dispatch`` span.  ``stats["mask_rows_shared"]`` counts, of
+``mask_rows``, those that shared their dispatch with another request's:
+a mean of 1.5 rows a round is half the rows alone or none, and this
+tells which.
 
 Mechanics (single dispatch thread, adaptive batching via backpressure):
 
@@ -140,6 +143,10 @@ class DeviceFeeder:
                       # writes the rows held: a stream's chunker gathers
                       # them into segments (models/dedup.py TpuChunker)
                       "mask_feeds": 0,
+                      # of ``mask_rows``, those that went to the device
+                      # beside another request's: the rows of every scan
+                      # group of two or more (one retried alone is alone)
+                      "mask_rows_shared": 0,
                       "max_mask_batch": 0, "mask_retried_alone": 0,
                       "sha_dispatches": 0, "sha_streams": 0,
                       "max_sha_streams": 0, "sha_retried_alone": 0,
@@ -283,6 +290,8 @@ class DeviceFeeder:
                                       self._tables(key, params), params)
         self.stats["mask_dispatches"] += 1
         self.stats["mask_rows"] += len(group)
+        if len(group) > 1:
+            self.stats["mask_rows_shared"] += len(group)
         self.stats["mask_feeds"] += sum(r.feeds for r in group)
         return hits
 

@@ -568,6 +568,11 @@ class MetricsRegistry:
               "Writes of their streams the scan rows carried: a stream's "
               "chunker gathers its writes into 4 MiB scan segments",
               [({}, float(fd["mask_feeds"]))] if "mask_feeds" in fd else [])
+        gauge("pbs_plus_feeder_scan_rows_shared_total",
+              "Scan rows that went to the device beside another "
+              "request's: the rows of every dispatch of two or more",
+              [({}, float(fd["mask_rows_shared"]))]
+              if "mask_rows_shared" in fd else [])
         gauge("pbs_plus_feeder_rounds_total",
               "Rounds of the device batcher: one drain of both queues, "
               "served as a scan dispatch per chunker key and one hash "

@@ -325,7 +325,8 @@ def test_metrics_render_every_new_gauge(tmp_path):
                 in expo)
     for name in ("device_compilations", "device_compile_seconds",
                  "device_table_uploads", "device_table_upload_bytes",
-                 "feeder_rounds", "feeder_scan_feeds"):
+                 "feeder_rounds", "feeder_scan_feeds",
+                 "feeder_scan_rows_shared"):
         assert f"pbs_plus_{name}_total " in expo, name
 
 

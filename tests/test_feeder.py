@@ -360,6 +360,17 @@ def test_wait_and_busy_clocks_bound_what_writers_stood(
     assert waits + n * call_s <= sum(stood) <= waits + n * busy + slack_s
     (dispatch,) = [r for r in spans if r["name"] == "feeder.dispatch"]
     assert dispatch["attrs"]["retried"] == (n if batch_fails else 0)
+    # rows that shared their dispatch: the group's when it landed, none
+    # when each was retried alone or there was one; the class a dispatch
+    # was padded to is on its ``device.scan`` child span, in one place
+    landed = n > 1 and not batch_fails
+    assert stats["mask_rows_shared"] == (n if landed else 0)
+    assert dispatch["attrs"]["reqs"] == n
+    assert "padded_rows" not in dispatch["attrs"]
+    padded = [r["attrs"]["padded_rows"] for r in spans
+              if r["name"] == "device.scan"]
+    assert len(padded) == (1 if landed else n)
+    assert all(p >= n for p in padded) if landed else padded == [1] * n
 
 
 def test_one_dispatch_span_per_round_links_its_submitters(wide_feeder):
