@@ -8,7 +8,7 @@ Dataflow per step (B agent streams at once — the batch axis IS the agent
 fan-in, SURVEY §2.10):
 
     host pages → device stream buffer uint8[B, S]
-      ├─ rolling-hash kernel → candidate mask bool[B, S]      (device)
+      ├─ rolling-hash kernel → candidate mask, 32 positions a word (device)
       ├─ greedy min/max cut selection over sparse candidates  (host, O(B·S/avg))
       ├─ block-gather + SHA-256 scan → digests uint8[N, 32]   (device)
       ├─ cuckoo probe → maybe-present bool[N]                 (device)

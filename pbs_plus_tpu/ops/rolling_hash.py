@@ -35,13 +35,15 @@ jaxenv.watch_compiles()
 # multi-chip dispatch evidence and padding occupancy (test/metrics
 # probe): mesh_* move whenever a batched dispatch is sharded over the
 # data mesh; mesh_shard_devices is how many distinct devices the last
-# sharded input's shards really sat on.  The five phase clocks
+# sharded input's shards really sat on; home_bytes is what the answers
+# weighed on their way back (padded_bytes / 8: a bit a position).  The
+# five phase clocks
 # (pack_s … unpack_s: seconds of the calling thread inside each step of
 # a round trip, docs/observability.md) come with the registration.
 stats = trace.device_stats("scan", {
     "mesh_dispatches": 0, "mesh_devices": 0, "mesh_shard_devices": 0,
     "dispatches": 0, "rows": 0, "padded_rows": 0, "bytes": 0,
-    "padded_bytes": 0})
+    "padded_bytes": 0, "home_bytes": 0})
 
 
 def _rotl(x: jax.Array, r: int) -> jax.Array:
@@ -123,14 +125,59 @@ def candidate_mask(data: jax.Array, tables: jax.Array, mask: int,
                                jnp.uint32(magic), history)
 
 
+# positions a word of the packed answer holds
+WORD_BITS = 32
+_BIT = np.arange(WORD_BITS, dtype=np.uint32)
+
+
+def _candidate_words_impl(data: jax.Array, tables: jax.Array, mask: int,
+                          magic: int, history: jax.Array) -> jax.Array:
+    """``_candidate_mask_impl``'s ``hit[B, S]`` bit-packed in the same
+    program: ``uint32[B, S/32]``, bit ``j`` of word ``w`` is position
+    ``j * S/32 + w``.  Strided, not 32 neighbours a word: the 32 bits of
+    a word are then 32 slices of the row OR-ed elementwise, the minor
+    axis stays S/32 wide, and nothing is reduced across lanes.  Exact
+    and of static shape (every segment class divides by 32); row-local,
+    so rows sharded over a mesh stay where they are."""
+    hit = _candidate_mask_impl(data, tables, mask, magic, history)
+    B, S = hit.shape
+    bits = hit.reshape(B, WORD_BITS, S // WORD_BITS).astype(jnp.uint32) \
+        << _BIT[None, :, None]
+    return jnp.bitwise_or.reduce(bits, axis=1)
+
+
+_candidate_words_jit = jax.jit(_candidate_words_impl)
+
+
+def candidate_words(data: jax.Array, tables: jax.Array, mask: int,
+                    magic: int, history: jax.Array) -> jax.Array:
+    """Jitted entry of the batched scan (``_dispatch_hits``): the
+    candidate mask of ``data[B, S]``, 32 positions a word."""
+    return _candidate_words_jit(data, tables, jnp.uint32(mask),
+                                jnp.uint32(magic), history)
+
+
+def unpack_words(words: np.ndarray, n: int) -> np.ndarray:
+    """Ascending positions below ``n`` of the bits set in one row of
+    ``candidate_words``' answer (host side): the few words that are not
+    zero, then their bits."""
+    # through a bool array: numpy scans one several times faster
+    at = np.flatnonzero(words != 0)
+    k, bit = np.nonzero((words[at, None] >> _BIT) & np.uint32(1))
+    pos = bit * len(words) + at[k]
+    pos = pos[pos < n]
+    pos.sort()
+    return pos
+
+
 # The whole jit key of the batched scan is (padded rows, padded segment
 # length), and both come from these two short lists.
 _ROW_CLASSES = (1, 4, 16, 64)
 _SEG_CLASSES = tuple(1 << k for k in range(16, 31, 2))     # 64 KiB … 1 GiB
 # device bytes one scanned byte costs while the program runs: input +
-# mask out + the uint32 hash array and its shifted copy (the chip's
-# compiler reports temp = 8.04x input at [8, 4 MiB] and [64, 4 MiB] —
-# tests/test_tpu_compile.py)
+# the uint32 hash array and its shifted copy (the chip's compiler
+# reports temp = 8.04x input at [8, 4 MiB] and [64, 4 MiB] —
+# tests/test_tpu_compile.py) + the packed answer, an eighth of a byte
 _SCAN_BYTES_PER_BYTE = 10
 # share of a device's memory one scan dispatch may take: the hash
 # staging buffers and the index table live there too
@@ -174,7 +221,7 @@ def batched_candidate_hits(bufs: list, hists: list, tables: jax.Array,
                            params: ChunkerParams) -> list[np.ndarray]:
     """THE pack/dispatch/unpack step for cross-stream candidate batching:
     stack variable-length segments (with optional per-row 63-byte history)
-    into class-padded ``[B_pad, S_pad]`` candidate_mask dispatches — as
+    into class-padded ``[B_pad, S_pad]`` candidate_words dispatches — as
     many as the device budget splits the rows into — and return each
     row's raw hit indices (0-based positions, unfiltered — callers apply
     their own window-validity/offset arithmetic).
@@ -246,12 +293,13 @@ def _dispatch_hits(bufs: list, hists: list, S_pad: int, mesh,
         rt.add(dispatches=1, rows=len(bufs), padded_rows=B_pad,
                bytes=sum(len(b) for b in bufs), padded_bytes=buf.size)
         with rt.phase("device"):
-            dmask = candidate_mask(dbuf, tables, params.mask, params.magic,
-                                   history=dhist).block_until_ready()
+            dwords = candidate_words(dbuf, tables, params.mask, params.magic,
+                                     dhist).block_until_ready()
         with rt.phase("d2h"):
-            m = np.asarray(dmask)
+            words = np.asarray(dwords)
+        rt.add(home_bytes=words.nbytes)
         with rt.phase("unpack"):
-            return [np.nonzero(m[i, :len(b)])[0]
+            return [unpack_words(words[i], len(b))
                     for i, b in enumerate(bufs)]
 
 

@@ -515,12 +515,14 @@ class MetricsRegistry:
               [({"op": op}, float(st["dispatches"]))
                for op, st in dev.items()])
         gauge("pbs_plus_device_bytes_total",
-              "Bytes asked for (payload) and bytes sent to the device "
-              "after padding to a shape class (padded), by op",
+              "Bytes asked for (payload), bytes sent to the device after "
+              "padding to a shape class (padded) and, where the op counts "
+              "them, bytes of the answer brought home (home), by op",
               [({"op": op, "kind": kind}, float(st[key]))
                for op, st in dev.items()
                for kind, key in (("payload", "bytes"),
-                                 ("padded", "padded_bytes"))])
+                                 ("padded", "padded_bytes"),
+                                 ("home", "home_bytes")) if key in st])
         # the host's SHA-256 (ops/sha256.py sha256_chunks: every hash
         # batch of a writer), beside the device engine's op="sha"
         # samples above

@@ -85,7 +85,7 @@ def _slow(fn):
 
 @pytest.mark.parametrize("op,phase,module,attr", [
     ("scan", "unpack", rolling_hash.np, "nonzero"),
-    ("scan", "device", rolling_hash, "candidate_mask"),
+    ("scan", "device", rolling_hash, "candidate_words"),
     ("sha", "device", sha256, "_sha256_scan"),
     ("probe", "device", cuckoo, "_lookup"),
 ])
@@ -279,13 +279,25 @@ def test_the_scan_waits_once_for_the_device(monkeypatch):
             waits.append("result")
             return self.array
 
-    mask = rolling_hash.candidate_mask
+    words = rolling_hash.candidate_words
     monkeypatch.setattr(jax, "block_until_ready",
                         lambda x: waits.append("inputs") or x)
-    monkeypatch.setattr(rolling_hash, "candidate_mask",
-                        lambda *a, **kw: Result(mask(*a, **kw)))
+    monkeypatch.setattr(rolling_hash, "candidate_words",
+                        lambda *a, **kw: Result(words(*a, **kw)))
     _trip("scan")
     assert waits == ["result"]
+
+
+def test_scan_span_carries_the_bytes_its_answer_weighed(spans):
+    """The answer comes home 32 positions a word (ISSUE 33):
+    ``home_bytes`` is on the ``device.scan`` span and in the op's
+    counters, an eighth of what went out."""
+    before = dict(rolling_hash.stats)
+    _trip("scan")
+    scan, = [r for r in spans if r["name"] == "device.scan"]
+    assert scan["attrs"]["home_bytes"] * 8 == scan["attrs"]["padded_bytes"]
+    for key in ("home_bytes", "padded_bytes"):
+        assert rolling_hash.stats[key] - before[key] == scan["attrs"][key]
 
 
 def test_metrics_render_every_new_gauge(tmp_path):
@@ -309,6 +321,9 @@ def test_metrics_render_every_new_gauge(tmp_path):
         for kind in ("payload", "padded"):
             assert (f'pbs_plus_device_bytes_total{{kind="{kind}",'
                     f'op="{op}"}}') in expo
+        # the answer's bytes on their way home: the scan's alone so far
+        assert ('pbs_plus_device_bytes_total{kind="home",'
+                f'op="{op}"}}' in expo) == (op == "scan")
         assert (f'pbs_plus_device_dispatch_seconds_count{{op="{op}"}}'
                 in expo)
     for state in ("scan", "sha", "idle", "linger"):

@@ -88,19 +88,19 @@ def test_scan_shapes_come_from_two_short_lists(monkeypatch):
     rng = np.random.default_rng(32)
     table = device_tables(P)
     seen = set()
-    real = rh.candidate_mask
+    real = rh.candidate_words
 
     def spy(dbuf, *a, **kw):
         seen.add(tuple(dbuf.shape))
         return real(dbuf, *a, **kw)
-    monkeypatch.setattr(rh, "candidate_mask", spy)
+    monkeypatch.setattr(rh, "candidate_words", spy)
     for rows, n in ((1, 1), (1, 65_537), (2, 300), (3, 70_000),
                     (5, 65_536), (9, 12), (17, 1_000)):
         bufs = [rng.integers(0, 256, n, dtype=np.uint8)] * rows
         rh.batched_candidate_hits(bufs, [None] * rows, table, P)
     # single rows stay local; batches pad to the 8 virtual devices' mesh
-    assert seen <= {(b, s) for b in (1, 8, 16, 24, 64)
-                    for s in (1 << 16, 1 << 18)}, seen
+    assert seen and seen <= {(b, s) for b in (1, 8, 16, 24, 64)
+                             for s in (1 << 16, 1 << 18)}, seen
 
 
 _P1K = ChunkerParams(avg_size=1 << 10)     # densest candidates allowed
@@ -166,6 +166,139 @@ def test_batched_hits_over_ragged_rows_in_one_dispatch(case):
         want = candidates(stream, _P1K, force_numpy=True) - hist - 1
         assert np.array_equal(hits, want), (case, len(r), hist)
         assert not len(hits) or hits[-1] < len(r)
+
+
+class _P64(ChunkerParams):
+    """A condition of six bits — about one position in 64 hits, denser
+    than any ``avg_size`` the chunker allows — so that words of the
+    packed answer hold several bits."""
+    mask = 63
+
+
+def _forced_hit_row(rng, n: int, at: tuple) -> np.ndarray:
+    """``n`` seeded bytes redrawn window by window until every position
+    of ``at`` is a hit of ``_P1K`` (positions 64 bytes or more apart:
+    a hit hangs on the 64 bytes that end at it)."""
+    row = rng.integers(0, 256, n, dtype=np.uint8)
+    for p in at:
+        while p + 1 not in candidates(row[p - 63:p + 1], _P1K,
+                                      force_numpy=True) + (p - 63):
+            row[p - 63:p + 1] = rng.integers(0, 256, 64, dtype=np.uint8)
+    return row
+
+
+def _packed_case(case: str):
+    """(rows, tails, params, hits that have to be there) of one request
+    to the packed scan."""
+    rng = np.random.default_rng(sum(case.encode()))
+
+    def tail():
+        return rng.integers(0, 256, 63, dtype=np.uint8)
+
+    def row(n):
+        return rng.integers(0, 256, n, dtype=np.uint8)
+    forced = ()
+    if case.startswith("ragged-"):
+        # (a) every row class up to 16 at every segment class up to 4 MiB,
+        # every second row with a tail, none of a class's own length
+        n, seg = {"ragged-1x4MiB": (1, 4 << 20), "ragged-3x1MiB": (3, 1 << 20),
+                  "ragged-4x256KiB": (4, 1 << 18),
+                  "ragged-16x64KiB": (16, 1 << 16)}[case]
+        rows = [row(seg - 1 - 997 * i) for i in range(n)]
+        tails = [tail() if i % 2 == 0 else None for i in range(n)]
+        return rows, tails, _P1K, forced
+    if case == "dense-one-in-64":                                   # (b)
+        return ([row(70_000), row(65_536), row(300)],
+                [tail(), None, tail()], _P64(avg_size=1 << 10), forced)
+    if case == "zeros-and-short-row":                               # (c)
+        return ([np.zeros(100_000, dtype=np.uint8),
+                 _row_with_phantom(rng, 900), row(1)],
+                [None, tail(), None], _P1K, forced)
+    if case == "forced-edges":                                      # (d)
+        # position 63 (the first with a whole window and no tail), the
+        # row's last byte, and words 0 and S/32 - 1 of the strided
+        # packing: positions k * S/32 and k * S/32 + S/32 - 1
+        n, stride = 1 << 16, (1 << 16) // 32
+        forced = (63, 3 * stride, 5 * stride - 1, n - 1)
+        return [_forced_hit_row(rng, n, forced)], [None], _P1K, forced
+    assert case == "sharded-over-the-mesh"                          # (e)
+    return ([row(40_000 + 3_001 * i) for i in range(5)],
+            [tail() if i % 2 else None for i in range(5)], _P1K, forced)
+
+
+@pytest.mark.parametrize("case", [
+    "ragged-1x4MiB", "ragged-3x1MiB", "ragged-4x256KiB", "ragged-16x64KiB",
+    "dense-one-in-64", "zeros-and-short-row", "forced-edges",
+    "sharded-over-the-mesh"])
+def test_packed_hits_are_the_dense_masks_positions(case):
+    """The answer comes home 32 positions a word (ISSUE 33): every row's
+    positions are ``np.nonzero`` of the dense ``candidate_mask`` over the
+    same padded rows, cut at the row's real length, in ascending order —
+    and an eighth of the padded bytes crossed."""
+    import jax
+    from pbs_plus_tpu.ops import rolling_hash as rh
+    rows, tails, params, forced = _packed_case(case)
+    table = device_tables(params)
+    before = dict(rh.stats)
+    got = rh.batched_candidate_hits(rows, tails, table, params)
+    spent = {k: rh.stats[k] - before[k] for k in
+             ("dispatches", "padded_bytes", "home_bytes", "mesh_dispatches")}
+    assert spent["dispatches"] == 1
+    assert spent["home_bytes"] * 8 == spent["padded_bytes"]
+    assert spent["mesh_dispatches"] == (len(rows) > 1)
+    seg = rh.segment_class(max(len(r) for r in rows))
+    buf = np.zeros((len(rows), seg), dtype=np.uint8)
+    hist = np.zeros((len(rows), 63), dtype=np.uint8)
+    for i, (r, t) in enumerate(zip(rows, tails)):
+        buf[i, :len(r)] = r
+        if t is not None:
+            hist[i] = t
+    dense = np.asarray(candidate_mask(jnp.asarray(buf), table, params.mask,
+                                      params.magic, history=jnp.asarray(hist)))
+    assert dense.any()
+    for r, hits, m in zip(rows, got, dense):
+        assert np.array_equal(hits, np.nonzero(m[:len(r)])[0]), (case, len(r))
+    assert set(forced) <= set(got[0].tolist())
+    if case == "dense-one-in-64":
+        # words with several bits set did come home
+        words = np.asarray(rh.candidate_words(
+            jnp.asarray(buf), table, params.mask, params.magic,
+            jnp.asarray(hist)))
+        assert (words & (words - 1)).any()
+    if case == "sharded-over-the-mesh":
+        # the packing is row-local: the words stay on the rows' devices
+        from jax.sharding import NamedSharding, PartitionSpec
+        from pbs_plus_tpu.parallel.mesh import data_mesh
+        by_rows = NamedSharding(data_mesh(), PartitionSpec("data", None))
+        pad = np.zeros((8, seg), dtype=np.uint8)
+        pad[:len(rows)] = buf
+        words = rh.candidate_words(
+            jax.device_put(pad, by_rows), table, params.mask, params.magic,
+            jax.device_put(np.zeros((8, 63), np.uint8), by_rows))
+        assert words.sharding.is_equivalent_to(by_rows, 2)
+        assert len({s.device for s in words.addressable_shards}) == 8
+
+
+def test_scan_crossover_engines_give_the_same_positions(monkeypatch):
+    """``tools/scan_crossover.py`` (the host's bar for the scan): at a
+    small row and one repeat, its native scanners and the device trip
+    report the same positions — a CPU run gives no rate to look at."""
+    from pbs_plus_tpu.chunker import native
+    from tools import scan_crossover as sc
+    if not native.available():
+        pytest.skip("the native scanners did not build")
+    monkeypatch.setattr(sc, "REPEATS", 1)
+    monkeypatch.setattr(sc, "ROW", 1 << 16)
+    rng = np.random.default_rng(33)
+    rows = [rng.integers(0, 256, sc.ROW, dtype=np.uint8) for _ in range(4)]
+    tails = [rng.integers(0, 256, 63, dtype=np.uint8) for _ in rows]
+    host, want = sc.host_rates(rows, tails, _P1K)
+    assert sum(len(w) for w in want) > 100
+    assert all(host[e]["same_positions"] for e in host if e != "vector_impl")
+    for n in (1, 4):
+        trip = sc.device_rate(rows[:n], tails[:n], _P1K, want[:n])
+        assert trip["same_positions"] and trip["rows"] == n
+        assert trip["home_bytes_per_padded_byte"] == 0.125
 
 
 def test_device_cuts_match_cpu_cuts():

@@ -67,7 +67,8 @@ def device_bytes(compiled) -> int:
 
 
 def compile_scan(shape, rows: int, seg: int):
-    return rolling_hash._candidate_mask_jit.lower(
+    """The batched scan's program: the mask, packed 32 positions a word."""
+    return rolling_hash._candidate_words_jit.lower(
         shape((rows, seg), jnp.uint8), shape((2, 16), jnp.uint32),
         shape((), jnp.uint32), shape((), jnp.uint32),
         shape((rows, 63), jnp.uint8)).compile()
@@ -103,6 +104,30 @@ def test_scan_budget_splits_what_the_chip_refuses(shape, v5e_budget):
     assert device_bytes(compile_scan(shape, 1, 256 * MIB)) <= v5e_budget
     with pytest.raises(ValueError, match="does not fit"):
         rolling_hash.dispatch_rows(1 << 30)
+
+
+def test_packed_scan_keeps_its_rows_where_they_are_on_four_chips(topo):
+    """The fan-in's sharded dispatch on a v5e 2x2: packing the answer is
+    row-local, so the words come out sharded as the rows went in and the
+    program holds no collective (ISSUE 33)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("data",))
+    by_rows, whole = NamedSharding(mesh, P("data", None)), \
+        NamedSharding(mesh, P())
+
+    def arg(dims, dtype, sharding):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+    compiled = rolling_hash._candidate_words_jit.lower(
+        arg((4, 4 * MIB), jnp.uint8, by_rows),
+        arg((2, 16), jnp.uint32, whole), arg((), jnp.uint32, whole),
+        arg((), jnp.uint32, whole),
+        arg((4, 63), jnp.uint8, by_rows)).compile()
+    assert compiled.output_shardings.is_equivalent_to(by_rows, 2)
+    text = compiled.as_text()
+    assert not any(op in text for op in (
+        "all-gather", "all-reduce", "all-to-all", "collective-permute",
+        "reduce-scatter"))
 
 
 def test_sha256_tpu_branch_compiles_once_for_every_length(shape, monkeypatch):
