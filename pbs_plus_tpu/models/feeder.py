@@ -63,8 +63,11 @@ The thread's own time (docs/observability.md "The batcher"): every
 second of the feeder thread's life goes to one of four clocks in
 ``stats`` — ``mask_busy_s`` / ``sha_busy_s`` inside a dispatch,
 ``idle_s`` waiting with both queues empty, ``linger_s`` widening a batch
-— and every request adds its wait from submit to the start of its
-dispatch to ``mask_wait_s`` / ``sha_wait_s``.  ``linger_rounds`` counts
+— kept by the shared thread clock (``trace.ThreadClock``), which also
+brings ``cpu_s``, the thread's own CPU seconds, up to date once a round:
+a dispatch's busy time less its CPU is time blocked (the device, the
+interpreter lock).  Every request adds its wait from submit to the start
+of its dispatch to ``mask_wait_s`` / ``sha_wait_s``.  ``linger_rounds`` counts
 the rounds that waited out a linger and ``linger_joined`` those of them
 in which a second request arrived before the wait ended: with one
 session nobody can join, and the linger only delays the request it
@@ -152,13 +155,14 @@ class DeviceFeeder:
                       "max_sha_streams": 0, "sha_retried_alone": 0,
                       # the thread's life, partitioned (module docstring)
                       "mask_busy_s": 0.0, "sha_busy_s": 0.0,
-                      "idle_s": 0.0, "linger_s": 0.0, "rounds": 0,
+                      "idle_s": 0.0, "linger_s": 0.0, "cpu_s": 0.0,
+                      "rounds": 0,
                       # of the rounds, those that lingered, and those of
                       # them a second request joined before the wait ended
                       "linger_rounds": 0, "linger_joined": 0,
                       # requests' waits from submit to their dispatch
                       "mask_wait_s": 0.0, "sha_wait_s": 0.0}
-        self._clock = 0.0       # the feeder thread's: start of its state
+        self._clock = trace.ThreadClock(self.stats)     # the thread's
         # the round under way, for its ``feeder.dispatch`` spans
         self._round = {"lingered": 0, "joined": 0}
 
@@ -201,21 +205,15 @@ class DeviceFeeder:
                 self._thread.start()
             self._cv.notify_all()
 
-    def _spent(self, state: str) -> None:
-        """The feeder thread's time since the last call goes to
-        ``state``: the four clocks partition the thread's life."""
-        now = time.perf_counter()
-        self.stats[state] += now - self._clock
-        self._clock = now
-
     def _run(self) -> None:
         trace.name_os_thread("device-feeder")   # its line in a profile
-        self._clock = time.perf_counter()
+        clock = self._clock
+        clock.start()
         while True:
             with self._cv:
                 while not self._mask_q and not self._sha_q:
                     self._cv.wait()
-                self._spent("idle_s")
+                clock.spent("idle_s")
                 # adaptive widening: if only one request is pending, give
                 # concurrent writers a linger window to join the batch
                 lingered = (self.linger_s > 0
@@ -223,7 +221,7 @@ class DeviceFeeder:
                 joined = False
                 if lingered:
                     self._cv.wait(self.linger_s)
-                    self._spent("linger_s")
+                    clock.spent("linger_s")
                     joined = len(self._mask_q) + len(self._sha_q) > 1
                     self.stats["linger_rounds"] += 1
                     self.stats["linger_joined"] += joined
@@ -243,15 +241,16 @@ class DeviceFeeder:
             try:
                 if mask_reqs:
                     self._dispatch_masks(mask_reqs)
-                    self._spent("mask_busy_s")
+                    clock.spent("mask_busy_s")
                 if sha_reqs:
                     self._dispatch_sha(sha_reqs)
-                    self._spent("sha_busy_s")
+                    clock.spent("sha_busy_s")
             except BaseException as e:
                 for r in mask_reqs + sha_reqs:
                     if not r.done.is_set():
                         r.exc = e
                         r.done.set()
+            clock.cpu()
 
     def _take_sha_locked(self) -> list[_ShaReq]:
         out, total = [], 0

@@ -190,7 +190,11 @@ class _ChunkedStream:
         # path pays two perf_counter_ns calls, and sync()/finish() emit
         # ONE aggregate span per stage (docs/observability.md "Ingest
         # stages") — batch-dispatched stages (sha/probe/presketch on the
-        # batch-hasher path) get real per-dispatch spans instead.
+        # batch-hasher path) get real per-dispatch spans instead.  The
+        # chunker's two readings are also the session clock's, where the
+        # calling thread has one (``trace.state``: a backup job's writer
+        # thread; docs/observability.md "The session's clocks"), as are
+        # the brackets around the batch-dispatched stages.
         # Pipelined hash workers += these concurrently; a lost update
         # only shaves an observability aggregate (same contract as
         # pipeline._hash_inflight).
@@ -205,13 +209,13 @@ class _ChunkedStream:
         self._buf.append(data)
         self.offset += len(data)
         self.stats.bytes_streamed += len(data)
-        if trace.enabled():
-            t0 = time.perf_counter_ns()
+        at_chunker = trace.state("cdc_s")
+        t0 = at_chunker.begin()
+        try:
             cuts = self._chunker.feed(data)
-            self._cdc_ns += time.perf_counter_ns() - t0
-            self._cdc_bytes += len(data)
-        else:
-            cuts = self._chunker.feed(data)
+        finally:
+            self._cdc_ns += at_chunker.end() - t0
+        self._cdc_bytes += len(data)
         self._emit(cuts)
 
     def _emit(self, run_relative_cuts: list[int]) -> None:
@@ -225,14 +229,13 @@ class _ChunkedStream:
         chunk = self._buf.take(n)      # memoryview when seam-free
         self._buf_base = end
         if self._hasher is None:
-            if trace.enabled():
-                t0 = time.perf_counter_ns()
-                digest = hashlib.sha256(chunk).digest()
-                self._sha_ns += time.perf_counter_ns() - t0
-                self._sha_chunks += 1
-            else:
-                digest = hashlib.sha256(chunk).digest()
-            self._insert(digest, chunk)
+            hashing = trace.state("sha_s")
+            t0 = hashing.begin()
+            digest = hashlib.sha256(chunk).digest()
+            self._sha_ns += hashing.end() - t0
+            self._sha_chunks += 1
+            with trace.state("store_s"):
+                self._insert(digest, chunk)
             self.records.append((end, digest))
         else:
             self.records.append((end, b""))
@@ -256,7 +259,8 @@ class _ChunkedStream:
         backend = self._ingest
         if not backend.capabilities.probe:
             return None
-        with trace.span("ingest.probe", chunks=len(digests)):
+        with trace.state("probe_s"), \
+                trace.span("ingest.probe", chunks=len(digests)):
             return backend.probe_batch(digests)
 
     def _insert_probed(self, digest: bytes, chunk: bytes,
@@ -281,20 +285,23 @@ class _ChunkedStream:
         same batches — accounting stays bit-identical."""
         backend = self._ingest
         if backend.capabilities.presketch:
-            with trace.span("ingest.presketch", chunks=len(digests)):
+            with trace.state("presketch_s"), \
+                    trace.span("ingest.presketch", chunks=len(digests)):
                 backend.presketch_batch(digests, chunks, known)
 
     def _flush_hashes(self) -> None:
         if not self._pending:
             return
         assert self._hasher is not None
-        with trace.span("ingest.sha", chunks=len(self._pending)):
+        with trace.state("sha_s"), \
+                trace.span("ingest.sha", chunks=len(self._pending)):
             digests = self._hasher([c for _, c in self._pending])
         known = self._probe_known(digests)
         self._presketch(digests, [c for _, c in self._pending], known)
         new0 = self.stats.new_chunks
-        with trace.span("ingest.store", chunks=len(digests),
-                        bytes=self._pending_bytes) as sp:
+        with trace.state("store_s"), \
+                trace.span("ingest.store", chunks=len(digests),
+                           bytes=self._pending_bytes) as sp:
             for i, ((idx, chunk), digest) in enumerate(zip(self._pending,
                                                            digests)):
                 end, _ = self.records[idx]
@@ -307,7 +314,12 @@ class _ChunkedStream:
 
     def flush_chunker(self) -> None:
         """Force a cut at the current offset and restart the chunker."""
-        cuts = self._chunker.finalize()
+        at_chunker = trace.state("cdc_s")   # a scan of the row's rest
+        t0 = at_chunker.begin()
+        try:
+            cuts = self._chunker.finalize()
+        finally:
+            self._cdc_ns += at_chunker.end() - t0
         self._emit(cuts)
         assert self._buf_base == self.offset and not self._buf
         self._chunker = self._factory(self.params)

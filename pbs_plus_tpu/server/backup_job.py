@@ -71,18 +71,38 @@ _ABORTED = object()
 PUMP_TOTALS = {"files": 0, "one_call_files": 0, "calls": 0,
                "batched_files": 0, "batch_calls": 0}
 
+# A session's own clocks (docs/observability.md "The session's clocks").
+# The writer thread's life, partitioned by state through the shared
+# thread clock (utils/trace.py): waiting for the pump, at the chunker
+# (the gather and the stand at the device), the hash batch, the index
+# probe, the similarity sketch, the store, and everything else on the
+# thread; beside them its own CPU seconds.
+WRITER_STATES = ("pump_wait_s", "cdc_s", "sha_s", "probe_s", "presketch_s",
+                 "store_s", trace.REST)
+# The pump's awaits, wall sums: suspended on the agent (every agentfs
+# call), on the writer (a queue put is an executor hop even when the
+# queue has room) and on the writer's join at the end.
+PUMP_WAITS = ("rpc_wait_s", "put_wait_s", "join_wait_s")
+# Process-wide totals of both, rendered on /metrics like PUMP_TOTALS and
+# written where those are; ``loop_cpu_s`` is the event loop thread's CPU
+# clock (``time.thread_time`` on it) as last read at a pump's end.
+CLOCK_TOTALS = {**{"writer_" + k: 0.0 for k in WRITER_STATES + ("cpu_s",)},
+                **{"pump_" + k: 0.0 for k in PUMP_WAITS},
+                "loop_cpu_s": 0.0}
+
 
 def _get_abortable(q: "queue.Queue", abort: "threading.Event | None"):
     """Blocking queue get that returns _ABORTED instead of waiting
     forever once ``abort`` is set (producers cancelled mid-flight never
     send their sentinels).  The single polling idiom for every
     writer-side wait in this module."""
-    while True:
-        try:
-            return q.get(timeout=0.25)
-        except queue.Empty:
-            if abort is not None and abort.is_set():
-                return _ABORTED
+    with trace.state("pump_wait_s"):
+        while True:
+            try:
+                return q.get(timeout=0.25)
+            except queue.Empty:
+                if abort is not None and abort.is_set():
+                    return _ABORTED
 
 
 def match_exclusion(rel: str, patterns: list[str]) -> bool:
@@ -265,6 +285,10 @@ class RemoteTreeBackup:
         self.resume = getattr(session, "resume_plan", None)
         # this job's share of PUMP_TOTALS
         self.pump = dict.fromkeys(PUMP_TOTALS, 0)
+        # the session's clocks: the writer thread's, and the pump's waits
+        self.writer_clock = trace.ThreadClock(
+            dict.fromkeys(WRITER_STATES, 0.0), label="writer")
+        self.waits = dict.fromkeys(PUMP_WAITS, 0.0)
         # until the agent answers that it does not know read_many
         self._batching = True
         self._wq: queue.Queue = queue.Queue(maxsize=QUEUE_DEPTH)
@@ -291,12 +315,29 @@ class RemoteTreeBackup:
 
     async def run(self) -> BackupResult:
         with trace.span("backup.pump") as sp:
+            t0, loop_cpu0 = time.perf_counter(), time.thread_time()
             try:
                 return await self._run()
             finally:
-                sp.set(**self.pump)
+                # one record a job: the pump's counts, the writer's
+                # clock (its thread is joined by now), the pump's waits
+                # and the loop thread's CPU clock at both ends
+                clocks = {
+                    **{"writer_" + k: v
+                       for k, v in self.writer_clock.seconds.items()},
+                    **{"pump_" + k: v for k, v in self.waits.items()},
+                    "pump_life_s": time.perf_counter() - t0,
+                    "loop_cpu0": loop_cpu0, "loop_cpu1": time.thread_time()}
+                sp.set(job=self.log.scope.get("job_id", ""), **self.pump,
+                       **clocks)
                 for k, v in self.pump.items():
                     PUMP_TOTALS[k] += v
+                for k, v in clocks.items():
+                    if k in CLOCK_TOTALS:       # the states and the waits
+                        CLOCK_TOTALS[k] += v
+                CLOCK_TOTALS["loop_cpu_s"] = clocks["loop_cpu1"]
+                self.log.info("session clocks: %s", " ".join(
+                    f"{k}={v:.6f}" for k, v in clocks.items()))
 
     async def _run(self) -> BackupResult:
         # hand the job's trace context to the writer thread: ingest
@@ -306,7 +347,7 @@ class RemoteTreeBackup:
             target=self._writer_loop, name="backup-writer", daemon=True)
         writer_thread.start()
         try:
-            root_attr = await self.fs.attr("")
+            root_attr = await self._rpc(self.fs.attr(""))
             await self._put(("entry", self._to_entry("", root_attr), None))
             await self._walk("")
         except BaseException as e:
@@ -336,12 +377,28 @@ class RemoteTreeBackup:
 
     async def _close_writer(self, writer_thread: threading.Thread) -> None:
         await self._put(_SENTINEL)
-        await asyncio.get_running_loop().run_in_executor(
-            None, writer_thread.join)
+        await self._hop(writer_thread.join, wait="join_wait_s")
+
+    async def _rpc(self, call):
+        """Await one agentfs call: the pump is suspended on the agent."""
+        t = time.perf_counter()
+        try:
+            return await call
+        finally:
+            self.waits["rpc_wait_s"] += time.perf_counter() - t
+
+    async def _hop(self, fn, *args, wait: str = "put_wait_s") -> None:
+        """Run a blocking call of the writer's side on a pool thread:
+        the pump is suspended on the writer (a put that finds room
+        costs the hop all the same)."""
+        t = time.perf_counter()
+        try:
+            await asyncio.get_running_loop().run_in_executor(None, fn, *args)
+        finally:
+            self.waits[wait] += time.perf_counter() - t
 
     async def _put(self, item) -> None:
-        await asyncio.get_running_loop().run_in_executor(
-            None, self._wq.put, item)
+        await self._hop(self._wq.put, item)
 
     async def _put_many(self, items: list) -> None:
         """Hand the writer several items in order with one hop at most:
@@ -351,8 +408,7 @@ class RemoteTreeBackup:
             try:
                 self._wq.put_nowait(item)
             except queue.Full:
-                await asyncio.get_running_loop().run_in_executor(
-                    None, self._put_rest, items[i:])
+                await self._hop(self._put_rest, items[i:])
                 return
 
     def _put_rest(self, items: list) -> None:
@@ -368,7 +424,7 @@ class RemoteTreeBackup:
 
     async def _walk(self, rel: str) -> None:
         try:
-            entries = await self.fs.read_dir(rel)
+            entries = await self._rpc(self.fs.read_dir(rel))
         except ConnectionError:
             # transport death fails the JOB (the job-level retry may
             # re-run it); swallowing it as a per-dir error would grind
@@ -463,8 +519,8 @@ class RemoteTreeBackup:
         while len(run) > 1 and self._batching:
             pump["calls"] += 1
             pump["batch_calls"] += 1
-            answer = await self.fs.read_many([rel for rel, _ in run],
-                                             READ_BLOCK)
+            answer = await self._rpc(self.fs.read_many(
+                [rel for rel, _ in run], READ_BLOCK))
             if answer is None:
                 self._batching = False
                 break
@@ -528,7 +584,6 @@ class RemoteTreeBackup:
         READ_BLOCK is the end (the agent opens regular files only): a
         file of one block is one call and one item of the writer's
         queue, with no block queue, sentinel or close of its own."""
-        loop = asyncio.get_running_loop()
         pump = self.pump
         pump["files"] += 1
         handle, fq, reader, off = 0, None, None, 0
@@ -538,8 +593,8 @@ class RemoteTreeBackup:
                 pump["calls"] += 1
                 if reader is None:
                     try:
-                        handle, block, eof = await self.fs.open_read(
-                            rel, READ_BLOCK)
+                        handle, block, eof = await self._rpc(
+                            self.fs.open_read(rel, READ_BLOCK))
                     except (ConnectionError, FirstReadError):
                         raise
                     except Exception as e:
@@ -552,10 +607,11 @@ class RemoteTreeBackup:
                     reader = _QueuePumpReader(fq, self._abort, first=block)
                     await self._put(("file", entry, reader))
                 else:
-                    block = await self.fs.read_at(handle, off, READ_BLOCK)
+                    block = await self._rpc(
+                        self.fs.read_at(handle, off, READ_BLOCK))
                     eof = len(block) < READ_BLOCK
                     if block:
-                        await loop.run_in_executor(None, fq.put, block)
+                        await self._hop(fq.put, block)
                 off += len(block)
                 self.result.bytes_total += len(block)
                 if self.resume is not None:
@@ -571,19 +627,18 @@ class RemoteTreeBackup:
                 await self._put(
                     ("file", entry, self._failed_reader(rel, e)))
             elif fq is not None:
-                await loop.run_in_executor(
-                    None, fq.put, RuntimeError(f"read {rel}: {e}"))
+                await self._hop(fq.put, RuntimeError(f"read {rel}: {e}"))
             self.result.errors.append(f"{rel}: read: {e}")
             if isinstance(e, ConnectionError):
                 raise
             return
         finally:
             if fq is not None:
-                await loop.run_in_executor(None, fq.put, _SENTINEL)
+                await self._hop(fq.put, _SENTINEL)
             if handle:
                 pump["calls"] += 1
                 try:
-                    await self.fs.close(handle)
+                    await self._rpc(self.fs.close(handle))
                 except Exception as e:
                     self.log.debug("agentfs close failed for %s: %s", rel, e)
         self.result.files += 1
@@ -632,8 +687,12 @@ class RemoteTreeBackup:
 
     def _writer_loop(self) -> None:
         # fresh thread: attach the job's trace context so the writer's
-        # ingest-stage spans/emits parent under the job span
-        with trace.attached(getattr(self, "_tctx", None)):
+        # ingest-stage spans/emits parent under the job span, and the
+        # session's clock, which the stream writer's states reach from
+        # below (pxar/transfer.py)
+        trace.name_os_thread("backup-writer")   # its line in a profile
+        with trace.attached(getattr(self, "_tctx", None)), \
+                trace.clocked(self.writer_clock):
             self._writer_loop_body()
 
     def _writer_loop_body(self) -> None:

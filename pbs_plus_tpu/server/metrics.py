@@ -554,12 +554,15 @@ class MetricsRegistry:
         gauge("pbs_plus_feeder_thread_seconds_total",
               "The device batcher thread's life by state: inside a scan "
               "or a hash dispatch, idle with both queues empty, "
-              "lingering to widen a batch",
+              "lingering to widen a batch; and, beside them, the "
+              "thread's own CPU seconds (cpu): a dispatch's time less "
+              "its CPU is time blocked",
               [({"state": state}, float(fd[key]))
                for state, key in (("scan", "mask_busy_s"),
                                   ("sha", "sha_busy_s"),
                                   ("idle", "idle_s"),
-                                  ("linger", "linger_s")) if key in fd])
+                                  ("linger", "linger_s"),
+                                  ("cpu", "cpu_s")) if key in fd])
         gauge("pbs_plus_feeder_requests_total",
               "Requests the device batcher served, by kind (scan rows, "
               "hash batches)",
@@ -611,6 +614,31 @@ class MetricsRegistry:
               "read_many calls among them: each asks for a run of one "
               "listing's consecutive small files",
               [({}, float(pt["batch_calls"]))])
+        # the sessions' own clocks (server/backup_job.py CLOCK_TOTALS;
+        # docs/observability.md "The session's clocks"), summed over the
+        # jobs that ended
+        ct = dict(_backup_job.CLOCK_TOTALS)
+        gauge("pbs_plus_writer_thread_seconds_total",
+              "The backup writer threads' lives by state: waiting for "
+              "the pump, at the chunker (the stand at the device with "
+              "it), hashing, probing the index, sketching, storing, and "
+              "everything else; and, beside them, the threads' own CPU "
+              "seconds (cpu)",
+              [({"state": key[:-2]}, float(ct["writer_" + key]))
+               for key in _backup_job.WRITER_STATES + ("cpu_s",)])
+        gauge("pbs_plus_pump_wait_seconds_total",
+              "Seconds the backup pumps were suspended: on the agent "
+              "(agentfs calls), on the writer (queue puts, each an "
+              "executor hop) and on the writer's join at a job's end",
+              [({"on": on}, float(ct[key]))
+               for on, key in (("agent", "pump_rpc_wait_s"),
+                               ("writer", "pump_put_wait_s"),
+                               ("join", "pump_join_wait_s"))])
+        gauge("pbs_plus_loop_cpu_seconds_total",
+              "CPU seconds of the event loop's thread (pumps, aRPC, TLS "
+              "and whatever else shares the loop), as read at the last "
+              "backup pump's end",
+              [({}, float(ct["loop_cpu_s"]))])
         gauge("pbs_plus_device_compilations_total",
               "Programs jax built or loaded from its cache since the "
               "device ops were loaded; one that moves while backups run "

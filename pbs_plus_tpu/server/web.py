@@ -116,15 +116,20 @@ class RateLimiter:
 
 
 def traces_payload(n: "str | int | None" = None,
-                   trace_id: "str | None" = None) -> list:
+                   trace_id: "str | None" = None,
+                   jobs: "str | None" = None) -> list:
     """The traces endpoint's answer, split out so the span ring contract
-    is testable without standing up the TLS/web stack."""
+    is testable without standing up the TLS/web stack.  ``jobs``: the
+    table of job records (closed ``backup.pump`` spans with their
+    sessions' clocks), which outlives the ring's churn."""
     try:
         limit = min(int(n), 10_000) if n is not None else 256
     except (TypeError, ValueError):
         limit = 256
     if limit <= 0:
         return []
+    if jobs not in (None, "", "0"):
+        return trace.job_records(limit)
     return trace.recent(limit, trace_id=trace_id or None)
 
 
@@ -574,9 +579,11 @@ def build_app(server: "Server", *, require_auth: bool = True) -> web.Application
     async def traces(request):
         """The trace ring (docs/observability.md): closed spans, oldest
         first.  ``?trace=<id>`` filters to one trace, ``?n=`` bounds the
-        answer (default 256 — the ring itself is the hard cap)."""
+        answer (default 256 — the ring itself is the hard cap);
+        ``?jobs=1`` answers from the table of job records instead."""
         return web.json_response({"data": traces_payload(
-            request.query.get("n"), request.query.get("trace"))})
+            request.query.get("n"), request.query.get("trace"),
+            request.query.get("jobs"))})
 
     _profile_lock = asyncio.Lock()
 

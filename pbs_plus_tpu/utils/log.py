@@ -56,6 +56,11 @@ class Logger:
             dedup_window_s = conf.env().log_dedup_window_s
         self._window = dedup_window_s
 
+    @property
+    def scope(self) -> dict[str, Any]:
+        """The fields this logger attaches to every record."""
+        return dict(self._scope)
+
     def with_scope(self, **fields: Any) -> "Logger":
         s = dict(self._scope)
         s.update(fields)

@@ -43,6 +43,20 @@ measurement substrate:
   never imports jax: the annotation class is taken only when jax is
   already loaded.
 
+- **Thread clocks.**  ``ThreadClock`` partitions a thread's life by
+  state: ``spent(state)`` gives the time since the last call to
+  ``state``, so the states sum to the thread's wall time, and the
+  thread's own CPU seconds (``time.thread_time``) stand beside them —
+  life less CPU is time blocked.  A thread attaches its clock with
+  ``clocked(clock)``; code below it calls ``spent(state)`` or brackets a
+  block with ``state(state)``, no-ops on a thread without one.  The
+  device batcher's thread and every backup session's writer thread keep
+  one (docs/observability.md "The session's clocks").  Counters, like a
+  round trip's phase clocks: they run whether or not spans are enabled.
+- **Job records.**  Closed ``backup.pump`` spans — one a backup job,
+  carrying the session's clocks — are also kept in a table of their own
+  (``job_records()``), which the ring's churn does not reach.
+
 Tracing is ALWAYS ON.  The disabled path exists only for the bench's
 tracing-on/off comparison (``disabled()``); the per-span cost without a
 subscriber is gated < 5 µs (tests/test_bench_harness.py — the
@@ -154,6 +168,12 @@ def _ring_capacity() -> int:
 # closed spans, oldest evicted; deque append/snapshot are GIL-atomic so
 # the hot path takes no lock
 _ring: "deque[dict]" = deque(maxlen=_ring_capacity())
+# closed backup.pump spans, one a backup job with its session's clocks
+# (server/backup_job.py): a window of eight sessions closes thousands of
+# rpc.serve and device spans, and a reader of a job's record must not
+# depend on the ring's retention
+_JOB_SPAN = "backup.pump"
+_jobs: "deque[dict]" = deque(maxlen=256)
 # open spans (orphan detection): span_id -> (name, wall-clock start)
 _active: dict = {}
 # per-close subscribers (test/chaos hooks); empty in production, and the
@@ -196,6 +216,8 @@ def _feed_histogram(name: str, seconds: float, attrs: "dict | None") -> None:
 
 def _close_record(rec: dict) -> None:
     _ring.append(rec)
+    if rec["name"] == _JOB_SPAN:
+        _jobs.append(rec)
     _feed_histogram(rec["name"], rec["dur_s"], rec.get("attrs"))
     if _subs:
         for fn in list(_subs):
@@ -461,6 +483,158 @@ def round_trip(name: str, stats: dict, *, lock=None, shape: str = "",
     return _RoundTrip(name, stats, lock, shape, attrs)
 
 
+# -- thread clocks -----------------------------------------------------------
+# Where a thread's own time goes (docs/observability.md "The session's
+# clocks"): the device batcher's thread (models/feeder.py) and a backup
+# session's writer thread (server/backup_job.py) each keep one.
+
+REST = "other_s"        # what a clocked thread does between bracketed states
+_thread = threading.local()
+
+
+class ThreadClock:
+    """One thread's life, partitioned by state.  ``spent(state)`` gives
+    the time since the last call (or the start) to ``state``, so the
+    states of ``seconds`` sum to the thread's wall time; ``stop`` closes
+    the books with ``life_s`` and ``cpu_s``, the thread's own CPU seconds
+    (``time.thread_time``): life less CPU is time blocked — on a queue,
+    the device, a file, the interpreter lock.  ``seconds`` may be the
+    owner's counters dict (``DeviceFeeder.stats``); ``label`` names the
+    profiler annotations of ``state()`` blocks, ``<label>.<state>``.
+    Every call but the constructor is made on the clocked thread."""
+
+    __slots__ = ("seconds", "label", "_t0", "_t", "_cpu")
+
+    def __init__(self, seconds: "dict | None" = None, label: str = ""):
+        self.seconds = {} if seconds is None else seconds
+        self.label = label
+        self._t0 = self._t = self._cpu = 0
+
+    def start(self) -> None:
+        self._t0 = self._t = time.perf_counter_ns()
+        self._cpu = time.thread_time()
+
+    def spent(self, state: str, now_ns: "int | None" = None) -> None:
+        """The thread's time since the last call goes to ``state``;
+        ``now_ns`` is a ``perf_counter_ns`` reading the caller already
+        has."""
+        if now_ns is None:
+            now_ns = time.perf_counter_ns()
+        seconds = self.seconds
+        seconds[state] = seconds.get(state, 0.0) + (now_ns - self._t) * 1e-9
+        self._t = now_ns
+
+    def cpu(self) -> None:
+        """Bring ``cpu_s`` up to now (a thread that never stops: the
+        batcher's, once a round)."""
+        now = time.thread_time()
+        seconds = self.seconds
+        seconds["cpu_s"] = seconds.get("cpu_s", 0.0) + now - self._cpu
+        self._cpu = now
+
+    def stop(self) -> None:
+        self.spent(REST)
+        self.cpu()
+        seconds = self.seconds
+        seconds["life_s"] = seconds.get("life_s", 0.0) \
+            + (self._t - self._t0) * 1e-9
+
+
+class clocked:
+    """Attach ``clock`` to the calling thread for the block and run it:
+    ``spent()`` and ``state()`` below reach it with no argument."""
+
+    __slots__ = ("_clock",)
+
+    def __init__(self, clock: ThreadClock):
+        self._clock = clock
+
+    def __enter__(self) -> ThreadClock:
+        _thread.clock = self._clock
+        self._clock.start()
+        return self._clock
+
+    def __exit__(self, *exc) -> bool:
+        _thread.clock = None
+        self._clock.stop()
+        return False
+
+
+def spent(state: str, now_ns: "int | None" = None) -> None:
+    """The calling thread's time since its clock's last reading goes to
+    ``state``; nothing on a thread with no clock (pipelined hash
+    workers, local and S3 backups, tests)."""
+    clock = getattr(_thread, "clock", None)
+    if clock is not None:
+        clock.spent(state, now_ns)
+
+
+class _State:
+    """One stay of a clocked thread in a state: what came before goes to
+    the residue (``REST``), the block to ``state``, and the block is a
+    profiler annotation ``<label>.<state>``.  A context manager;
+    ``begin``/``end`` return their ``perf_counter_ns`` readings for a
+    caller that keeps an accumulator of its own on the same two reads
+    (``_ChunkedStream.write``)."""
+
+    __slots__ = ("_clock", "_state", "_ann")
+
+    def __init__(self, clock: ThreadClock, state: str):
+        self._clock = clock
+        self._state = state
+        self._ann = annotation(f"{clock.label}.{state[:-2]}")
+
+    def begin(self) -> int:
+        now = time.perf_counter_ns()
+        self._clock.spent(REST, now)
+        self._ann.__enter__()
+        return now
+
+    def end(self) -> int:
+        self._ann.__exit__(None, None, None)
+        now = time.perf_counter_ns()
+        self._clock.spent(self._state, now)
+        return now
+
+    def __enter__(self) -> "_State":
+        self.begin()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.end()
+        return False
+
+
+class _NoState:
+    """``state()`` on a thread with no clock: the readings alone."""
+
+    __slots__ = ()
+    begin = end = staticmethod(time.perf_counter_ns)
+
+    def __enter__(self) -> "_NoState":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_NO_STATE = _NoState()
+
+
+def state(state: str) -> "_State | _NoState":
+    """Bracket a block of the calling thread as ``state`` (a key that
+    ends in ``_s``) of its clock; a no-op on a thread with none."""
+    clock = getattr(_thread, "clock", None)
+    return _NO_STATE if clock is None else _State(clock, state)
+
+
+def job_records(n: "int | None" = None) -> list:
+    """Closed ``backup.pump`` spans, oldest first: one record a backup
+    job, 256 deep, kept apart from the ring."""
+    out = list(_jobs)
+    return out[-n:] if n is not None and n > 0 else out
+
+
 # -- propagation -------------------------------------------------------------
 
 def capture() -> "tuple[str, str] | None":
@@ -553,6 +727,7 @@ def active_spans() -> list:
 def clear() -> None:
     """Drop ring + orphan state (test isolation only)."""
     _ring.clear()
+    _jobs.clear()
     _active.clear()
 
 

@@ -590,17 +590,28 @@ def test_bench_sync():
 
 def test_bench_observability():
     """Tracing overhead benchmark (bench._observability_bench →
-    detail.observability in the bench JSON) with the ISSUE 12 gates:
+    detail.observability in the bench JSON) with the ISSUE 12 gates, made
+    steady beside loaded neighbours (ROADMAP D0; ISSUE 34): unit costs
+    are the least of several batches on the thread's own CPU clock —
     span open/close < 5 µs disarmed (no subscriber), histogram record
-    well under the span cost, and tracing-on pipelined ingest ≥ 0.97x
-    tracing-off — always-on tracing must be invisible next to real
-    work."""
+    well under the span cost, a clocked state bracket (the session's
+    clocks) under the span's bound too — and what an ingest pays is
+    counted, not timed: spans and clock reads per chunk and per write,
+    and their cost by those unit costs as a share of the ingest's own CPU
+    seconds < 3 % — always-on tracing must be invisible next to real
+    work.  The wall-clock on/off ratio is printed and gates nothing."""
     import bench
 
     res = bench._observability_bench(mib=48 if FULL else 16)
+    pipe, writer = res["pipelined"], res["session_writer"]
     print(f"\n  observability: span {res['span_overhead_ns']:7.0f} ns"
           f" | span+hist {res['span_hist_overhead_ns']:7.0f} ns"
           f" | record {res['hist_record_ns']:6.0f} ns"
+          f" | state {res['state_overhead_ns']:6.0f} ns"
+          f" | per chunk: spans {pipe['spans_per_chunk']}"
+          f"/{writer['spans_per_chunk']}, clock reads "
+          f"{pipe['clock_reads_per_chunk']}/{writer['clock_reads_per_chunk']}"
+          f" | traced share {res['traced_share']:.5f}"
           f" | ingest on/off {res['on_vs_off']:.4f}"
           f" ({res['ingest_on_mib_s']}/{res['ingest_off_mib_s']} MiB/s)")
     # the disarmed-span bound (the failpoints <5µs discipline)
@@ -608,5 +619,17 @@ def test_bench_observability():
     # a histogram-feeding close stays the same order of magnitude
     assert res["span_hist_overhead_ns"] < 10000, res
     assert res["hist_record_ns"] < 5000, res
-    # always-on tracing costs < 3% of pipelined ingest throughput
-    assert res["on_vs_off"] >= 0.97, res
+    # a state of a session's clock: two readings, two additions and an
+    # annotation; without a clock next to nothing
+    assert res["state_overhead_ns"] < 5000, res
+    assert res["state_unclocked_ns"] < res["state_overhead_ns"], res
+    # counts: no span per chunk on either path (stage spans are per hash
+    # batch or aggregated per stream), a handful of clock reads per
+    # chunk in the pipeline's stages, and the two readings a write
+    # always paid in the session writer's — the clock added none
+    assert pipe["spans_per_chunk"] <= 0.5, res
+    assert writer["spans_per_chunk"] <= 0.5, res
+    assert pipe["clock_reads_per_chunk"] <= 8, res
+    assert writer["clock_reads_per_write"] <= 2.5, res
+    # always-on tracing costs < 3% of the ingest's own CPU
+    assert res["traced_share"] < 0.03, res

@@ -8,6 +8,7 @@ every call; the real agent's side is in tests/test_agentfs_battery.py."""
 
 import asyncio
 import threading
+import time
 
 import pytest
 
@@ -338,7 +339,8 @@ def test_pump_counters_on_the_span_and_in_the_totals():
     assert pump.pump == want
     assert {k: bj.PUMP_TOTALS[k] - before[k] for k in want} == want
     spans = [r for r in trace.recent() if r["name"] == "backup.pump"]
-    assert len(spans) == 1 and spans[0]["attrs"] == want
+    assert len(spans) == 1
+    assert {k: spans[0]["attrs"][k] for k in want} == want
 
 
 def test_pump_totals_on_metrics(tmp_path):
@@ -643,7 +645,8 @@ def test_batch_counters_on_the_span_and_in_the_totals():
     assert pump.pump == want
     assert {k: bj.PUMP_TOTALS[k] - before[k] for k in want} == want
     spans = [r for r in trace.recent() if r["name"] == "backup.pump"]
-    assert len(spans) == 1 and spans[0]["attrs"] == want
+    assert len(spans) == 1
+    assert {k: spans[0]["attrs"][k] for k in want} == want
 
 
 def test_batch_totals_on_metrics(tmp_path):
@@ -663,3 +666,212 @@ def test_batch_totals_on_metrics(tmp_path):
     assert (f'pbs_plus_pump_batch_calls_total '
             f'{float(t["batch_calls"])}') in expo
     assert t["batched_files"] >= 2 and t["batch_calls"] >= 1
+
+
+# ------------------------------------------------ the session's clocks
+
+RECORD_KEYS = ({"job"} | set(bj.PUMP_TOTALS)
+               | {"writer_" + k for k in bj.WRITER_STATES}
+               | {"writer_cpu_s", "writer_life_s"}
+               | {"pump_" + k for k in bj.PUMP_WAITS}
+               | {"pump_life_s", "loop_cpu0", "loop_cpu1"})
+
+
+class _StreamSession:
+    """A session whose writer is the real one: a ``SessionWriter`` over
+    a chunk store in ``base``, hash batches of four chunks through a
+    batch hasher, as a ``chunker="tpu"`` session's payload stream goes
+    (``_flush_hashes``: hash, probe, store)."""
+
+    def __init__(self, base, monkeypatch, insert_delay=0.0):
+        import hashlib
+
+        from pbs_plus_tpu.chunker import ChunkerParams
+        from pbs_plus_tpu.pxar import transfer
+        from pbs_plus_tpu.pxar.datastore import ChunkStore
+        monkeypatch.setattr(transfer, "_HASH_BATCH_COUNT", 4)
+        store = ChunkStore(str(base), n_shards=2, index_budget_mb=2)
+        self.inserts = 0
+        insert = store.insert
+
+        def slow_insert(digest, data, **kw):
+            self.inserts += 1
+            time.sleep(insert_delay)
+            return insert(digest, data, **kw)
+        store.insert = slow_insert
+        self.writer = transfer.SessionWriter(
+            store, payload_params=ChunkerParams(avg_size=1 << 10),
+            batch_hasher=lambda chunks: [hashlib.sha256(c).digest()
+                                         for c in chunks])
+
+
+def _pump_record(pump):
+    recs = [r for r in trace.job_records()
+            if r["attrs"]["job"] == pump.log.scope["job_id"]]
+    assert len(recs) == 1
+    return recs[0]
+
+
+def _clocked_run(fs, sess, job_id):
+    from pbs_plus_tpu.utils.log import L
+
+    async def main():
+        pump = RemoteTreeBackup(fs, sess,
+                                job_log=L.with_scope(job_id=job_id))
+        await asyncio.wait_for(pump.run(), 60)
+        return pump
+    pump = asyncio.run(main())
+    attrs = _pump_record(pump)["attrs"]
+    writer = {k: attrs["writer_" + k] for k in bj.WRITER_STATES}
+    waits = {k: attrs["pump_" + k] for k in bj.PUMP_WAITS}
+    # the writer's states partition its life, and its CPU is inside it
+    assert sum(writer.values()) == pytest.approx(attrs["writer_life_s"],
+                                                 rel=0.01)
+    assert 0 < attrs["writer_cpu_s"] <= attrs["writer_life_s"]
+    assert sum(waits.values()) <= attrs["pump_life_s"]
+    return pump, attrs, writer, waits
+
+
+def _others(states: dict, *driven) -> dict:
+    return {k: v for k, v in states.items() if k not in driven}
+
+
+def test_a_slow_agent_is_the_writers_pump_wait_and_the_pumps_rpc_wait(
+        tmp_path, monkeypatch):
+    """Every call to the agent takes 50 ms: the pump is suspended on it
+    for that long and the writer waits for the pump as long, less the
+    little it has to do with one file while the pump asks for the next;
+    no other state of either moves by a tenth of it."""
+    fs = CountingFS({f"f{i:02d}": 600 for i in range(20)})
+    delay, calls = 0.05, []
+    read_many, open_read = fs.read_many, fs.open_read
+
+    async def slow(call, *a):
+        calls.append(call.__name__)
+        await asyncio.sleep(delay)
+        return await call(*a)
+    fs.read_many = lambda *a: slow(read_many, *a)
+    fs.open_read = lambda *a: slow(open_read, *a)
+    pump, attrs, writer, waits = _clocked_run(
+        fs, _StreamSession(tmp_path, monkeypatch), "row-slow-agent")
+    injected = delay * len(calls)
+    assert len(calls) >= 10
+    assert waits["rpc_wait_s"] >= injected
+    assert writer["pump_wait_s"] >= injected - sum(
+        _others(writer, "pump_wait_s").values())
+    assert max(_others(writer, "pump_wait_s").values()) <= injected / 10
+    assert max(_others(waits, "rpc_wait_s").values()) <= injected / 10
+
+
+def test_a_slow_store_is_the_writers_store_and_the_pumps_put_wait(
+        tmp_path, monkeypatch):
+    """Every insert into the chunk store takes 20 ms: the writer's
+    thread is in its store state for that long, and the pump, one item
+    ahead of it, is suspended on the writer's queue (at the end, on its
+    join) as long; no other state of either moves by a tenth of it."""
+    monkeypatch.setattr(bj, "QUEUE_DEPTH", 1)
+    sess = _StreamSession(tmp_path, monkeypatch, insert_delay=0.02)
+    fs = CountingFS({f"f{i:02d}": 600 + i for i in range(60)})
+    pump, attrs, writer, waits = _clocked_run(fs, sess, "row-slow-store")
+    injected = 0.02 * sess.inserts
+    assert sess.inserts >= 20
+    assert writer["store_s"] >= injected
+    assert waits["put_wait_s"] + waits["join_wait_s"] >= injected
+    assert waits["put_wait_s"] >= 0.8 * injected
+    assert max(_others(writer, "store_s").values()) <= injected / 10
+    assert waits["rpc_wait_s"] <= injected / 10
+
+
+def test_the_pumps_record_carries_every_clock_and_the_jobs_row_id(
+        tmp_path, monkeypatch):
+    trace.clear()
+    pump, attrs, writer, waits = _clocked_run(
+        CountingFS({"a": 10, "b": 3 * BLOCK}),
+        _StreamSession(tmp_path, monkeypatch), "row-7")
+    assert set(attrs) == RECORD_KEYS and attrs["job"] == "row-7"
+    rec = _pump_record(pump)
+    assert attrs["pump_life_s"] == pytest.approx(rec["dur_s"], abs=0.005)
+    assert attrs["loop_cpu1"] >= attrs["loop_cpu0"] > 0
+    # the record is the ring's backup.pump span, kept a second time
+    assert rec in trace.recent()
+    # a pump with no job's logger still closes a record
+    _run(CountingFS({"a": 10}))
+    assert trace.job_records()[-1]["attrs"]["job"] == ""
+
+
+def test_job_records_outlive_the_rings_churn():
+    """A window's rpc.serve closes evict a job's span from the ring; the
+    table of job records keeps it, and keeps 256 of them."""
+    trace.clear()
+    _run(CountingFS({"a": 10}))
+    for _ in range(10_000):
+        with trace.span("rpc.serve"):
+            pass
+    assert not [r for r in trace.recent() if r["name"] == "backup.pump"]
+    kept = trace.job_records()
+    assert len(kept) == 1 and kept[0]["attrs"]["files"] == 1
+    for i in range(300):
+        trace.emit("backup.pump", 0.001, job=f"j{i}")
+    kept = trace.job_records()
+    assert len(kept) == 256 and kept[-1]["attrs"]["job"] == "j299"
+    assert trace.job_records(2) == kept[-2:]
+    trace.clear()
+    assert trace.job_records() == []
+
+
+def test_the_log_the_traces_endpoint_and_metrics_show_the_same_clocks(
+        tmp_path, monkeypatch, caplog):
+    """One backup, three surfaces: the line at the job's end in its log,
+    the record on ``GET /api2/json/d2d/traces`` and the process totals
+    on ``/metrics``."""
+    import logging
+
+    from pbs_plus_tpu.server import metrics
+    from pbs_plus_tpu.server.store import Server, ServerConfig
+    from pbs_plus_tpu.server.web import traces_payload
+    server = Server(ServerConfig(state_dir=str(tmp_path / "state"),
+                                 cert_dir=str(tmp_path / "certs"),
+                                 datastore_dir=str(tmp_path / "ds")))
+    registry = metrics.MetricsRegistry(server)
+
+    def series(expo: str) -> dict:
+        out = {}
+        for line in expo.splitlines():
+            if line.startswith(("pbs_plus_writer_thread_seconds_total{",
+                                "pbs_plus_pump_wait_seconds_total{",
+                                "pbs_plus_loop_cpu_seconds_total")):
+                name, value = line.rsplit(" ", 1)
+                out[name] = float(value)
+        return out
+    before = series(registry.render())
+    with caplog.at_level(logging.INFO, logger="pbs_plus_tpu"):
+        pump, attrs, writer, waits = _clocked_run(
+            CountingFS({f"f{i}": 700 for i in range(12)}),
+            _StreamSession(tmp_path / "chunks", monkeypatch), "row-3")
+    # the job's log
+    lines = [r for r in caplog.records
+             if r.getMessage().startswith("session clocks: ")]
+    assert len(lines) == 1 and lines[0].scope["job_id"] == "row-3"
+    logged = {k: float(v) for k, v in (
+        kv.split("=") for kv in lines[0].getMessage().split(": ")[1].split())}
+    clocks = {k: v for k, v in attrs.items()
+              if k.startswith(("writer_", "pump_", "loop_"))}
+    assert set(logged) == set(clocks)
+    assert logged == pytest.approx(clocks, abs=1e-6)
+    # the endpoint: the ring's span, and the table's record
+    assert [r for r in traces_payload(trace_id=None)
+            if r["name"] == "backup.pump" and r["attrs"] == attrs]
+    assert traces_payload(1, jobs="1")[0]["attrs"] == attrs
+    # /metrics: the process totals moved by this job's clocks
+    after = series(registry.render())
+    moved = {name: after[name] - before.get(name, 0.0) for name in after}
+    for state in bj.WRITER_STATES + ("cpu_s",):
+        name = ('pbs_plus_writer_thread_seconds_total{state="%s"}'
+                % state[:-2])
+        assert moved[name] == pytest.approx(attrs["writer_" + state],
+                                            abs=1e-6)
+    for on, key in (("agent", "rpc_wait_s"), ("writer", "put_wait_s"),
+                    ("join", "join_wait_s")):
+        name = 'pbs_plus_pump_wait_seconds_total{on="%s"}' % on
+        assert moved[name] == pytest.approx(attrs["pump_" + key], abs=1e-6)
+    assert after["pbs_plus_loop_cpu_seconds_total"] == attrs["loop_cpu1"]

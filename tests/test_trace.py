@@ -520,3 +520,102 @@ def test_span_set_adds_attrs_known_late_and_is_a_noop_when_disabled():
     assert _by_name("ingest.store")[0]["attrs"] == {"chunks": 2, "new": 1}
     assert _by_name("job")[0]["attrs"] == {"kind": "backup"}
     assert len(trace.recent()) == 2
+
+
+# ------------------------------------------------------ thread clocks
+
+def _life_of(clock, body):
+    t = threading.Thread(target=lambda: _clocked(clock, body))
+    t.start()
+    t.join(30)
+    assert not t.is_alive()
+    return clock.seconds
+
+
+def _clocked(clock, body):
+    with trace.clocked(clock):
+        body()
+
+
+def test_thread_clock_partitions_a_threads_life():
+    """The states a clocked thread passes through sum to its life, its
+    CPU seconds stand beside them, and what no bracket names is the
+    residue."""
+    def body():
+        time.sleep(0.02)                        # the residue
+        with trace.state("pump_wait_s"):
+            time.sleep(0.05)
+        spin = time.perf_counter() + 0.03
+        while time.perf_counter() < spin:       # CPU, in the residue
+            pass
+        st = trace.state("cdc_s")
+        t0 = st.begin()
+        time.sleep(0.04)
+        took.append((st.end() - t0) * 1e-9)
+        trace.spent("store_s")                  # since the last reading
+
+    took: list = []
+    clock = trace.ThreadClock(label="writer")
+    s = _life_of(clock, body)
+    # the caller's own two readings are the clock's
+    assert took == [pytest.approx(s["cdc_s"], abs=1e-4)]
+    states = {k: v for k, v in s.items() if k not in ("cpu_s", "life_s")}
+    assert set(states) == {"pump_wait_s", "cdc_s", "store_s", trace.REST}
+    assert sum(states.values()) == pytest.approx(s["life_s"], rel=0.01)
+    assert s["pump_wait_s"] >= 0.05 and s["cdc_s"] >= 0.04
+    assert s[trace.REST] >= 0.05
+    assert 0.02 <= s["cpu_s"] <= s["life_s"]
+    assert s["life_s"] >= 0.14
+
+
+def test_thread_clock_keeps_its_owners_counters():
+    """The batcher's way: the clock writes into the owner's dict, the
+    keys it is given and no ``life_s`` until it stops; ``cpu`` brings
+    the CPU seconds up to date in between."""
+    stats = {"idle_s": 0.0, "mask_busy_s": 0.0, "rounds": 7}
+    clock = trace.ThreadClock(stats)
+
+    def body():
+        clock.start()
+        time.sleep(0.02)
+        clock.spent("idle_s")
+        spin = time.perf_counter() + 0.02
+        while time.perf_counter() < spin:
+            pass
+        clock.spent("mask_busy_s")
+        clock.cpu()
+    t = threading.Thread(target=body)
+    t.start()
+    t.join(30)
+    assert stats["rounds"] == 7 and "life_s" not in stats
+    assert stats["idle_s"] >= 0.02 and stats["mask_busy_s"] >= 0.02
+    assert 0.01 <= stats["cpu_s"] <= stats["idle_s"] + stats["mask_busy_s"]
+
+
+def test_spent_and_state_without_a_clock_are_noops():
+    """A thread with no clock (pipelined hash workers, local and S3
+    backups, this test): nothing is kept, and ``state`` still gives its
+    caller the two readings."""
+    trace.spent("cdc_s")
+    with trace.state("store_s") as st:
+        pass
+    assert st is trace.state("probe_s")         # the one no-op
+    t0 = st.begin()
+    assert st.end() >= t0 > 0
+    clock = trace.ThreadClock()
+    s = _life_of(clock, lambda: None)
+    # ... and a clock reaches no thread but the one it is attached on
+    trace.spent("cdc_s")
+    assert set(s) == {trace.REST, "cpu_s", "life_s"}
+
+
+def test_a_clock_is_detached_when_its_block_ends():
+    clock = trace.ThreadClock()
+    with trace.clocked(clock):
+        with trace.state("sha_s"):
+            pass
+    before = dict(clock.seconds)
+    with trace.state("sha_s"):
+        time.sleep(0.001)
+    trace.spent("sha_s")
+    assert clock.seconds == before
