@@ -429,24 +429,26 @@ class AgentFSClient:
     async def open(self, path: str) -> int:
         return (await self.s.call("agentfs.open", {"path": path})).data["handle"]
 
-    async def open_read(self, path: str, n: int) -> tuple[int, bytes, bool]:
+    async def open_read(self, path: str,
+                        n: int) -> tuple[int, bytearray, bool]:
         """Open ``path`` and read its first ``n`` bytes in one call:
         ``(handle, data, eof)``.  At ``eof`` the agent has closed the
         file and ``handle`` is 0.  An agent that predates the ``read``
         key ignores it and answers 200 with a bare handle: that comes
         back as ``(handle, b"", False)`` and the caller goes on with
-        ``read_at`` — what the peer answered decides, nothing else."""
-        buf = bytearray()
+        ``read_at`` — what the peer answered decides, nothing else.
+        ``data`` is the buffer the bytes were received into, as is
+        ``read_at``'s; ``read_many``'s files are views of one."""
         try:
-            resp, _ = await self.s.call_binary_into(
-                "agentfs.open", {"path": path, "read": n}, buf)
+            resp, buf = await self.s.call_binary(
+                "agentfs.open", {"path": path, "read": n}, n)
         except CallError as e:
             if e.response.status == STATUS_ERROR:
                 raise FirstReadError(e.response.message) from e
             raise
         if resp.status != STATUS_RAW_STREAM:
             return resp.data["handle"], b"", False
-        return resp.data["handle"], bytes(buf), bool(resp.data["eof"])
+        return resp.data["handle"], buf, bool(resp.data["eof"])
 
     async def read_many(self, paths: list[str],
                         budget: int) -> "list | None":
@@ -460,11 +462,10 @@ class AgentFSClient:
         (404 from the router; a file's own 404 is in its item, never in
         the call's status) and the caller reads file by file — what the
         peer answered decides, nothing else."""
-        buf = bytearray()
         try:
-            resp, _ = await self.s.call_binary_into(
+            resp, buf = await self.s.call_binary(
                 "agentfs.read_many", {"paths": paths, "budget": budget},
-                buf)
+                budget)
         except CallError as e:
             if e.response.status == STATUS_NOT_FOUND:
                 return None
@@ -473,7 +474,7 @@ class AgentFSClient:
         view, off = memoryview(buf), 0
         for rec in resp.data["files"]:
             if "n" in rec:
-                out.append(bytes(view[off:off + rec["n"]]))
+                out.append(view[off:off + rec["n"]])
                 off += rec["n"]
             elif rec["status"] == STATUS_ERROR:
                 out.append(FirstReadError(rec["message"]))
@@ -482,11 +483,10 @@ class AgentFSClient:
                                               rec["message"])))
         return out + [None] * (len(paths) - len(out))
 
-    async def read_at(self, handle: int, off: int, n: int) -> bytes:
-        buf = bytearray()
-        await self.s.call_binary_into(
-            "agentfs.read_at", {"handle": handle, "off": off, "n": n}, buf)
-        return bytes(buf)
+    async def read_at(self, handle: int, off: int, n: int) -> bytearray:
+        return (await self.s.call_binary(
+            "agentfs.read_at", {"handle": handle, "off": off, "n": n},
+            n))[1]
 
     async def close(self, handle: int) -> None:
         await self.s.call("agentfs.close", {"handle": handle})

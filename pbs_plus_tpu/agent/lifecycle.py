@@ -210,7 +210,8 @@ class AgentLifecycle:
                 await asyncio.get_running_loop().run_in_executor(
                     None, self.snapshots.cleanup, job.snapshot)
             self.jobs.pop(job.job_id, None)
-            self.log.info("backup job session closed (agentfs: %s)", fs.stats)
+            self.log.info("backup job session closed (agentfs: %s; mux: %s)",
+                          fs.stats, job.conn.stats)
 
     @staticmethod
     def _remove_handoff(proc) -> None:

@@ -26,8 +26,8 @@ from .router import Router, HandlerError
 from .transport import connect_to_server, serve, TlsServerConfig, TlsClientConfig
 from .agents_manager import (AdmissionDeadlineError, AdmissionRejected,
                              AgentsManager, ClientSession)
-from .binary_stream import (send_data_from_reader, receive_data_into,
-                            MAX_FRAME, StreamLengthError)
+from .binary_stream import (send_data_from_reader, receive_data,
+                            receive_data_into, MAX_FRAME, StreamLengthError)
 
 __all__ = [
     "MuxConnection", "MuxStream", "MuxError",
@@ -37,6 +37,6 @@ __all__ = [
     "connect_to_server", "serve", "TlsServerConfig", "TlsClientConfig",
     "AdmissionDeadlineError", "AdmissionRejected", "AgentsManager",
     "ClientSession",
-    "send_data_from_reader", "receive_data_into", "MAX_FRAME",
+    "send_data_from_reader", "receive_data", "receive_data_into", "MAX_FRAME",
     "StreamLengthError",
 ]

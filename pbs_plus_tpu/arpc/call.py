@@ -119,9 +119,35 @@ class Session:
         Returns (response, bytes_received).  (Reference: CallBinaryWithMeta
         reading into caller buffers, internal/arpc/call.go:176-199.)"""
         from .binary_stream import receive_data_into
+        resp, n = await self._call_raw(
+            method, payload, lambda st: receive_data_into(st, writer),
+            timeout, headers)
+        return resp, n or 0
+
+    async def call_binary(self, method: str, payload: Any, max_len: int,
+                          *, timeout: float | None = 300.0,
+                          headers: dict[str, str] | None = None,
+                          ) -> tuple[Response, bytearray]:
+        """Raw-stream download into a buffer of its own, sized once from
+        the transfer's header and at most ``max_len`` bytes (what the
+        call asks the peer for): the bulk bytes' way.  Returns
+        (response, buffer); the buffer is empty where the server
+        answered without a stream."""
+        from .binary_stream import receive_data
+        resp, buf = await self._call_raw(
+            method, payload, lambda st: receive_data(st, max_len),
+            timeout, headers)
+        return resp, bytearray() if buf is None else buf
+
+    async def _call_raw(self, method: str, payload: Any, receive,
+                        timeout: float | None,
+                        headers: dict[str, str] | None):
+        """One raw-stream call: ``(response, receive(stream))``, or
+        ``(response, None)`` where the server answered 2xx with no
+        stream."""
         hdrs = trace.headers_out(headers)
 
-        async def _do() -> tuple[Response, int]:
+        async def _do():
             st = await self.conn.open_stream()
             try:
                 await st.write(Request(method, payload, hdrs).encode())
@@ -129,13 +155,12 @@ class Session:
                 if resp.status != STATUS_RAW_STREAM:
                     if not resp.ok:
                         raise CallError(resp)
-                    return resp, 0
+                    return resp, None
                 ready = await st.readexactly(1)
                 if ready != _READY:
                     raise MuxError("bad raw-stream ready byte")
                 await st.write(_ACK)
-                n = await receive_data_into(st, writer)
-                return resp, n
+                return resp, await receive(st)
             finally:
                 await st.close()
         return await asyncio.wait_for(_do(), timeout)

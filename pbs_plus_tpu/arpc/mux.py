@@ -16,6 +16,7 @@ from __future__ import annotations
 import asyncio
 import struct
 import time
+from collections import deque
 from typing import Optional
 
 from ..utils import conf, failpoints, trace
@@ -45,7 +46,13 @@ class MuxStream:
     def __init__(self, conn: "MuxConnection", sid: int):
         self.conn = conn
         self.sid = sid
-        self._rx = bytearray()
+        # the DATA frames' own payloads, as the read loop handed them
+        # over, and how much of the first has been read: a read that
+        # takes a whole frame takes it by reference, ``readinto`` copies
+        # each straight into the caller's buffer
+        self._rx: deque[bytes] = deque()
+        self._rx_head = 0
+        self._rx_len = 0
         self._rx_event = asyncio.Event()
         self._rx_eof = False
         self._rx_reset = False
@@ -60,23 +67,58 @@ class MuxStream:
         self._rx_unacked = 0
 
     # -- read -------------------------------------------------------------
-    async def read(self, n: int = -1) -> bytes:
-        """Read up to n bytes (all buffered if n<0); b"" at EOF."""
-        while not self._rx and not self._rx_eof and not self._rx_reset:
+    async def _rx_wait(self) -> bool:
+        """Wait for buffered bytes; False at EOF."""
+        while not self._rx_len and not self._rx_eof and not self._rx_reset:
             self._rx_event.clear()
             await self._rx_event.wait()
         if self._rx_reset:
             raise MuxError(f"stream {self.sid} reset by peer")
-        if not self._rx:
+        return bool(self._rx_len)
+
+    def _rx_pieces(self, n: int):
+        """Consume the next ``n`` buffered bytes frame by frame: a whole
+        frame as it arrived, part of one as a view of it."""
+        rx = self._rx
+        self._rx_len -= n
+        while n:
+            frame, head = rx[0], self._rx_head
+            end = min(len(frame), head + n)
+            n -= end - head
+            if end == len(frame):
+                rx.popleft()
+                self._rx_head = 0
+            else:
+                self._rx_head = end
+            yield frame if (head, end) == (0, len(frame)) \
+                else memoryview(frame)[head:end]
+
+    async def read(self, n: int = -1) -> bytes:
+        """Read up to n bytes (all buffered if n<0); b"" at EOF."""
+        if not await self._rx_wait():
             return b""
-        if n < 0 or n >= len(self._rx):
-            out = bytes(self._rx)
-            self._rx.clear()
-        else:
-            out = bytes(self._rx[:n])
-            del self._rx[:n]
-        await self._grant(len(out))
+        if n < 0 or n > self._rx_len:
+            n = self._rx_len
+        pieces = list(self._rx_pieces(n))
+        out = pieces[0] if len(pieces) == 1 and type(pieces[0]) is bytes \
+            else b"".join(pieces)
+        await self._grant(n)
         return out
+
+    async def readinto(self, buf) -> int:
+        """Read up to ``len(buf)`` bytes straight into the writable
+        buffer ``buf``, one copy from the frames as they arrived; 0 at
+        EOF.  Counts and grants what ``read(len(buf))`` would."""
+        if not await self._rx_wait():
+            return 0
+        n = min(len(buf), self._rx_len)
+        at = 0
+        for piece in self._rx_pieces(n):
+            buf[at:at + len(piece)] = piece
+            at += len(piece)
+        self.conn.stats["rx_direct_bytes"] += n
+        await self._grant(n)
+        return n
 
     async def readexactly(self, n: int) -> bytes:
         out = bytearray()
@@ -110,9 +152,13 @@ class MuxStream:
         if self.conn.closed:
             raise MuxError("connection closed")
 
-    async def write(self, data: bytes) -> None:
+    async def write(self, data) -> None:
         self._check_writable()
         view = memoryview(data)
+        # a frame goes down as a view of ``data``, which the transport
+        # may keep until the socket takes it: bytes the caller could
+        # still change are copied frame by frame
+        copied = not view.readonly
         while view:
             # re-checked every chunk, not only when blocked on credit: a
             # mid-stream peer RST with window remaining must fail the
@@ -125,7 +171,8 @@ class MuxStream:
                 self._check_writable()
             n = min(len(view), MAX_DATA_FRAME, self._tx_credit)
             self._tx_credit -= n
-            await self.conn._send_frame(DATA, self.sid, bytes(view[:n]))
+            await self.conn._send_frame(
+                DATA, self.sid, bytes(view[:n]) if copied else view[:n])
             view = view[n:]
 
     # -- lifecycle --------------------------------------------------------
@@ -169,7 +216,9 @@ class MuxStream:
 
     # -- conn callbacks ---------------------------------------------------
     def _on_data(self, payload: bytes) -> None:
-        self._rx += payload
+        if payload:
+            self._rx.append(payload)
+            self._rx_len += len(payload)
         self._rx_unacked += len(payload)
         self._rx_event.set()
 
@@ -224,12 +273,25 @@ class MuxConnection:
         self._write_deadline_s = (conf.env().mux_write_deadline_s
                                   if write_deadline_s is None
                                   else write_deadline_s)
+        # the transport's high-water mark: a write that leaves its
+        # buffer at or above it may pause the writer's protocol (TLS
+        # pauses at the mark, a plain socket above it), and only a
+        # paused one makes ``drain`` wait.  ``_tx_draining``: a drain
+        # began and has not returned (cancelled while paused), so the
+        # next frame may find the protocol paused below the mark.
+        self._tx_high = writer.transport.get_write_buffer_limits()[1]
+        self._tx_draining = False
         self._last_rx = time.monotonic()
         self._tasks: list[asyncio.Task] = []
         # cheap observability for fleet soaks (docs/fleet.md): cumulative
-        # frame/byte counters plus shed/reject/violation events
+        # frame/byte counters plus shed/reject/violation events;
+        # ``drain_waits``: frames whose write left the transport above
+        # its high-water mark, so that they waited under the deadline's
+        # timer; ``rx_direct_bytes``: payload bytes that went from their
+        # frames into a caller's buffer with one copy (``readinto``)
         self.stats = {"frames_tx": 0, "frames_rx": 0,
                       "bytes_tx": 0, "bytes_rx": 0,
+                      "drain_waits": 0, "rx_direct_bytes": 0,
                       "write_deadline_sheds": 0, "syn_rejects": 0,
                       "flow_violations": 0,
                       "stream_length_violations": 0}
@@ -240,7 +302,7 @@ class MuxConnection:
             self._tasks.append(asyncio.create_task(self._keepalive_loop()))
 
     # -- frame io ---------------------------------------------------------
-    async def _send_frame(self, ftype: int, sid: int, payload: bytes) -> None:
+    async def _send_frame(self, ftype: int, sid: int, payload) -> None:
         if self.closed:
             raise MuxError("connection closed")
         shed = False
@@ -265,7 +327,14 @@ class MuxConnection:
                     self.writer.write(payload)
                 self.stats["frames_tx"] += 1
                 self.stats["bytes_tx"] += _HDR.size + len(payload)
-                if self._write_deadline_s > 0:
+                if self._write_deadline_s > 0 and (
+                        self._tx_draining
+                        or self.writer.transport.get_write_buffer_size()
+                        >= self._tx_high):
+                    # the frame has to wait for the peer, at most the
+                    # deadline; one that found room makes no timer
+                    self.stats["drain_waits"] += 1
+                    self._tx_draining = True
                     try:
                         await asyncio.wait_for(self.writer.drain(),
                                                self._write_deadline_s)
@@ -275,7 +344,11 @@ class MuxConnection:
                         # only safe unit; skipping frames would desync the
                         # mux) rather than queue unbounded bytes
                         shed = True
+                    self._tx_draining = False
                 else:
+                    # not paused, so this returns at once (or raises what
+                    # the connection died of); with no deadline it waits
+                    # as long as the peer takes
                     await self.writer.drain()
                 dur = time.perf_counter() - t0
             except (ConnectionError, OSError) as e:

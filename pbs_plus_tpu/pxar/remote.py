@@ -135,11 +135,9 @@ class RemoteArchiveClient:
         resp = await self.s.call("pxar.read_dir", {"path": path})
         return [Entry.from_wire(d) for d in resp.data["entries"]]
 
-    async def read_at(self, path: str, off: int, n: int) -> bytes:
-        buf = bytearray()
-        await self.s.call_binary_into(
-            "pxar.read_at", {"path": path, "off": off, "n": n}, buf)
-        return bytes(buf)
+    async def read_at(self, path: str, off: int, n: int) -> bytearray:
+        return (await self.s.call_binary(
+            "pxar.read_at", {"path": path, "off": off, "n": n}, n))[1]
 
     async def done(self) -> None:
         await self.s.call("pxar.done")

@@ -91,6 +91,16 @@ CLOCK_TOTALS = {**{"writer_" + k: 0.0 for k in WRITER_STATES + ("cpu_s",)},
                 "loop_cpu_s": 0.0}
 
 
+# What the bulk bytes' way did on the job's aRPC connection, the
+# server's end of it (arpc/mux.py ``MuxConnection.stats``): frames sent
+# and those of them that had to wait for the peer under the write
+# deadline's timer, bytes received and those of them that went from
+# their frames into the pump's buffers with one copy.  On the job's
+# record as ``mux_*``, totalled here for /metrics.
+MUX_COUNTS = ("frames_tx", "drain_waits", "bytes_rx", "rx_direct_bytes")
+MUX_TOTALS = dict.fromkeys(MUX_COUNTS, 0)
+
+
 def _get_abortable(q: "queue.Queue", abort: "threading.Event | None"):
     """Blocking queue get that returns _ABORTED instead of waiting
     forever once ``abort`` is set (producers cancelled mid-flight never
@@ -227,11 +237,14 @@ class BackupResult:
 class _QueuePumpReader:
     """File-like .read(n) fed by a thread-safe queue of blocks (async
     producer / sync writer-thread consumer).  ``first`` is the block that
-    came with the open; with no queue it is the whole file."""
+    came with the open; with no queue it is the whole file.  A block is
+    any bytes-like object the producer no longer writes to — the buffer
+    an agentfs read was received into — and ``read`` serves it whole or
+    as views of it, never as copies."""
 
     def __init__(self, q: "queue.Queue | None",
                  abort: "threading.Event | None" = None, *,
-                 first: bytes = b""):
+                 first=b""):
         self._q = q
         self._abort = abort
         self._buf = first
@@ -241,7 +254,7 @@ class _QueuePumpReader:
         # dead consumer (advisor finding r1)
         self.dead = False
 
-    def read(self, n: int = -1) -> bytes:
+    def read(self, n: int = -1):
         while not self._buf and not self._eof:
             item = _get_abortable(self._q, self._abort)
             if item is _ABORTED:
@@ -259,11 +272,10 @@ class _QueuePumpReader:
         if not self._buf:
             return b""
         if n < 0 or n >= len(self._buf):
-            out = self._buf
-            self._buf = b""
+            out, self._buf = self._buf, b""
         else:
-            out = self._buf[:n]
-            self._buf = self._buf[n:]
+            view = memoryview(self._buf)
+            out, self._buf = view[:n], view[n:]
         return out
 
 
@@ -313,9 +325,17 @@ class RemoteTreeBackup:
             xattrs={k: bytes(v) for k, v in m.get("xattrs", {}).items()},
         )
 
+    def _mux_counts(self) -> dict:
+        """The job's connection's counters now; none where the agent
+        file system is not an aRPC client's (the tests' fakes)."""
+        conn = getattr(getattr(self.fs, "s", None), "conn", None)
+        stats = getattr(conn, "stats", {})
+        return {k: stats[k] for k in MUX_COUNTS if k in stats}
+
     async def run(self) -> BackupResult:
         with trace.span("backup.pump") as sp:
             t0, loop_cpu0 = time.perf_counter(), time.thread_time()
+            mux0 = self._mux_counts()
             try:
                 return await self._run()
             finally:
@@ -328,10 +348,13 @@ class RemoteTreeBackup:
                     **{"pump_" + k: v for k, v in self.waits.items()},
                     "pump_life_s": time.perf_counter() - t0,
                     "loop_cpu0": loop_cpu0, "loop_cpu1": time.thread_time()}
+                mux = {k: v - mux0[k] for k, v in self._mux_counts().items()}
                 sp.set(job=self.log.scope.get("job_id", ""), **self.pump,
-                       **clocks)
+                       **clocks, **{"mux_" + k: v for k, v in mux.items()})
                 for k, v in self.pump.items():
                     PUMP_TOTALS[k] += v
+                for k, v in mux.items():
+                    MUX_TOTALS[k] += v
                 for k, v in clocks.items():
                     if k in CLOCK_TOTALS:       # the states and the waits
                         CLOCK_TOTALS[k] += v
@@ -534,7 +557,7 @@ class RemoteTreeBackup:
                     break
                 taken += 1
                 pump["files"] += 1
-                if isinstance(got, bytes):
+                if not isinstance(got, Exception):
                     pump["batched_files"] += 1
                 # the sides _stream_file gives open_read's errors
                 side = "read" if isinstance(got, FirstReadError) else "open"
@@ -542,7 +565,7 @@ class RemoteTreeBackup:
                     await self._before_handing_on()
                 except Exception as e:
                     got, side = e, "read"
-                if isinstance(got, bytes):
+                if not isinstance(got, Exception):
                     items.append(("file", entry,
                                   _QueuePumpReader(None, first=got)))
                     self.result.bytes_total += len(got)

@@ -614,6 +614,26 @@ class MetricsRegistry:
               "read_many calls among them: each asks for a run of one "
               "listing's consecutive small files",
               [({}, float(pt["batch_calls"]))])
+        # the bulk bytes' way on the jobs' aRPC connections, this end
+        # (server/backup_job.py MUX_TOTALS; arpc/mux.py stats)
+        mt = dict(_backup_job.MUX_TOTALS)
+        gauge("pbs_plus_mux_frames_tx_total",
+              "Mux frames the server sent on backup jobs' connections, "
+              "by whether the write left the transport above its "
+              "high-water mark, so that the frame waited for the peer "
+              "under the write deadline's timer (waited), or found room "
+              "and made no timer (free)",
+              [({"drain": "waited"}, float(mt["drain_waits"])),
+               ({"drain": "free"},
+                float(mt["frames_tx"] - mt["drain_waits"]))])
+        gauge("pbs_plus_mux_bytes_rx_total",
+              "Bytes the server received on backup jobs' connections, by "
+              "whether they went from their frames into the pump's "
+              "buffer with one copy (direct: the bulk bytes) or not "
+              "(other: frame headers, envelopes, control)",
+              [({"way": "direct"}, float(mt["rx_direct_bytes"])),
+               ({"way": "other"},
+                float(mt["bytes_rx"] - mt["rx_direct_bytes"]))])
         # the sessions' own clocks (server/backup_job.py CLOCK_TOTALS;
         # docs/observability.md "The session's clocks"), summed over the
         # jobs that ended

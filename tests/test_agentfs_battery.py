@@ -747,3 +747,207 @@ def test_an_older_agent_publishes_identically(pki, tmp_path, monkeypatch,
         assert pump_old["one_call_files"] == 0
         assert pump_old["calls"] == 1 + 4 * 3 + 4 + 4 + 6 + 8
         assert st_old["open_reads"] == 0 and st_old["opens"] == 8
+
+
+# ------------------------------------------- the bulk bytes' way (PR 35)
+
+KIB, MIB = 1 << 10, 1 << 20
+WAY_SIZES = [0, 1, 256 * KIB - 1, 256 * KIB, MIB + 1, 8 * MIB]
+
+
+def _way_body(n: int) -> bytes:
+    return (bytes(i * 13 % 251 for i in range(65521)) * (n // 65521 + 1))[:n]
+
+
+@pytest.mark.parametrize("size", WAY_SIZES)
+@pytest.mark.parametrize("way", ["read_at", "open_read", "read_many"])
+def test_block_round_trip_is_byte_exact(pki, tmp_path, way, size):
+    """A block of every size around the frame and slice boundaries comes
+    back byte for byte through each of the three reads, in the buffer
+    it was received into (``read_many``: views of one), and every
+    payload byte went from its frame into that buffer with one copy."""
+    body = _way_body(size)
+    (tmp_path / "f").write_bytes(body)
+    (tmp_path / "g").write_bytes(b"neighbour")
+    ask = 8 * MIB
+
+    async def main():
+        h = Harness(pki, tmp_path)
+        async with h as c:
+            direct0 = c.s.conn.stats["rx_direct_bytes"]
+            if way == "read_at":
+                handle = await c.open("f")
+                got = await c.read_at(handle, 0, ask)
+                assert type(got) is bytearray
+                await c.close(handle)
+            elif way == "open_read":
+                handle, got, eof = await c.open_read("f", ask)
+                assert type(got) is bytearray
+                assert eof == (size < ask) and (handle == 0) == eof
+                if handle:
+                    await c.close(handle)
+            else:
+                got, other = await c.read_many(["f", "g"], ask + 9)
+                assert type(got) is memoryview and other == b"neighbour"
+                assert got.obj is other.obj         # one buffer, two views
+                size_all = size + 9
+            assert got == body and len(got) == size
+            received = size_all if way == "read_many" else size
+            assert c.s.conn.stats["rx_direct_bytes"] - direct0 == received
+            assert h.fs.stats["bytes"] == received
+    asyncio.run(main())
+
+
+def test_read_asks_bound_the_buffer(pki, tmp_path):
+    """The buffer a read is received into is never larger than what the
+    read asked for, whatever length the answer's header declares."""
+    (tmp_path / "f").write_bytes(_way_body(MIB))
+
+    async def main():
+        async with Harness(pki, tmp_path) as c:
+            handle = await c.open("f")
+            assert await c.read_at(handle, 0, 1000) == _way_body(1000)
+            # the raw call with a smaller bound than the agent was asked
+            # for: the excess is drained, the stream stays in step
+            _, buf = await c.s.call_binary(
+                "agentfs.read_at", {"handle": handle, "off": 0, "n": MIB},
+                300 * KIB)
+            assert buf == _way_body(300 * KIB)
+            assert await c.read_at(handle, MIB - 5, 99) == _way_body(MIB)[-5:]
+            await c.close(handle)
+    asyncio.run(main())
+
+
+_AGENT_CHILD = r"""
+import asyncio, sys
+sys.path.insert(0, sys.argv[1])
+from pbs_plus_tpu.agent.agentfs import AgentFSServer
+from pbs_plus_tpu.arpc import Router, TlsServerConfig, serve
+
+async def main():
+    root, cert, key, ca = sys.argv[2:6]
+    fs, router = AgentFSServer(root), Router()
+    fs.register(router)
+
+    async def on_conn(conn, peer, headers):
+        await router.serve_connection(conn)
+
+    srv = await serve("127.0.0.1", 0, TlsServerConfig(cert, key, ca),
+                      on_connection=on_conn)
+    print(srv.sockets[0].getsockname()[1], flush=True)
+    await asyncio.get_running_loop().run_in_executor(None, sys.stdin.read)
+    srv.close()
+
+asyncio.run(main())
+"""
+
+
+def test_read_at_receiving_side_holds_under_two_blocks(pki, tmp_path):
+    """The copy count that cannot rot: around one 8 MiB ``read_at``
+    from an agent in another process, the receiving process's traced
+    memory peaks under two blocks — the destination and the frames in
+    flight.  PR 34's way held the growing buffer and its ``bytes`` copy
+    at once, two blocks before a frame was counted; the next edit that
+    copies the block fails here, not on a ledger line.  A count of
+    bytes, never a clock."""
+    import subprocess
+    import sys
+    import tracemalloc
+    block = 8 * MIB
+    (tmp_path / "f").write_bytes(_way_body(2 * block))
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    child = subprocess.Popen(
+        [sys.executable, "-c", _AGENT_CHILD, repo, str(tmp_path),
+         pki["server_cert"], pki["server_key"], pki["ca"]],
+        stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True)
+
+    async def main(port: int) -> tuple[int, bytearray]:
+        cp, kp = pki["client"]
+        conn = await connect_to_server(
+            "127.0.0.1", port, TlsClientConfig(cp, kp, pki["ca"]))
+        try:
+            c = AgentFSClient(Session(conn))
+            handle = await c.open("f")
+            # the first block warms TLS's and the reader's own buffers
+            assert len(await c.read_at(handle, 0, block)) == block
+            tracemalloc.start()
+            try:
+                base = tracemalloc.get_traced_memory()[0]
+                got = await c.read_at(handle, block, block)
+                peak = tracemalloc.get_traced_memory()[1] - base
+            finally:
+                tracemalloc.stop()
+            assert conn.stats["rx_direct_bytes"] == 2 * block
+            await c.close(handle)
+            return peak, got
+        finally:
+            await conn.close()
+
+    try:
+        port = int(child.stdout.readline())
+        peak, got = asyncio.run(main(port))
+    finally:
+        child.stdin.close()
+        child.wait(timeout=30)
+    assert got == _way_body(2 * block)[block:]
+    assert block <= peak < 1.75 * block, peak
+
+
+def test_a_backup_over_a_real_session_records_the_way(pki, tmp_path):
+    """The pump's record of a job carries what the bulk bytes' way did
+    on the job's connection — ``mux_frames_tx``, ``mux_drain_waits``,
+    ``mux_bytes_rx``, ``mux_rx_direct_bytes`` — the same totals move on
+    ``/metrics``, and nearly every byte received went the direct way."""
+    from pbs_plus_tpu.server import backup_job as bj
+    from pbs_plus_tpu.server import metrics
+    from pbs_plus_tpu.server.store import Server, ServerConfig
+    from pbs_plus_tpu.utils import trace
+    tree = tmp_path / "tree"
+    tree.mkdir()
+    sizes = {"big": 9 * MIB + 3, "s1": 13_000, "s2": 0, "s3": 700}
+    for name, size in sizes.items():
+        (tree / name).write_bytes(_way_body(size))
+    seen = {}
+
+    class Writer:
+        def write_entry(self, entry):
+            pass
+
+        def write_entry_reader(self, entry, reader):
+            parts = []
+            while True:
+                b = reader.read(4 * MIB)
+                if not b:
+                    break
+                parts.append(bytes(b))
+            seen[entry.path] = b"".join(parts)
+
+    class Sess:
+        writer = Writer()
+
+    async def main():
+        async with Harness(pki, tree) as c:
+            pump = bj.RemoteTreeBackup(c, Sess())
+            res = await pump.run()
+            return res, dict(c.s.conn.stats)
+    before = dict(bj.MUX_TOTALS)
+    trace.clear()
+    res, stats = asyncio.run(main())
+    assert res.errors == [] and res.files == len(sizes)
+    assert seen == {name: _way_body(size) for name, size in sizes.items()}
+    attrs = trace.job_records()[-1]["attrs"]
+    mux = {k: attrs["mux_" + k] for k in bj.MUX_COUNTS}
+    assert mux == {k: stats[k] for k in bj.MUX_COUNTS}   # the job's own conn
+    assert mux["rx_direct_bytes"] == sum(sizes.values())
+    assert mux["rx_direct_bytes"] / mux["bytes_rx"] >= 0.95
+    assert 0 <= mux["drain_waits"] <= mux["frames_tx"]
+    assert {k: bj.MUX_TOTALS[k] - before[k] for k in mux} == mux
+    server = Server(ServerConfig(state_dir=str(tmp_path / "state"),
+                                 cert_dir=str(tmp_path / "certs"),
+                                 datastore_dir=str(tmp_path / "ds")))
+    expo = metrics.MetricsRegistry(server).render()
+    t = bj.MUX_TOTALS
+    assert (f'pbs_plus_mux_bytes_rx_total{{way="direct"}} '
+            f'{float(t["rx_direct_bytes"])}') in expo
+    assert (f'pbs_plus_mux_frames_tx_total{{drain="waited"}} '
+            f'{float(t["drain_waits"])}') in expo

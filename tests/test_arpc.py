@@ -372,3 +372,460 @@ def test_mux_peer_rst_after_local_close_retires_stream():
         srv.close()
         await srv.wait_closed()
     run_async(main())
+
+
+# ------------------------------------------- the bulk bytes' way (PR 35)
+#
+# A MuxConnection over a tape in place of a socket: everything it hands
+# to the transport is kept, everything the peer "sends" is fed by hand,
+# so frame boundaries, grants and counters are exact.
+
+from pbs_plus_tpu.arpc import receive_data  # noqa: E402
+from pbs_plus_tpu.arpc import mux as muxmod  # noqa: E402
+from pbs_plus_tpu.arpc.binary_stream import (  # noqa: E402
+    MAGIC, StreamLengthError,
+)
+from pbs_plus_tpu.utils import failpoints  # noqa: E402
+
+KIB, MIB = 1 << 10, 1 << 20
+
+
+class _Tape:
+    """A StreamWriter's surface (and its transport's) over nothing."""
+
+    HIGH = 64 * KIB
+
+    def __init__(self):
+        self.transport = self
+        self.writes: list[bytes] = []
+        self.buffered = 0               # what get_write_buffer_size says
+        self.drains = 0
+        self.unpaused = asyncio.Event()
+        self.unpaused.set()
+
+    def get_write_buffer_limits(self):
+        return (self.HIGH // 4, self.HIGH)
+
+    def get_write_buffer_size(self):
+        return self.buffered
+
+    def write(self, data):
+        self.writes.append(bytes(data))
+
+    async def drain(self):
+        self.drains += 1
+        await self.unpaused.wait()
+
+    def close(self):
+        pass
+
+    async def wait_closed(self):
+        pass
+
+    def frames(self) -> list[tuple[int, int, bytes]]:
+        """(type, sid, payload) of every frame written so far."""
+        wire, out, at = b"".join(self.writes), [], 0
+        while at < len(wire):
+            ftype, sid, ln = muxmod._HDR.unpack_from(wire, at)
+            at += muxmod._HDR.size
+            out.append((ftype, sid, wire[at:at + ln]))
+            at += ln
+        assert at == len(wire)
+        return out
+
+
+def _frame(ftype: int, sid: int, payload: bytes = b"") -> bytes:
+    return muxmod._HDR.pack(ftype, sid, len(payload)) + payload
+
+
+async def _taped(**kw):
+    """(connection, its tape, the reader the test feeds)."""
+    reader, tape = asyncio.StreamReader(), _Tape()
+    conn = muxmod.MuxConnection(reader, tape, is_client=False,
+                                keepalive_s=0, **kw)
+    conn.start()
+    return conn, tape, reader
+
+
+async def _settle():
+    for _ in range(20):
+        await asyncio.sleep(0)
+
+
+def _body(n: int) -> bytes:
+    return bytes(i * 7 % 251 for i in range(min(n, 4096))) * (n // 4096 + 1)
+
+
+def test_wire_sender_frames_are_the_parents():
+    """No wire change on the way out: the transfer's 14-byte header as a
+    frame of its own, DATA payloads cut at 256 KiB inside 1 MiB slices
+    and at the credit, the rest of the slice after the grant — the
+    boundaries PR 34's sender wrote, byte for byte."""
+    total = muxmod.INITIAL_CREDIT + 300 * KIB
+    body = _body(total)[:total]
+
+    async def main():
+        conn, tape, reader = await _taped()
+        st = await conn.open_stream()
+        send = asyncio.ensure_future(send_data_from_reader(st, body, total))
+        await _settle()
+        assert not send.done()          # out of credit, 14 bytes short
+        reader.feed_data(_frame(muxmod.WINDOW, st.sid,
+                                (MIB).to_bytes(4, "little")))
+        assert await asyncio.wait_for(send, 5) == total
+        frames = tape.frames()
+        assert frames[0] == (muxmod.SYN, 2, b"")
+        assert all(f[:2] == (muxmod.DATA, 2) for f in frames[1:])
+        hdr = frames[1][2]
+        assert len(hdr) == 14 and hdr[:4] == MAGIC
+        assert int.from_bytes(hdr[6:], "little") == total
+        assert [len(f[2]) for f in frames[2:]] == \
+            [256 * KIB] * 15 + [256 * KIB - 14, 14, 256 * KIB, 44 * KIB]
+        assert b"".join(f[2] for f in frames[2:]) == body
+        assert conn.stats["frames_tx"] == len(frames)
+        assert conn.stats["bytes_tx"] == sum(9 + len(f[2]) for f in frames)
+        await conn.close()
+    run_async(main())
+
+
+@pytest.mark.parametrize("source", ["bytes", "bytearray", "reader"])
+def test_wire_sender_same_bytes_from_every_source(source):
+    """Immutable bytes go down as views, a bytearray the caller could
+    still change is copied frame by frame, a reader is read block by
+    block: the wire is the same."""
+    import io
+    total = MIB + 1
+    body = _body(total)[:total]
+
+    async def main():
+        conn, tape, _ = await _taped()
+        st = await conn.open_stream()
+        src = {"bytes": body, "bytearray": bytearray(body),
+               "reader": io.BytesIO(body)}[source]
+        assert await send_data_from_reader(st, src, total) == total
+        if source == "bytearray":
+            src[:] = bytes(total)       # the caller's buffer, reused
+        frames = tape.frames()[2:]
+        assert [len(f[2]) for f in frames] == [256 * KIB] * 4 + [1]
+        assert b"".join(f[2] for f in frames) == body
+        await conn.close()
+    run_async(main())
+
+
+async def _fed_stream(conn, reader, sid=1):
+    reader.feed_data(_frame(muxmod.SYN, sid))
+    return await asyncio.wait_for(conn.accept_stream(), 5)
+
+
+def test_mux_read_partial_across_frame_boundaries():
+    """``read(n)`` is what it was: up to n of what is buffered, across
+    frames; a read that takes exactly one whole frame takes the frame's
+    own bytes, by reference."""
+    async def main():
+        conn, tape, reader = await _taped()
+        st = await _fed_stream(conn, reader)
+        payloads = [b"a" * 5, b"b" * 3, b"c" * 4, b"d" * 6]
+        for p in payloads:
+            reader.feed_data(_frame(muxmod.DATA, 1, p))
+        await _settle()
+        assert await st.read(2) == b"aa"
+        assert await st.read(4) == b"aaab"          # over a boundary
+        assert await st.read(2) == b"bb"            # a frame's tail
+        whole = await st.read(4)
+        assert whole == b"cccc" and whole is st_frames[2]
+        assert await st.read(100) == b"dddddd"
+        reader.feed_data(_frame(muxmod.DATA, 1, b"ee") +
+                         _frame(muxmod.DATA, 1, b"ff") +
+                         _frame(muxmod.FIN, 1))
+        await _settle()
+        assert await st.read() == b"eeff"           # all that is buffered
+        assert await st.read(5) == b""              # EOF
+        assert st._rx_len == 0 and not st._rx
+        await conn.close()
+
+    st_frames = []
+    orig = muxmod.MuxStream._on_data
+
+    def keep(self, payload):
+        st_frames.append(payload)
+        orig(self, payload)
+    muxmod.MuxStream._on_data = keep
+    try:
+        run_async(main())
+    finally:
+        muxmod.MuxStream._on_data = orig
+
+
+def test_mux_readinto_lands_frames_with_one_copy_and_counts_them():
+    async def main():
+        conn, tape, reader = await _taped()
+        st = await _fed_stream(conn, reader)
+        for p in (b"abcde", b"fgh", b"ijkl"):
+            reader.feed_data(_frame(muxmod.DATA, 1, p))
+        await _settle()
+        buf = bytearray(7)
+        with memoryview(buf) as v:
+            assert await st.readinto(v) == 7
+        assert bytes(buf) == b"abcdefg"
+        big = bytearray(100)
+        with memoryview(big) as v:
+            assert await st.readinto(v) == 5        # what is buffered
+        assert bytes(big[:5]) == b"hijkl"
+        assert conn.stats["rx_direct_bytes"] == 12
+        reader.feed_data(_frame(muxmod.FIN, 1))
+        await _settle()
+        with memoryview(big) as v:
+            assert await st.readinto(v) == 0        # EOF
+        await conn.close()
+    run_async(main())
+
+
+def test_mux_credit_accounting_is_the_parents():
+    """``_rx_unacked`` and the WINDOW grants for a given run of reads
+    are what PR 34's byte-array stream gave: a grant once a quarter of
+    the credit is consumed, of all consumed since the last one —
+    whether the bytes left by ``read`` or by ``readinto``."""
+    quarter = muxmod.INITIAL_CREDIT // 4
+
+    async def main():
+        conn, tape, reader = await _taped()
+        st = await _fed_stream(conn, reader)
+        for _ in range(12):                         # 3 MiB in 256 KiB frames
+            reader.feed_data(_frame(muxmod.DATA, 1, bytes(256 * KIB)))
+        await _settle()
+        assert st._rx_unacked == 3 * MIB
+
+        def grants():
+            return [int.from_bytes(f[2], "little")
+                    for f in tape.frames() if f[0] == muxmod.WINDOW]
+
+        assert len(await st.read(quarter - 1)) == quarter - 1
+        assert grants() == [] and st._rx_unacked == 3 * MIB
+        assert len(await st.read(1)) == 1
+        assert grants() == [quarter]
+        assert st._rx_unacked == 3 * MIB - quarter
+        buf = bytearray(quarter + 300)
+        with memoryview(buf) as v:
+            assert await st.readinto(v[:300]) == 300
+            assert grants() == [quarter]
+            assert await st.readinto(v[300:]) == quarter
+        assert grants() == [quarter, quarter + 300]
+        assert st._rx_unacked == 3 * MIB - 2 * quarter - 300
+        assert st._consumed_since_grant == 0
+        await conn.close()
+    run_async(main())
+
+
+@pytest.mark.parametrize("over", [0, 1])
+def test_mux_flow_violation_resets_at_credit_plus_slack(over):
+    """A peer that writes past its credit is reset when the unread
+    bytes pass INITIAL_CREDIT + the slack, not before."""
+    limit = muxmod.INITIAL_CREDIT + muxmod._RX_CREDIT_SLACK
+
+    async def main():
+        conn, tape, reader = await _taped()
+        st = await _fed_stream(conn, reader)
+        left = limit + over
+        while left:
+            n = min(left, muxmod.MAX_DATA_FRAME)
+            reader.feed_data(_frame(muxmod.DATA, 1, bytes(n)))
+            left -= n
+        await _settle()
+        assert st._rx_unacked == limit + over
+        assert conn.stats["flow_violations"] == over
+        rsts = [f for f in tape.frames() if f[0] == muxmod.RST]
+        assert len(rsts) == over
+        if over:
+            with pytest.raises(muxmod.MuxError):
+                await st.read(1)
+        else:
+            assert len(await st.read(10)) == 10
+        await conn.close()
+    run_async(main())
+
+
+def test_send_frame_makes_no_timer_on_an_unpaused_transport():
+    """A frame that finds room in the transport takes the plain drain:
+    no ``wait_for``, ``drain_waits`` stays 0."""
+    async def main():
+        conn, tape, _ = await _taped(write_deadline_s=60.0)
+        st = await conn.open_stream()
+        timers = []
+        orig = asyncio.wait_for
+
+        async def counted(*a, **kw):
+            timers.append(a)
+            return await orig(*a, **kw)
+        muxmod.asyncio.wait_for = counted
+        try:
+            await st.write(bytes(MIB))
+        finally:
+            muxmod.asyncio.wait_for = orig
+        assert conn.stats["frames_tx"] == 5 and tape.drains == 5
+        assert conn.stats["drain_waits"] == 0 and timers == []
+        await conn.close()
+    run_async(main())
+
+
+def test_send_frame_waits_under_the_deadline_above_the_high_water_mark():
+    """A write that leaves the transport at its high-water mark takes
+    the timed path and counts; when the peer drains, the frame goes on."""
+    async def main():
+        conn, tape, _ = await _taped(write_deadline_s=60.0)
+        st = await conn.open_stream()
+        tape.buffered = tape.HIGH
+        tape.unpaused.clear()
+        w = asyncio.ensure_future(st.write(b"x" * 10))
+        await _settle()
+        assert not w.done() and conn.stats["drain_waits"] == 1
+        tape.buffered = 0
+        tape.unpaused.set()
+        await asyncio.wait_for(w, 5)
+        await st.write(b"y")
+        assert conn.stats["drain_waits"] == 1
+        assert conn.stats["write_deadline_sheds"] == 0
+        await conn.close()
+    run_async(main())
+
+
+def test_send_frame_sheds_a_reader_that_never_drains():
+    """The slow-reader shed keeps its meaning: a frame that has to wait
+    waits at most the deadline, then the connection is shed."""
+    async def main():
+        conn, tape, _ = await _taped(write_deadline_s=0.05)
+        st = await conn.open_stream()
+        tape.buffered = 10 * tape.HIGH
+        tape.unpaused.clear()
+        with pytest.raises(muxmod.MuxError, match="shed"):
+            await asyncio.wait_for(st.write(b"x" * 10), 5)
+        assert conn.stats["write_deadline_sheds"] == 1
+        assert conn.stats["drain_waits"] == 1
+        assert conn.closed and "write deadline" in conn.close_reason
+    run_async(main())
+
+
+def test_send_frame_cancelled_drain_keeps_the_next_frame_timed():
+    """A drain cancelled while paused leaves the protocol paused below
+    the mark: the next frame still waits under the deadline."""
+    async def main():
+        conn, tape, _ = await _taped(write_deadline_s=0.05)
+        st = await conn.open_stream()
+        tape.buffered = tape.HIGH
+        tape.unpaused.clear()
+        w = asyncio.ensure_future(st.write(b"x"))
+        await _settle()
+        w.cancel()
+        with pytest.raises(asyncio.CancelledError):
+            await w
+        tape.buffered = tape.HIGH // 2          # between the marks
+        with pytest.raises(muxmod.MuxError, match="shed"):
+            await asyncio.wait_for(st.write(b"y"), 5)
+        assert conn.stats["drain_waits"] == 2
+    run_async(main())
+
+
+def test_write_frame_corrupt_failpoint_still_corrupts_a_view():
+    """``arpc.mux.write_frame=corrupt`` gets a view now and still flips
+    the frame's last byte on the wire (materialised only when armed);
+    the caller's bytes stay as they were."""
+    body = bytes(range(200))
+
+    async def main():
+        conn, tape, _ = await _taped()
+        st = await conn.open_stream()
+        with failpoints.armed("arpc.mux.write_frame", "corrupt", once=True):
+            await st.write(body)
+        await st.write(body)
+        frames = [f[2] for f in tape.frames() if f[0] == muxmod.DATA]
+        assert frames[0] == body[:-1] + bytes([body[-1] ^ 1])
+        assert frames[1] == body
+        await conn.close()
+    run_async(main())
+
+
+async def _taped_transfer(reader, sid, declared, payload):
+    reader.feed_data(_frame(muxmod.DATA, sid,
+                            MAGIC + (1).to_bytes(2, "little")
+                            + declared.to_bytes(8, "little")))
+    for at in range(0, len(payload), muxmod.MAX_DATA_FRAME):
+        reader.feed_data(_frame(muxmod.DATA, sid,
+                                payload[at:at + muxmod.MAX_DATA_FRAME]))
+
+
+@pytest.mark.parametrize("sink", ["own", "bytearray", "callable"])
+def test_receive_max_len_discards_the_excess(sink):
+    """Drain-on-short-buffer: what the transfer holds past ``max_len``
+    is read and dropped, the consumed length returned, and the stream
+    is left at the transfer's end."""
+    body = _body(MIB + 5)[:MIB + 5]
+    keep = 300 * KIB + 1
+
+    async def main():
+        conn, tape, reader = await _taped()
+        st = await _fed_stream(conn, reader)
+        await _taped_transfer(reader, 1, len(body), body)
+        reader.feed_data(_frame(muxmod.DATA, 1, b"next"))
+        if sink == "own":
+            got = await receive_data(st, keep)
+            n = len(got)
+        elif sink == "bytearray":
+            got = bytearray(b"head")
+            n = await receive_data_into(st, got, max_len=keep)
+            assert got[:4] == b"head"
+            del got[:4]
+        else:
+            blocks = []
+            n = await receive_data_into(st, blocks.append, max_len=keep)
+            got = b"".join(blocks)
+        assert n == keep and got == body[:keep]
+        assert await st.read(10) == b"next"
+        assert conn.stats["rx_direct_bytes"] == (keep if sink == "own"
+                                                 else 0)
+        await conn.close()
+    run_async(main())
+
+
+@pytest.mark.parametrize("size", [0, 1, 256 * KIB - 1, 256 * KIB, MIB + 1])
+def test_receive_data_direct_bytes_are_the_bytes_received(size):
+    """Every payload byte of a transfer reaches the buffer by
+    ``readinto``: ``rx_direct_bytes`` is the transfer's length, and the
+    buffer is sized once, to it."""
+    body = _body(size)[:size]
+
+    async def main():
+        conn, tape, reader = await _taped()
+        st = await _fed_stream(conn, reader)
+        await _taped_transfer(reader, 1, size, body)
+        buf = await receive_data(st, 2 * MIB)
+        assert type(buf) is bytearray and buf == body
+        assert conn.stats["rx_direct_bytes"] == size
+        await conn.close()
+    run_async(main())
+
+
+def test_receive_data_header_that_lies_is_counted_and_commits_the_ask():
+    """A transfer that ends before its declared length: the violation
+    is counted, and the buffer was sized to what the caller asked for,
+    not to the lie."""
+    async def main():
+        conn, tape, reader = await _taped()
+        st = await _fed_stream(conn, reader)
+        await _taped_transfer(reader, 1, 512 * MIB, b"only this")
+        reader.feed_data(_frame(muxmod.FIN, 1))
+        sized = []
+        orig = muxmod.MuxStream.readinto
+
+        async def seen(self, buf):
+            sized.append(len(buf))
+            return await orig(self, buf)
+        muxmod.MuxStream.readinto = seen
+        try:
+            with pytest.raises(StreamLengthError) as ei:
+                await receive_data(st, MIB)
+        finally:
+            muxmod.MuxStream.readinto = orig
+        assert sized[0] == MIB
+        assert (ei.value.declared, ei.value.actual) == (512 * MIB, 9)
+        assert conn.stats["stream_length_violations"] == 1
+        await conn.close()
+    run_async(main())

@@ -875,3 +875,72 @@ def test_the_log_the_traces_endpoint_and_metrics_show_the_same_clocks(
         name = 'pbs_plus_pump_wait_seconds_total{on="%s"}' % on
         assert moved[name] == pytest.approx(attrs["pump_" + key], abs=1e-6)
     assert after["pbs_plus_loop_cpu_seconds_total"] == attrs["loop_cpu1"]
+
+
+# ------------------------------------------------ blocks served as views
+
+@pytest.mark.parametrize("kind", [bytes, bytearray, memoryview])
+def test_the_reader_serves_views_of_its_block_never_copies(kind):
+    """``_QueuePumpReader.read`` hands the writer the block it was
+    given — whole as it is, in part as views of it — whatever bytes-like
+    object the agentfs read was received into."""
+    import queue
+    body = _body("blk", 1000)
+    first, second = kind(body[:600]), kind(body[600:])
+    fq: queue.Queue = queue.Queue()
+    fq.put(second)
+    fq.put(bj._SENTINEL)
+    reader = bj._QueuePumpReader(fq, first=first)
+    a, b = reader.read(250), reader.read(250)
+    assert (a, b) == (body[:250], body[250:500])
+    for part in (a, b):
+        assert type(part) is memoryview
+        assert part.obj is (first.obj if kind is memoryview else first)
+    c = reader.read(250)                    # the block's rest, short
+    assert c == body[500:600] and len(c) == 100
+    whole = reader.read(4096)               # a whole block: itself
+    assert whole is second
+    assert not reader.read(10) and not reader.read(10)
+
+
+def test_views_reach_the_writer_byte_exact_through_a_real_stream(
+        tmp_path, monkeypatch):
+    """Blocks that arrive as bytearrays and as views of one buffer (what
+    ``read_at`` and ``read_many`` hand on since PR 35) go through the
+    real ``SessionWriter`` — entry digest, chunk buffer, batch hasher,
+    store — and read back byte for byte."""
+    import hashlib
+
+    class ViewFS(CountingFS):
+        async def read_at(self, handle, off, n):
+            return bytearray(await super().read_at(handle, off, n))
+
+        async def read_many(self, paths, budget):
+            got = await super().read_many(paths, budget)
+            buf = bytearray(b"".join(g for g in got if isinstance(g, bytes)))
+            view, at, out = memoryview(buf), 0, []
+            for g in got:
+                if isinstance(g, bytes):
+                    out.append(view[at:at + len(g)])
+                    at += len(g)
+                else:
+                    out.append(g)
+            return out
+
+    sizes = {"a": 10, "b": 0, "c": 700, "d": 3 * BLOCK + 7, "e": BLOCK}
+    fs = ViewFS(sizes)
+    sess = _StreamSession(tmp_path, monkeypatch)
+    digests = {}
+    real = sess.writer.write_entry_reader
+
+    def keep(entry, reader, **kw):
+        digests[entry.path] = real(entry, reader, **kw)
+        return digests[entry.path]
+    sess.writer.write_entry_reader = keep
+    pump, res, _ = _run(fs, sess)
+    assert not isinstance(res, Exception), res
+    assert res.errors == [] and res.files == len(sizes)
+    assert pump.pump["batched_files"] == 3          # a, b, c came as views
+    assert digests == {name: hashlib.sha256(_body(name, size)).digest()
+                       for name, size in sizes.items()}
+    assert res.bytes_total == sum(sizes.values())

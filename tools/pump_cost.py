@@ -15,8 +15,13 @@ pumped REPEATS times against the agent as it is, against one without
 against one that also ignores ``read`` on ``agentfs.open`` (one from
 before PR 29).  Per tree
 and agent, the median run: wall and process CPU seconds, milliseconds a
-file and a MiB, and the pump's own counts: calls a file, the files that
-came in a ``read_many`` answer and those answers.  One JSON line on
+file and a MiB, the pump's own counts — calls a file, the files that
+came in a ``read_many`` answer and those answers — and what the bulk
+bytes' way did on the session's two ends (``MuxConnection.stats``):
+``drain_waits_per_frame_tx``, the share of the agent's frames that had
+to wait for the peer under the write deadline's timer, and
+``rx_direct_per_byte_rx``, the share of the bytes the server received
+that went from their frames into the pump's buffers with one copy.  One JSON line on
 stdout, the same in ``chiprun_out/pump_cost.json``.  It never imports
 jax; the numbers are the host's and only mean something on the host they
 were taken on.
@@ -99,8 +104,10 @@ async def _pump_once(pki: dict, root: str, agent_cls) -> dict:
     fs = agent_cls(root)
     router = Router()
     fs.register(router)
+    agent_ends = []
 
     async def on_conn(conn, peer, headers):
+        agent_ends.append(conn)
         await router.serve_connection(conn)
 
     srv = await serve("127.0.0.1", 0,
@@ -118,7 +125,10 @@ async def _pump_once(pki: dict, root: str, agent_cls) -> dict:
             raise RuntimeError(f"pump errors: {res.errors[:3]}")
         return {"wall_s": wall, "cpu_s": cpu, "files": res.files,
                 "bytes": res.bytes_total, "pump": dict(pump.pump),
-                "agent": dict(fs.stats)}
+                "agent": dict(fs.stats),
+                # the agent's end sends the bulk, the server's receives it
+                "mux_agent": dict(agent_ends[0].stats),
+                "mux_server": dict(conn.stats)}
     finally:
         await conn.close()
         srv.close()
@@ -165,7 +175,15 @@ def main() -> int:
                     / mid["pump"]["files"],
                     "batched_files": mid["pump"]["batched_files"],
                     "batch_calls": mid["pump"]["batch_calls"],
-                    "pump": mid["pump"], "agent_stats": mid["agent"]})
+                    "drain_waits_per_frame_tx":
+                    mid["mux_agent"]["drain_waits"]
+                    / mid["mux_agent"]["frames_tx"],
+                    "rx_direct_per_byte_rx":
+                    mid["mux_server"]["rx_direct_bytes"]
+                    / mid["mux_server"]["bytes_rx"],
+                    "pump": mid["pump"], "agent_stats": mid["agent"],
+                    "mux_agent": mid["mux_agent"],
+                    "mux_server": mid["mux_server"]})
     line = json.dumps({"host_cores": os.cpu_count(), "repeats": REPEATS,
                        "rows": rows})
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
