@@ -15,18 +15,34 @@ cuckoo eviction + table growth; ``device_table`` re-uploads after a batch
 of inserts.  The host dict stays authoritative — a 64-bit-fingerprint
 false positive (~2⁻⁶⁴ per probe) is confirmed against it before a chunk
 upload is skipped.
+
+What that re-upload costs at a deployment's table (PERF.md, PR 36, cell
+``index-at-size.serial``: a 2 GiB table in HBM, 64 KiB chunks, ~211
+digests a probe, one TPU v5e and its 13-core host): a probe with a clean
+table is 1.6-1.8 ms of host clock, 0.9 ms of it round the program; the
+host twin answers the same 211 digests from the mirror in 0.10 ms; and
+the table's copy after an insert takes 0.22 s — the host's threads
+first relay ``uint32[NB, 4, 2]`` out into the device's tiling
+(``Transpose`` in a profile: 150 MB of trace a copy; the same bytes
+sent flat need none) — which every flush of a volume of new chunks
+pays: 67 copies a 1,085 MiB volume, a third of the writer thread's life
+(ROADMAP S7).  ``_lookup`` is one program a (table
+shape, probe class); ``warm_lookups`` builds them from shapes alone,
+ahead of the writers (``DedupIndex._warm_lookups``).
 """
 
 from __future__ import annotations
 
 import functools
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..utils import jaxenv, trace
+from ..utils.log import L
 
 jaxenv.watch_compiles()
 
@@ -116,6 +132,49 @@ def _lookup(table: jax.Array, digests: jax.Array) -> jax.Array:
     hit1 = jnp.any((s1[..., 0] == fp0[:, None]) & (s1[..., 1] == fp1[:, None]), axis=1)
     hit2 = jnp.any((s2[..., 0] == fp0[:, None]) & (s2[..., 1] == fp1[:, None]), axis=1)
     return hit1 | hit2
+
+
+# ``_lookup`` built ahead of a writer's first probe, by (buckets, probe
+# class): a table of another shape and every probe class is a program of
+# its own, and one that compiles inside a probe stops a backup for as
+# long as it takes.  A shape nobody built ahead still compiles at the
+# probe, as before (``round_trip`` warns).
+_programs: dict = {}
+
+
+def _build_lookups(n_buckets: int, classes) -> None:
+    for rows in classes:
+        key = (n_buckets, rows)
+        if key in _programs:
+            continue
+        try:
+            _programs[key] = _lookup.lower(
+                jax.ShapeDtypeStruct((n_buckets, SLOTS, 2), jnp.uint32),
+                jax.ShapeDtypeStruct((rows, 32), jnp.uint8)).compile()
+        except Exception as e:      # the probe then compiles for itself
+            L.warning("device.probe rows=%d buckets=%d not built ahead: %s",
+                      rows, n_buckets, e)
+
+
+def warm_lookups(n_buckets: int, classes) -> threading.Thread:
+    """Build (or load from the persistent cache) the lookup programs of a
+    table of ``n_buckets`` at the probe classes ``classes``, from shapes
+    alone — no table, no lock — on a thread of its own, which is
+    returned: the caller's thread goes on."""
+    t = threading.Thread(target=_build_lookups, args=(n_buckets, classes),
+                         name="index-warm", daemon=True)
+    t.start()
+    return t
+
+
+def _probe_class(n: int) -> int:
+    """The padded digest count of a probe of ``n`` digests."""
+    return next(c for c in _PROBE_CLASSES if c >= n)
+
+
+def probe_classes_upto(n: int) -> tuple:
+    """The probe classes batches of 1..``n`` digests are padded to."""
+    return tuple(c for c in _PROBE_CLASSES if c <= _probe_class(n))
 
 
 class CuckooIndex:
@@ -448,21 +507,32 @@ class CuckooIndex:
             with rt.phase("pack"):
                 arr = np.asarray(digests, dtype=np.uint8)
                 n = arr.shape[0]
-                n_pad = next(c for c in _PROBE_CLASSES if c >= n)
+                n_pad = _probe_class(n)
                 padded = np.zeros((n_pad, 32), dtype=np.uint8)
                 padded[:n] = arr
             rt.shape = f"rows={n_pad} buckets={self.n_buckets}"
+            mine = {"index_probe_padded": n_pad}
             with rt.phase("h2d"):
-                if self._dirty or self._device_table is None:
-                    # the table too, whole, after any insert
-                    rt.add(table_uploads=1,
-                           table_upload_bytes=self._table.nbytes)
-                table, dd = self.device_table(), jnp.asarray(padded)
-                jax.block_until_ready((table, dd))
+                # the table too, whole, after any insert: its own seconds
+                # apart from the digests' copy
+                carried = self._dirty or self._device_table is None
+                t0 = time.perf_counter()
+                table = self.device_table().block_until_ready()
+                if carried:
+                    upload_s = time.perf_counter() - t0
+                    nbytes = self._table.nbytes
+                    rt.add(table_uploads=1, table_upload_bytes=nbytes)
+                    rt.attrs["upload_s"] = upload_s
+                    mine.update(index_table_uploads=1,
+                                index_table_upload_bytes=nbytes,
+                                index_upload_s=upload_s)
+                dd = jnp.asarray(padded).block_until_ready()
             rt.add(dispatches=1, probes=n, bytes=arr.nbytes,
                    padded_bytes=padded.nbytes)
+            lookup = _programs.get((self.n_buckets, n_pad), _lookup)
             with rt.phase("device"):
-                dhit = _lookup(table, dd).block_until_ready()
+                dhit = lookup(table, dd).block_until_ready()
+            trace.tally(index_device_s=rt.attrs["device_s"], **mine)
             with rt.phase("d2h"):
                 hit = np.asarray(dhit)
             with rt.phase("unpack"):

@@ -72,7 +72,7 @@ from typing import Iterable, Iterator, Sequence
 
 import numpy as np
 
-from ..utils import atomicio, fswitness, jaxenv
+from ..utils import atomicio, fswitness, jaxenv, trace
 from .digestlog import FLAG_DATABLOB as _DATABLOB
 from .digestlog import FLAG_TOMBSTONE as _TOMB
 from .digestlog import MAN_MAGIC as _MAN_MAGIC
@@ -186,6 +186,10 @@ class DedupIndex:
         # first loads, the other sees `booted` and skips the scan
         self._booted = False
         self._boot_lock = threading.Lock()
+        # the table shape whose device lookup programs were last asked
+        # for (`_warm_lookups`), and the thread building them
+        self._warm_buckets = 0
+        self._warm_thread: "threading.Thread | None" = None
         # sketch entries recovered by the last load_snapshot (consumed
         # by ChunkStore._boot_index into the similarity tier); None =
         # snapshot had no valid sketch section
@@ -201,6 +205,7 @@ class DedupIndex:
         """Declare the current contents authoritative (caller
         pre-populated the index; no loader should ever run)."""
         self._booted = True
+        self._warm_lookups()
 
     def ensure_booted(self, loader) -> None:
         """Run ``loader()`` exactly once across every sharer before the
@@ -211,6 +216,33 @@ class DedupIndex:
             if not self._booted:
                 loader()
                 self._booted = True
+                self._warm_lookups()
+
+    def _warm_lookups(self) -> None:
+        """On a device host, have the lookup programs of the table's
+        shape built before a writer needs them: one per probe class a
+        hash batch can produce (``transfer._HASH_BATCH_COUNT`` digests at
+        most a flush), at boot (whose loaders `rebuild` or `load_snapshot`
+        it) and again whenever an insert has changed the table's shape —
+        on a thread of their own (``ops.cuckoo.warm_lookups``),
+        so neither the boot nor the insert that grew the table waits.
+        Nothing on a CPU host: the host twin has no program."""
+        nb = self.n_buckets
+        if nb == self._warm_buckets or not self._booted \
+                or not jaxenv.on_accelerator():
+            return
+        from ..ops.cuckoo import probe_classes_upto, warm_lookups
+        from .transfer import _HASH_BATCH_COUNT
+        self._warm_buckets = nb
+        self._warm_thread = warm_lookups(
+            nb, probe_classes_upto(_HASH_BATCH_COUNT))
+
+    def wait_warm(self, timeout: "float | None" = None) -> None:
+        """Block until the lookup programs last asked for are built
+        (tests; a probe never waits — it compiles what it misses)."""
+        t = self._warm_thread
+        if t is not None:
+            t.join(timeout)
 
     # -- introspection (the guarded-by sweep found all four of these
     #    reading _cuckoo/_datablob lock-free while rebuild/load_snapshot
@@ -283,6 +315,7 @@ class DedupIndex:
             else:
                 hit = self._cuckoo.contains_exact(digest)
         METRICS.add("probes")
+        trace.tally(index_contains=1)
         if hit:
             METRICS.add("hits")
         return hit
@@ -325,6 +358,7 @@ class DedupIndex:
                 hits = out.count(True)
                 fps = maybe.count(True) - hits
         METRICS.add("probes", len(digests))
+        trace.tally(index_hits=hits, index_false_positives=fps)
         if hits:
             METRICS.add("hits", hits)
         if fps:
@@ -335,8 +369,15 @@ class DedupIndex:
         """Maybe-present bool[N] for uint8[N,32] — numpy host mirror on
         CPU (no jit dispatch per probe batch), the vmap'd device lookup
         when an accelerator is the default jax backend (the table
-        uploads once per insert batch and is reused across probes;
-        which of the two is faster there: not measured)."""
+        uploads once per insert batch and is reused across probes).
+        Which of the two is faster there, at a 2 GiB table and ~211
+        digests a probe (PERF.md, PR 36, ``index-at-size.serial`` and
+        its host): the host twin, 0.10 ms against 1.6-1.8 ms for a
+        device trip with a clean table — and after an insert the device
+        trip carries the table's copy, 0.22 s, which a volume of new
+        chunks pays at every flush: a third of the writer's life
+        (ROADMAP S7)."""
+        trace.tally(index_probe_trips=1, index_probe_digests=len(arr))
         if jaxenv.pick_twin("index.probe"):
             return self._cuckoo.probe(arr)
         return self._cuckoo.probe_host(arr)
@@ -356,8 +397,10 @@ class DedupIndex:
                 new = True
             else:
                 new = self._cuckoo.insert(digest)
+            self._warm_lookups()
         if new:
             METRICS.add("inserts")
+            trace.tally(index_inserts=1)
         return new
 
     def insert_many(self, digests: Iterable[bytes]) -> int:
@@ -371,8 +414,10 @@ class DedupIndex:
                     n += self._insert_batch_spill(digests[i:i + (1 << 16)])
             else:
                 n = self._cuckoo.insert_many(digests)
+            self._warm_lookups()
         if n:
             METRICS.add("inserts", n)
+            trace.tally(index_inserts=n)
         return n
 
     def _insert_batch_spill(self, batch: "list[bytes]") -> int:
@@ -478,6 +523,7 @@ class DedupIndex:
             if len(live):
                 self._cuckoo.insert_fp_many(
                     [live[i].tobytes() for i in range(len(live))])
+                self._warm_lookups()
         if len(live):
             METRICS.add("inserts", len(live))
         return len(live)

@@ -100,6 +100,22 @@ CLOCK_TOTALS = {**{"writer_" + k: 0.0 for k in WRITER_STATES + ("cpu_s",)},
 MUX_COUNTS = ("frames_tx", "drain_waits", "bytes_rx", "rx_direct_bytes")
 MUX_TOTALS = dict.fromkeys(MUX_COUNTS, 0)
 
+# What the dedup index did for the session, counted on the writer's own
+# thread where it happens (``trace.tally`` in pxar/chunkindex.py and
+# ops/cuckoo.py; docs/observability.md "The index"): batched probes
+# (trips), the digests they asked and, on a device host, what those were
+# padded to; the probes' confirmed hits and the filter positives the
+# exact tier rejected; the store's scalar asks (contains) and the digests
+# it inserted; the times a probe found the table changed and copied it
+# to the device, whole, with the bytes and the wall seconds of those
+# copies; and the seconds of the lookups' device phase.  On the job's
+# record as ``index_*`` (with ``index_table_bytes``, the table's size at
+# the job's end), totalled here for /metrics.
+INDEX_COUNTS = ("probe_trips", "probe_digests", "probe_padded", "hits",
+                "false_positives", "contains", "inserts", "table_uploads",
+                "table_upload_bytes", "upload_s", "device_s")
+INDEX_TOTALS = {"index_" + k: 0 for k in INDEX_COUNTS}
+
 
 def _get_abortable(q: "queue.Queue", abort: "threading.Event | None"):
     """Blocking queue get that returns _ABORTED instead of waiting
@@ -299,7 +315,8 @@ class RemoteTreeBackup:
         self.pump = dict.fromkeys(PUMP_TOTALS, 0)
         # the session's clocks: the writer thread's, and the pump's waits
         self.writer_clock = trace.ThreadClock(
-            dict.fromkeys(WRITER_STATES, 0.0), label="writer")
+            dict.fromkeys(WRITER_STATES, 0.0), label="writer",
+            counts=dict.fromkeys(INDEX_TOTALS, 0))
         self.waits = dict.fromkeys(PUMP_WAITS, 0.0)
         # until the agent answers that it does not know read_many
         self._batching = True
@@ -332,6 +349,14 @@ class RemoteTreeBackup:
         stats = getattr(conn, "stats", {})
         return {k: stats[k] for k in MUX_COUNTS if k in stats}
 
+    def _index_table_bytes(self) -> int:
+        """The size of the dedup index's filter table now; 0 where the
+        session's store has none (the tests' fakes, a remote index)."""
+        chunks = getattr(getattr(self.session, "writer", None), "store",
+                         None)
+        return int(getattr(getattr(chunks, "_index", None), "table_bytes",
+                           0))
+
     async def run(self) -> BackupResult:
         with trace.span("backup.pump") as sp:
             t0, loop_cpu0 = time.perf_counter(), time.thread_time()
@@ -340,8 +365,9 @@ class RemoteTreeBackup:
                 return await self._run()
             finally:
                 # one record a job: the pump's counts, the writer's
-                # clock (its thread is joined by now), the pump's waits
-                # and the loop thread's CPU clock at both ends
+                # clock (its thread is joined by now) with what the
+                # index did on that thread, the pump's waits and the
+                # loop thread's CPU clock at both ends
                 clocks = {
                     **{"writer_" + k: v
                        for k, v in self.writer_clock.seconds.items()},
@@ -349,8 +375,11 @@ class RemoteTreeBackup:
                     "pump_life_s": time.perf_counter() - t0,
                     "loop_cpu0": loop_cpu0, "loop_cpu1": time.thread_time()}
                 mux = {k: v - mux0[k] for k, v in self._mux_counts().items()}
+                index = {**self.writer_clock.counts,
+                         "index_table_bytes": self._index_table_bytes()}
                 sp.set(job=self.log.scope.get("job_id", ""), **self.pump,
-                       **clocks, **{"mux_" + k: v for k, v in mux.items()})
+                       **clocks, **{"mux_" + k: v for k, v in mux.items()},
+                       **index)
                 for k, v in self.pump.items():
                     PUMP_TOTALS[k] += v
                 for k, v in mux.items():
@@ -359,8 +388,11 @@ class RemoteTreeBackup:
                     if k in CLOCK_TOTALS:       # the states and the waits
                         CLOCK_TOTALS[k] += v
                 CLOCK_TOTALS["loop_cpu_s"] = clocks["loop_cpu1"]
+                for k in INDEX_TOTALS:
+                    INDEX_TOTALS[k] += index[k]
                 self.log.info("session clocks: %s", " ".join(
-                    f"{k}={v:.6f}" for k, v in clocks.items()))
+                    f"{k}={v}" if isinstance(v, int) else f"{k}={v:.6f}"
+                    for k, v in {**clocks, **index}.items()))
 
     async def _run(self) -> BackupResult:
         # hand the job's trace context to the writer thread: ingest

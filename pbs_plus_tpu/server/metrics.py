@@ -659,6 +659,29 @@ class MetricsRegistry:
               "and whatever else shares the loop), as read at the last "
               "backup pump's end",
               [({}, float(ct["loop_cpu_s"]))])
+        # what the dedup index did for the sessions, counted on their
+        # writer threads (server/backup_job.py INDEX_TOTALS;
+        # docs/observability.md "The index"), summed over the jobs that
+        # ended
+        it = dict(_backup_job.INDEX_TOTALS)
+        gauge("pbs_plus_index_probe_digests_total",
+              "Digests the backup writers' batched index probes asked, "
+              "by result: confirmed present (hit), a filter positive the "
+              "exact tier rejected (false_positive), absent (miss)",
+              [({"result": "hit"}, float(it["index_hits"])),
+               ({"result": "false_positive"},
+                float(it["index_false_positives"])),
+               ({"result": "miss"},
+                float(it["index_probe_digests"] - it["index_hits"]
+                      - it["index_false_positives"]))])
+        gauge("pbs_plus_index_table_upload_bytes_total",
+              "Bytes of the index's filter table copied to the device "
+              "inside backup writers' probes: the whole table, at every "
+              "probe that follows an insert",
+              [({}, float(it["index_table_upload_bytes"]))])
+        gauge("pbs_plus_index_upload_seconds_total",
+              "Wall seconds the backup writers stood at those copies",
+              [({}, float(it["index_upload_s"]))])
         gauge("pbs_plus_device_compilations_total",
               "Programs jax built or loaded from its cache since the "
               "device ops were loaded; one that moves while backups run "

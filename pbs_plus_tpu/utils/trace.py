@@ -53,6 +53,10 @@ measurement substrate:
   device batcher's thread and every backup session's writer thread keep
   one (docs/observability.md "The session's clocks").  Counters, like a
   round trip's phase clocks: they run whether or not spans are enabled.
+  A clock also carries the thread's own ``counts``: ``tally(...)`` adds
+  to them from wherever the work happens on that thread (the dedup
+  index's probes and inserts: docs/observability.md "The index"), so
+  sessions running at once never share a count.
 - **Job records.**  Closed ``backup.pump`` spans — one a backup job,
   carrying the session's clocks — are also kept in a table of their own
   (``job_records()``), which the ring's churn does not reach.
@@ -500,13 +504,16 @@ class ThreadClock:
     (``time.thread_time``): life less CPU is time blocked — on a queue,
     the device, a file, the interpreter lock.  ``seconds`` may be the
     owner's counters dict (``DeviceFeeder.stats``); ``label`` names the
-    profiler annotations of ``state()`` blocks, ``<label>.<state>``.
+    profiler annotations of ``state()`` blocks, ``<label>.<state>``;
+    ``counts`` is what ``tally()`` adds to on this thread.
     Every call but the constructor is made on the clocked thread."""
 
-    __slots__ = ("seconds", "label", "_t0", "_t", "_cpu")
+    __slots__ = ("seconds", "counts", "label", "_t0", "_t", "_cpu")
 
-    def __init__(self, seconds: "dict | None" = None, label: str = ""):
+    def __init__(self, seconds: "dict | None" = None, label: str = "",
+                 counts: "dict | None" = None):
         self.seconds = {} if seconds is None else seconds
+        self.counts = {} if counts is None else counts
         self.label = label
         self._t0 = self._t = self._cpu = 0
 
@@ -567,6 +574,17 @@ def spent(state: str, now_ns: "int | None" = None) -> None:
     clock = getattr(_thread, "clock", None)
     if clock is not None:
         clock.spent(state, now_ns)
+
+
+def tally(**counts) -> None:
+    """Add to the calling thread's own counts (its clock's ``counts``):
+    work counted where it happens, on the thread that does it; nothing
+    on a thread with no clock."""
+    clock = getattr(_thread, "clock", None)
+    if clock is not None:
+        mine = clock.counts
+        for key, n in counts.items():
+            mine[key] = mine.get(key, 0) + n
 
 
 class _State:

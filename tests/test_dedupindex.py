@@ -148,6 +148,111 @@ def test_probe_batch_exact_and_fp_counting():
     assert idx.resident_bytes > idx.table_bytes
 
 
+@pytest.fixture
+def device_host(monkeypatch):
+    """A host whose jax backend passes for an accelerator: the index
+    takes its device twin and builds its lookup programs, on the CPU
+    backend."""
+    from pbs_plus_tpu.utils import jaxenv
+    monkeypatch.setattr(jaxenv, "on_accelerator", lambda: True)
+
+
+@pytest.mark.parametrize("spill", [False, True], ids=["ram", "spill"])
+@pytest.mark.parametrize("n", [1, 63, 64, 65, 256, 257, 512])
+def test_device_probe_across_classes_answers_as_a_set(
+        device_host, tmp_path, n, spill):
+    """ISSUE 36: batches that fill a probe class, fall one short and run
+    one over, between scalar inserts and discards: the device twin
+    answers as a Python set does, and the thread's own counts add up —
+    a table copy at every probe that follows a change and at no other,
+    the padding by class."""
+    from pbs_plus_tpu.ops import cuckoo
+    from pbs_plus_tpu.utils import trace
+    idx = DedupIndex(budget_mb=1,
+                     spill_dir=str(tmp_path) if spill else None)
+    idx.mark_booted()
+    pool = _digests(2 * n + 8, seed=36)
+    known = set(pool[:n:2])                 # every other one of a batch
+    idx.insert_many(sorted(known))
+    clock = trace.ThreadClock(label="test")
+    uploads0 = cuckoo.stats["table_uploads"]
+    rounds = [pool[:n], pool[:n], pool[n:2 * n], pool[1:n + 1]]
+    dirty = [True, False, True, True]       # preloaded; clean; then both
+    hits = 0
+    with trace.clocked(clock):
+        for k, batch in enumerate(rounds):
+            assert idx.probe_batch(batch) == [d in known for d in batch]
+            hits += sum(d in known for d in batch)
+            if k == 1:                      # a scalar insert, new
+                assert idx.insert(pool[2 * n]) is True
+                known.add(pool[2 * n])
+            elif k == 2:                    # a discard, then one again
+                assert idx.discard(pool[0]) is (pool[0] in known)
+                assert idx.insert(pool[0]) is True
+                assert idx.insert(pool[0]) is False
+                known.add(pool[0])
+    c = clock.counts
+    klass = next(k for k in (64, 256, 1024) if k >= n)
+    assert c["index_probe_trips"] == len(rounds)
+    assert c["index_probe_digests"] == len(rounds) * n
+    assert c["index_probe_padded"] == len(rounds) * klass
+    assert c["index_table_uploads"] == sum(dirty) \
+        == cuckoo.stats["table_uploads"] - uploads0
+    assert c["index_table_upload_bytes"] == sum(dirty) * idx.table_bytes
+    assert c["index_upload_s"] > 0 and c["index_device_s"] > 0
+    assert c["index_inserts"] == 2 and c["index_false_positives"] == 0
+    assert c["index_hits"] == hits
+
+
+def test_lookup_programs_are_built_before_a_writer_needs_them(device_host):
+    """After boot on a device host a probe of each class a flush can
+    produce compiles nothing on its own thread; neither after the table
+    has grown.  On a CPU host nothing is built."""
+    from pbs_plus_tpu.ops import cuckoo
+    from pbs_plus_tpu.ops.cuckoo import probe_classes_upto
+    from pbs_plus_tpu.pxar.transfer import _HASH_BATCH_COUNT
+    from pbs_plus_tpu.utils import jaxenv
+    classes = probe_classes_upto(_HASH_BATCH_COUNT)
+    assert classes == (64, 256, 1024)
+    idx = DedupIndex(budget_mb=1)
+    nb = idx.n_buckets
+    for k in classes:                       # another test's, perhaps
+        cuckoo._programs.pop((nb, k), None)
+        cuckoo._programs.pop((2 * nb, k), None)
+    probes = [_digests(k, seed=k) for k in (1, 64, 65, 256, 257, 512)]
+    idx.probe_batch(_digests(2, seed=1))    # before boot: nothing built
+    assert not any((nb, k) in cuckoo._programs for k in classes)
+    idx.mark_booted()
+    idx.wait_warm(60)
+    assert all((nb, k) in cuckoo._programs for k in classes)
+    before = jaxenv.thread_compiles()
+    for batch in probes:
+        assert idx.probe_batch(batch) == [False] * len(batch)
+    assert jaxenv.thread_compiles() == before
+    # growth: past load 0.85 the table doubles, and the index asks for
+    # the new shape's programs itself
+    idx.insert_many(_digests(int(nb * SLOTS * 0.85) + 1, seed=99))
+    assert idx.n_buckets == 2 * nb
+    idx.wait_warm(60)
+    assert all((2 * nb, k) in cuckoo._programs for k in classes)
+    before = jaxenv.thread_compiles()
+    for batch in probes:
+        assert idx.probe_batch(batch) == [False] * len(batch)
+    assert jaxenv.thread_compiles() == before
+
+
+def test_cpu_host_builds_no_lookup_program():
+    from pbs_plus_tpu.ops import cuckoo
+    idx = DedupIndex(budget_mb=2)
+    for k in (64, 256, 1024):
+        cuckoo._programs.pop((idx.n_buckets, k), None)
+    idx.mark_booted()
+    idx.wait_warm(60)
+    idx.insert_many(_digests(100, seed=3))
+    assert idx.probe_batch(_digests(100, seed=3)) == [True] * 100
+    assert not any(key[0] == idx.n_buckets for key in cuckoo._programs)
+
+
 def test_dedupindex_discard_and_reinsert():
     idx = DedupIndex(budget_mb=1)
     d = _digests(1, seed=10)[0]

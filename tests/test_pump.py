@@ -674,7 +674,9 @@ RECORD_KEYS = ({"job"} | set(bj.PUMP_TOTALS)
                | {"writer_" + k for k in bj.WRITER_STATES}
                | {"writer_cpu_s", "writer_life_s"}
                | {"pump_" + k for k in bj.PUMP_WAITS}
-               | {"pump_life_s", "loop_cpu0", "loop_cpu1"})
+               | {"pump_life_s", "loop_cpu0", "loop_cpu1"}
+               | {"index_" + k for k in bj.INDEX_COUNTS}
+               | {"index_table_bytes"})
 
 
 class _StreamSession:
@@ -839,7 +841,8 @@ def test_the_log_the_traces_endpoint_and_metrics_show_the_same_clocks(
         for line in expo.splitlines():
             if line.startswith(("pbs_plus_writer_thread_seconds_total{",
                                 "pbs_plus_pump_wait_seconds_total{",
-                                "pbs_plus_loop_cpu_seconds_total")):
+                                "pbs_plus_loop_cpu_seconds_total",
+                                "pbs_plus_index_")):
                 name, value = line.rsplit(" ", 1)
                 out[name] = float(value)
         return out
@@ -855,7 +858,7 @@ def test_the_log_the_traces_endpoint_and_metrics_show_the_same_clocks(
     logged = {k: float(v) for k, v in (
         kv.split("=") for kv in lines[0].getMessage().split(": ")[1].split())}
     clocks = {k: v for k, v in attrs.items()
-              if k.startswith(("writer_", "pump_", "loop_"))}
+              if k.startswith(("writer_", "pump_", "loop_", "index_"))}
     assert set(logged) == set(clocks)
     assert logged == pytest.approx(clocks, abs=1e-6)
     # the endpoint: the ring's span, and the table's record
@@ -875,6 +878,19 @@ def test_the_log_the_traces_endpoint_and_metrics_show_the_same_clocks(
         name = 'pbs_plus_pump_wait_seconds_total{on="%s"}' % on
         assert moved[name] == pytest.approx(attrs["pump_" + key], abs=1e-6)
     assert after["pbs_plus_loop_cpu_seconds_total"] == attrs["loop_cpu1"]
+    # and by what the index did for it (ISSUE 36)
+    assert attrs["index_probe_trips"] > 0 and attrs["index_inserts"] > 0
+    by_result = {r: moved['pbs_plus_index_probe_digests_total{result="%s"}'
+                       % r] for r in ("hit", "false_positive", "miss")}
+    assert by_result == {
+        "hit": attrs["index_hits"],
+        "false_positive": attrs["index_false_positives"],
+        "miss": attrs["index_probe_digests"] - attrs["index_hits"]
+        - attrs["index_false_positives"]}
+    assert moved["pbs_plus_index_table_upload_bytes_total"] \
+        == attrs["index_table_upload_bytes"]
+    assert moved["pbs_plus_index_upload_seconds_total"] \
+        == pytest.approx(attrs["index_upload_s"], abs=1e-6)
 
 
 # ------------------------------------------------ blocks served as views
