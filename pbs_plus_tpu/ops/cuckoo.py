@@ -11,24 +11,29 @@ bucket₁ = digest word 2 masked, bucket₂ = bucket₁ ^ mix(fingerprint).
 Lookups are a fully-parallel gather+compare per digest (vmap over the
 batch).  Inserts run on a host-side numpy mirror (single-writer, matching
 the reference's async single-writer index update queue, SURVEY §2.10) with
-cuckoo eviction + table growth; ``device_table`` re-uploads after a batch
-of inserts.  The host dict stays authoritative — a 64-bit-fingerprint
-false positive (~2⁻⁶⁴ per probe) is confirmed against it before a chunk
-upload is skipped.
+cuckoo eviction + table growth.  The host dict stays authoritative — a
+64-bit-fingerprint false positive (~2⁻⁶⁴ per probe) is confirmed against
+it before a chunk upload is skipped.
 
-What that re-upload costs at a deployment's table (PERF.md, PR 36, cell
-``index-at-size.serial``: a 2 GiB table in HBM, 64 KiB chunks, ~211
-digests a probe, one TPU v5e and its 13-core host): a probe with a clean
-table is 1.6-1.8 ms of host clock, 0.9 ms of it round the program; the
-host twin answers the same 211 digests from the mirror in 0.10 ms; and
-the table's copy after an insert takes 0.22 s — the host's threads
-first relay ``uint32[NB, 4, 2]`` out into the device's tiling
-(``Transpose`` in a profile: 150 MB of trace a copy; the same bytes
-sent flat need none) — which every flush of a volume of new chunks
-pays: 67 copies a 1,085 MiB volume, a third of the writer thread's life
-(ROADMAP S7).  ``_lookup`` is one program a (table
-shape, probe class); ``warm_lookups`` builds them from shapes alone,
-ahead of the writers (``DedupIndex._warm_lookups``).
+The device's copy of the table stays resident between probes and is
+kept in step with the mirror in place: every write to a row of the
+mirror marks its bucket, and the next probe sends the marked buckets'
+indices and rows (36 bytes a bucket, padded to a class) to a program
+that writes them into the resident table, which it is given to reuse
+(``donate_argnums``) — no second table is allocated.  The table goes
+whole only at the first probe, after a rebuild (growth, any change of
+its shape), or where the change, padded to its class, has more than a
+1,024th of the table's buckets (``_goes_whole``).  Why (PERF.md, cell
+``index-at-size.serial``: a 2 GiB table in HBM, ~211 digests a probe,
+one TPU v5e and its 13-core host): the whole copy took 0.22 s — the
+host's threads relay ``uint32[NB, 4, 2]`` out into the device's tiling
+— and every flush of a volume of new chunks paid it, a third of the
+writer thread's life, to change ~211 buckets of 32 bytes (ROADMAP S7).
+A probe with a clean table is 1.6-1.8 ms of host clock, 0.9 ms of it
+round the program.  ``_lookup`` is one program a (table shape, probe
+class) and ``_scatter`` one a (table shape, change class);
+``warm_lookups`` builds both from shapes alone, ahead of the writers
+(``DedupIndex._warm_lookups``).
 """
 
 from __future__ import annotations
@@ -50,19 +55,29 @@ SLOTS = 4
 _MIX = np.uint32(0x9E3779B1)
 _MAX_KICKS = 500
 BUCKET_BYTES = SLOTS * 2 * 4        # uint32[SLOTS, 2] per bucket
-# padded digest counts of a device probe: 64, 256, 1024, … (powers of four)
+# padded digest counts of a device probe: 64, 256, 1024, … (powers of four);
+# a change of the table is padded to the same classes of buckets
 _PROBE_CLASSES = tuple(1 << k for k in range(6, 31, 2))
+DELTA_BUCKET_BYTES = BUCKET_BYTES + 4   # a changed bucket's row and index
+# a bucket written in place costs what the whole copy spends on ~660
+# buckets (one TPU v5e, a 2 GiB table: 2.2 µs a step of ``_scatter``'s
+# loop against 0.1 ns a byte of the copy; PERF.md, section 6)
+_STEP_BUCKETS = 1024
 
 
 # device probes, mirror of rolling_hash.stats: ``probes`` digests asked in
 # ``dispatches`` lookups (``bytes`` of digests, ``padded_bytes`` after
-# padding to a probe class), the table copied to the device for a probe
-# ``table_uploads`` times — whole, after any insert — and the five phase
-# clocks; the table's upload is inside ``h2d_s``.  Probes run on the
-# writers' threads, several at once: a trip adds to them under the lock.
+# padding to a probe class); the table brought up to date for a probe
+# ``table_uploads`` times whole and ``table_delta_uploads`` times by its
+# changed buckets (``table_delta_buckets`` of them, ``table_delta_bytes``
+# sent, padding included), ``table_upload_bytes`` the bytes of both; and
+# the five phase clocks — the table's update is inside ``h2d_s``.  Probes
+# run on the writers' threads, several at once: a trip adds to them under
+# the lock.
 stats = trace.device_stats("probe", {
     "dispatches": 0, "probes": 0, "bytes": 0, "padded_bytes": 0,
-    "table_uploads": 0, "table_upload_bytes": 0})
+    "table_uploads": 0, "table_upload_bytes": 0, "table_delta_uploads": 0,
+    "table_delta_buckets": 0, "table_delta_bytes": 0})
 _stats_lock = threading.Lock()
 
 
@@ -134,33 +149,65 @@ def _lookup(table: jax.Array, digests: jax.Array) -> jax.Array:
     return hit1 | hit2
 
 
-# ``_lookup`` built ahead of a writer's first probe, by (buckets, probe
-# class): a table of another shape and every probe class is a program of
-# its own, and one that compiles inside a probe stops a backup for as
+@functools.partial(jax.jit, donate_argnums=0)
+def _scatter(table: jax.Array, idx: jax.Array, rows: jax.Array) -> jax.Array:
+    """table uint32[NB, SLOTS, 2], given to be reused; idx int32[K],
+    padded by repeating the last (the same row set twice); rows
+    uint32[K, SLOTS, 2] → the table with those buckets' rows replaced,
+    written in place.  One row a step: on a TPU the table lies with the
+    buckets minor (``{0,2,1}``), and a ``scatter`` of whole rows has the
+    compiler relay it out first — 32 times its size, refused at 2 GiB —
+    where a row's ``dynamic_update_slice`` writes it where it lies.
+    Eight steps an iteration: on one TPU v5e, at a 2 GiB table, 65,536
+    rows took 147 ms where one step an iteration took 207 (PERF.md,
+    section 6)."""
+    def put(i, t):
+        row = jax.lax.dynamic_slice_in_dim(rows, i, 1)
+        return jax.lax.dynamic_update_slice_in_dim(t, row, idx[i], axis=0)
+    return jax.lax.fori_loop(0, idx.shape[0], put, table, unroll=8)
+
+
+# ``_lookup`` and ``_scatter`` built ahead of a writer's first probe, by
+# (buckets, class): a table of another shape and every class is a program
+# of its own, and one that compiles inside a probe stops a backup for as
 # long as it takes.  A shape nobody built ahead still compiles at the
 # probe, as before (``round_trip`` warns).
 _programs: dict = {}
+_scatters: dict = {}
+
+
+def _goes_whole(n_buckets: int, k: int) -> bool:
+    """True where a change of ``k`` buckets would cost more written in
+    place than the whole table's copy: more than a ``_STEP_BUCKETS``-th
+    of its buckets (a 2 GiB table takes up to 65,536 in place, a 64 MiB
+    one up to 2,048)."""
+    return k * _STEP_BUCKETS > n_buckets
 
 
 def _build_lookups(n_buckets: int, classes) -> None:
-    for rows in classes:
-        key = (n_buckets, rows)
-        if key in _programs:
-            continue
+    table = jax.ShapeDtypeStruct((n_buckets, SLOTS, 2), jnp.uint32)
+    for k in classes:
+        key = (n_buckets, k)
         try:
-            _programs[key] = _lookup.lower(
-                jax.ShapeDtypeStruct((n_buckets, SLOTS, 2), jnp.uint32),
-                jax.ShapeDtypeStruct((rows, 32), jnp.uint8)).compile()
+            if key not in _programs:
+                _programs[key] = _lookup.lower(
+                    table, jax.ShapeDtypeStruct((k, 32), jnp.uint8)).compile()
+            if key not in _scatters and not _goes_whole(n_buckets, k):
+                _scatters[key] = _scatter.lower(
+                    table, jax.ShapeDtypeStruct((k,), jnp.int32),
+                    jax.ShapeDtypeStruct((k, SLOTS, 2), jnp.uint32)).compile()
         except Exception as e:      # the probe then compiles for itself
             L.warning("device.probe rows=%d buckets=%d not built ahead: %s",
-                      rows, n_buckets, e)
+                      k, n_buckets, e)
 
 
 def warm_lookups(n_buckets: int, classes) -> threading.Thread:
     """Build (or load from the persistent cache) the lookup programs of a
-    table of ``n_buckets`` at the probe classes ``classes``, from shapes
-    alone — no table, no lock — on a thread of its own, which is
-    returned: the caller's thread goes on."""
+    table of ``n_buckets`` at the probe classes ``classes``, and the
+    programs that write a change of as many buckets into it (those of the
+    classes that do not go whole), from shapes alone — no table, no lock
+    — on a thread of its own, which is returned: the caller's thread goes
+    on."""
     t = threading.Thread(target=_build_lookups, args=(n_buckets, classes),
                          name="index-warm", daemon=True)
     t.start()
@@ -186,7 +233,16 @@ class CuckooIndex:
         self.n_buckets = n_buckets
         self._table = np.zeros((n_buckets, SLOTS, 2), dtype=np.uint32)
         self._device_table: jax.Array | None = None
-        self._dirty = True
+        # what the device's copy lacks: the whole table (no copy yet, or
+        # the mirror was rebuilt), else the buckets in _marks[:_n_marks],
+        # noted after each write to a row (``_mark``); the probe that
+        # brings the copy up to date (``_sync``) and the lookup reading it
+        # run under the same lock, since the update gives the old array
+        # away
+        self._lock = threading.Lock()
+        self._whole = True                    # guarded-by: self._lock
+        self._marks = np.zeros(0, np.int32)   # guarded-by: self._lock
+        self._n_marks = 0                     # guarded-by: self._lock
         self._known: set[bytes] = set()       # authoritative
         self._rng = np.random.default_rng(seed)
         # filter-only mode (the spillable exact tier, pxar/digestlog.py):
@@ -222,7 +278,6 @@ class CuckooIndex:
         self._known.add(digest)
         fp0, fp1, b1, b2 = self._fp_bucket(digest)
         self._insert_fp(fp0, fp1, b1, b2)
-        self._dirty = True
         return True
 
     def discard(self, digest: bytes) -> bool:
@@ -242,12 +297,11 @@ class CuckooIndex:
             for s in range(SLOTS):
                 if row[s, 0] == fp0 and row[s, 1] == fp1:
                     row[s] = (0, 0)
-                    self._dirty = True
+                    self._mark(b)
                     return True
         # fingerprint not in the mirror (dropped during an eviction
         # overflow before a growth rebuild): the authoritative set is
         # already updated, so membership answers stay correct
-        self._dirty = True
         return True
 
     def discard_many(self, digests) -> int:
@@ -271,14 +325,17 @@ class CuckooIndex:
             for s in range(SLOTS):
                 if row[s, 0] == 0 and row[s, 1] == 0:
                     row[s] = (fp0, fp1)
+                    self._mark(b)
                     return True
-        # eviction chain
+        # eviction chain: every bucket on it is written
         b = b1
         cur = np.array([fp0, fp1], dtype=np.uint32)
+        chain = []
         for _ in range(_MAX_KICKS):
             s = int(self._rng.integers(0, SLOTS))
             victim = self._table[b, s].copy()
             self._table[b, s] = cur
+            chain.append(b)
             cur = victim
             vfp0 = int(cur[0])
             mask = self.n_buckets - 1
@@ -287,7 +344,10 @@ class CuckooIndex:
             for s2 in range(SLOTS):
                 if row[s2, 0] == 0 and row[s2, 1] == 0:
                     row[s2] = cur
+                    chain.append(b)
+                    self._mark(chain)
                     return True
+        self._mark(chain)
         if not grow:
             # mid-rebuild overflow: the rebuild loop doubles and retries
             # from a fresh source pass (the displaced fingerprint is
@@ -331,7 +391,6 @@ class CuckooIndex:
         else:
             fp0, fp1, b1, b2 = self._fp_bucket(digest)
             self._insert_fp(fp0, fp1, b1, b2)
-        self._dirty = True
 
     def insert_fp_many(self, digests: "list[bytes]") -> None:
         """Bulk fingerprint insert (filter-only mode): group-wise free
@@ -355,7 +414,6 @@ class CuckooIndex:
                 self._insert_fp(fp0, fp1, b1, b2)
                 if self.n_buckets != nb:
                     break              # the growth rebuild placed the rest
-        self._dirty = True
 
     def discard_fp(self, digest: bytes) -> None:
         """Zero the fingerprint slot (filter-only mode).  A twin digest
@@ -368,9 +426,8 @@ class CuckooIndex:
             for s in range(SLOTS):
                 if row[s, 0] == fp0 and row[s, 1] == fp1:
                     row[s] = (0, 0)
-                    self._dirty = True
+                    self._mark(b)
                     return
-        self._dirty = True
 
     def insert_many(self, digests: list[bytes]) -> int:
         """Bulk insert, vectorized: one numpy pass computes every
@@ -406,7 +463,6 @@ class CuckooIndex:
                     # _insert_fp grew the table, and the rebuild placed
                     # every known digest — the rest of the tail included
                     break
-        self._dirty = True
         return len(uniq)
 
     def _fp_buckets_vec(self, arr: np.ndarray):
@@ -447,6 +503,7 @@ class CuckooIndex:
             put = sel_i[fits]
             self._table[bs[fits], slot[fits], 0] = fp0[put]
             self._table[bs[fits], slot[fits], 1] = fp1[put]
+            self._mark(bs[fits])
             remaining[put] = False
         return np.flatnonzero(remaining)
 
@@ -459,6 +516,7 @@ class CuckooIndex:
         overflow mid-rebuild doubles the table and retries from a fresh
         source pass (no nested-grow recursion)."""
         while True:
+            self._mark_whole()
             self._table = np.zeros((self.n_buckets, SLOTS, 2),
                                    dtype=np.uint32)
             if self._place_all():
@@ -491,11 +549,71 @@ class CuckooIndex:
         return True
 
     # -- device probe -----------------------------------------------------
+    def _mark(self, buckets) -> None:
+        """Note buckets whose rows of the mirror were just written, for
+        the device's copy.  Called after the write: ``_sync`` takes the
+        marks before it reads the rows, so a write it missed stays marked
+        for the next probe.  Once the marks alone would send the table
+        whole, it goes whole, and nothing is noted until it has."""
+        with self._lock:
+            if self._whole:
+                return
+            b = np.asarray(buckets, dtype=np.int32).ravel()
+            n = self._n_marks + b.size
+            if _goes_whole(self.n_buckets, n):
+                self._whole, self._marks, self._n_marks = \
+                    True, np.zeros(0, np.int32), 0
+                return
+            if n > self._marks.size:
+                grown = np.empty(max(n, 2 * self._marks.size, 64), np.int32)
+                grown[:self._n_marks] = self._marks[:self._n_marks]
+                self._marks = grown
+            self._marks[self._n_marks:n] = b
+            self._n_marks = n
+
+    def _mark_whole(self) -> None:
+        """The mirror is rebuilt: the next probe sends it whole."""
+        with self._lock:
+            self._whole, self._marks, self._n_marks = \
+                True, np.zeros(0, np.int32), 0
+
+    def _sync(self) -> tuple:
+        """Bring the device's copy of the table up to the mirror; the
+        caller holds ``_lock``.  Returns what went: ``(None, 0, 0)``
+        where nothing had changed, ``("delta", bytes, buckets)`` where
+        the changed buckets were written into the resident table, and
+        ``("whole", bytes, 0)``."""
+        if not self._whole and self._device_table is not None:
+            if not self._n_marks:
+                return None, 0, 0
+            idx = np.unique(self._marks[:self._n_marks])
+            self._n_marks = 0
+            k = _probe_class(idx.size)
+            if not _goes_whole(self.n_buckets, k):
+                pad = np.full(k, idx[-1], dtype=np.int32)
+                pad[:idx.size] = idx
+                rows = self._table[pad]
+                scatter = _scatters.get((self.n_buckets, k), _scatter)
+                self._device_table = scatter(self._device_table, pad, rows)
+                return "delta", pad.nbytes + rows.nbytes, idx.size
+        # cleared before the mirror is read: a row written while it is
+        # copied is marked for the next probe.  The CPU backend may take
+        # an aligned array's memory as its own instead of copying it, and
+        # the mirror is written after the copy
+        self._whole, self._n_marks = False, 0
+        self._device_table = None           # not two tables at once
+        src = np.array(self._table) if jax.default_backend() == "cpu" \
+            else self._table
+        self._device_table = jnp.asarray(src)
+        return "whole", self._table.nbytes, 0
+
     def device_table(self) -> jax.Array:
-        if self._dirty or self._device_table is None:
-            self._device_table = jnp.asarray(self._table)
-            self._dirty = False
-        return self._device_table
+        """The device's copy of the table, brought up to date.  The next
+        update after a change writes into this array and takes it over:
+        what the caller holds is valid until the next probe."""
+        with self._lock:
+            self._sync()
+            return self._device_table
 
     def probe(self, digests: np.ndarray | jax.Array) -> np.ndarray:
         """digests uint8[N,32] → bool[N] (maybe-present; exact-confirm via
@@ -512,26 +630,39 @@ class CuckooIndex:
                 padded[:n] = arr
             rt.shape = f"rows={n_pad} buckets={self.n_buckets}"
             mine = {"index_probe_padded": n_pad}
-            with rt.phase("h2d"):
-                # the table too, whole, after any insert: its own seconds
-                # apart from the digests' copy
-                carried = self._dirty or self._device_table is None
-                t0 = time.perf_counter()
-                table = self.device_table().block_until_ready()
-                if carried:
-                    upload_s = time.perf_counter() - t0
-                    nbytes = self._table.nbytes
-                    rt.add(table_uploads=1, table_upload_bytes=nbytes)
-                    rt.attrs["upload_s"] = upload_s
-                    mine.update(index_table_uploads=1,
-                                index_table_upload_bytes=nbytes,
-                                index_upload_s=upload_s)
-                dd = jnp.asarray(padded).block_until_ready()
-            rt.add(dispatches=1, probes=n, bytes=arr.nbytes,
-                   padded_bytes=padded.nbytes)
-            lookup = _programs.get((self.n_buckets, n_pad), _lookup)
-            with rt.phase("device"):
-                dhit = lookup(table, dd).block_until_ready()
+            # the table's update gives the old array away: no other
+            # thread's may come between it and the lookup that reads it
+            with self._lock:
+                with rt.phase("h2d"):
+                    # the table's update, where it changed: its own
+                    # seconds apart from the digests' copy
+                    t0 = time.perf_counter()
+                    kind, nbytes, changed = self._sync()
+                    table = self._device_table.block_until_ready()
+                    if kind is not None:
+                        upload_s = time.perf_counter() - t0
+                        rt.attrs["upload_s"] = upload_s
+                        mine.update(index_table_upload_bytes=nbytes,
+                                    index_upload_s=upload_s)
+                    if kind == "whole":
+                        rt.add(table_uploads=1, table_upload_bytes=nbytes)
+                        mine["index_table_uploads"] = 1
+                    elif kind == "delta":
+                        rt.shape = (f"delta={_probe_class(changed)} "
+                                    f"buckets={self.n_buckets}")
+                        rt.add(table_delta_uploads=1,
+                               table_delta_buckets=changed,
+                               table_delta_bytes=nbytes,
+                               table_upload_bytes=nbytes)
+                        mine.update(index_table_delta_uploads=1,
+                                    index_table_delta_buckets=changed)
+                    dd = jnp.asarray(padded).block_until_ready()
+                rt.shape = f"rows={n_pad} buckets={self.n_buckets}"
+                rt.add(dispatches=1, probes=n, bytes=arr.nbytes,
+                       padded_bytes=padded.nbytes)
+                lookup = _programs.get((self.n_buckets, n_pad), _lookup)
+                with rt.phase("device"):
+                    dhit = lookup(table, dd).block_until_ready()
             trace.tally(index_device_s=rt.attrs["device_s"], **mine)
             with rt.phase("d2h"):
                 hit = np.asarray(dhit)

@@ -222,8 +222,12 @@ class DedupIndex:
         """On a device host, have the lookup programs of the table's
         shape built before a writer needs them: one per probe class a
         hash batch can produce (``transfer._HASH_BATCH_COUNT`` digests at
-        most a flush), at boot (whose loaders `rebuild` or `load_snapshot`
-        it) and again whenever an insert has changed the table's shape —
+        most a flush), and the programs that write a change of as many
+        buckets into the device's table (a flush's inserts change a
+        bucket a digest, and an eviction chain up to 500 more: 1,012 fit
+        the class 1024), at boot (whose loaders `rebuild` or
+        `load_snapshot` it) and again whenever an insert has changed the
+        table's shape —
         on a thread of their own (``ops.cuckoo.warm_lookups``),
         so neither the boot nor the insert that grew the table waits.
         Nothing on a CPU host: the host twin has no program."""
@@ -368,14 +372,12 @@ class DedupIndex:
     def _probe_arr(self, arr: np.ndarray) -> np.ndarray:
         """Maybe-present bool[N] for uint8[N,32] — numpy host mirror on
         CPU (no jit dispatch per probe batch), the vmap'd device lookup
-        when an accelerator is the default jax backend (the table
-        uploads once per insert batch and is reused across probes).
-        Which of the two is faster there, at a 2 GiB table and ~211
-        digests a probe (PERF.md, PR 36, ``index-at-size.serial`` and
-        its host): the host twin, 0.10 ms against 1.6-1.8 ms for a
-        device trip with a clean table — and after an insert the device
-        trip carries the table's copy, 0.22 s, which a volume of new
-        chunks pays at every flush: a third of the writer's life
+        when an accelerator is the default jax backend (the table stays
+        on the device, and a probe after inserts writes the buckets they
+        changed into it: ``CuckooIndex._sync``).  At a 2 GiB table and
+        ~211 digests a probe (PERF.md, ``index-at-size.serial``
+        and its host) the host twin answers in 0.10 ms against 1.6-1.8 ms
+        for a device trip with a clean table: 0.1 s of a volume's ~27 s
         (ROADMAP S7)."""
         trace.tally(index_probe_trips=1, index_probe_digests=len(arr))
         if jaxenv.pick_twin("index.probe"):

@@ -106,14 +106,17 @@ MUX_TOTALS = dict.fromkeys(MUX_COUNTS, 0)
 # (trips), the digests they asked and, on a device host, what those were
 # padded to; the probes' confirmed hits and the filter positives the
 # exact tier rejected; the store's scalar asks (contains) and the digests
-# it inserted; the times a probe found the table changed and copied it
-# to the device, whole, with the bytes and the wall seconds of those
-# copies; and the seconds of the lookups' device phase.  On the job's
-# record as ``index_*`` (with ``index_table_bytes``, the table's size at
-# the job's end), totalled here for /metrics.
+# it inserted; the times a probe found the table changed and brought the
+# device's copy up to date — whole (``table_uploads``) or by the buckets
+# that changed (``table_delta_uploads``, ``table_delta_buckets``) — with
+# the bytes and the wall seconds of both kinds; and the seconds of the
+# lookups' device phase.  On the job's record as ``index_*`` (with
+# ``index_table_bytes``, the table's size at the job's end), totalled
+# here for /metrics.
 INDEX_COUNTS = ("probe_trips", "probe_digests", "probe_padded", "hits",
                 "false_positives", "contains", "inserts", "table_uploads",
-                "table_upload_bytes", "upload_s", "device_s")
+                "table_upload_bytes", "upload_s", "device_s",
+                "table_delta_uploads", "table_delta_buckets")
 INDEX_TOTALS = {"index_" + k: 0 for k in INDEX_COUNTS}
 
 

@@ -542,15 +542,26 @@ class MetricsRegistry:
               "Seconds the calling (writer) threads spent in the host "
               "engine's hashlib calls, summed over threads",
               [({}, float(st["host_s"])) for st in host])
+        pr = dev.get("probe")
         gauge("pbs_plus_device_table_uploads_total",
-              "Times a probe found the dedup index's table dirtied by "
-              "an insert and copied it to the device again, whole",
-              [({}, float(dev["probe"]["table_uploads"]))]
-              if "probe" in dev else [])
+              "Times a probe found the dedup index's table changed since "
+              "the device's copy and brought the copy up to date, by "
+              "kind: the whole table (the first probe, after a rebuild, "
+              "or a change of over a 1,024th of its buckets) or the "
+              "changed buckets written into it (delta)",
+              [({"kind": "whole"}, float(pr["table_uploads"])),
+               ({"kind": "delta"}, float(pr["table_delta_uploads"]))]
+              if pr else [])
         gauge("pbs_plus_device_table_upload_bytes_total",
-              "Bytes of those table copies",
-              [({}, float(dev["probe"]["table_upload_bytes"]))]
-              if "probe" in dev else [])
+              "Bytes of those updates, by kind",
+              [({"kind": "whole"}, float(pr["table_upload_bytes"]
+                                         - pr["table_delta_bytes"])),
+               ({"kind": "delta"}, float(pr["table_delta_bytes"]))]
+              if pr else [])
+        gauge("pbs_plus_device_table_delta_buckets_total",
+              "Changed buckets the delta updates wrote into the device's "
+              "table (before padding to a class)",
+              [({}, float(pr["table_delta_buckets"]))] if pr else [])
         gauge("pbs_plus_feeder_thread_seconds_total",
               "The device batcher thread's life by state: inside a scan "
               "or a hash dispatch, idle with both queues empty, "
@@ -674,13 +685,18 @@ class MetricsRegistry:
                ({"result": "miss"},
                 float(it["index_probe_digests"] - it["index_hits"]
                       - it["index_false_positives"]))])
+        gauge("pbs_plus_index_table_uploads_total",
+              "Times a backup writer's probe brought the device's copy "
+              "of the index's filter table up to date, by kind: whole, "
+              "or the buckets changed since the last probe (delta)",
+              [({"kind": "whole"}, float(it["index_table_uploads"])),
+               ({"kind": "delta"}, float(it["index_table_delta_uploads"]))])
         gauge("pbs_plus_index_table_upload_bytes_total",
-              "Bytes of the index's filter table copied to the device "
-              "inside backup writers' probes: the whole table, at every "
-              "probe that follows an insert",
+              "Bytes sent to the device to bring the index's filter table "
+              "up to date inside backup writers' probes, whole and delta",
               [({}, float(it["index_table_upload_bytes"]))])
         gauge("pbs_plus_index_upload_seconds_total",
-              "Wall seconds the backup writers stood at those copies",
+              "Wall seconds the backup writers stood at those updates",
               [({}, float(it["index_upload_s"]))])
         gauge("pbs_plus_device_compilations_total",
               "Programs jax built or loaded from its cache since the "

@@ -164,18 +164,21 @@ def test_device_probe_across_classes_answers_as_a_set(
     """ISSUE 36: batches that fill a probe class, fall one short and run
     one over, between scalar inserts and discards: the device twin
     answers as a Python set does, and the thread's own counts add up —
-    a table copy at every probe that follows a change and at no other,
-    the padding by class."""
+    an update of the device's table at every probe that follows a change
+    and at no other (whole the first time, the changed buckets after;
+    in spill mode the preload's own probe made the first, and a preload
+    of more than 64 goes whole again), bytes = whole uploads × the table's
+    bytes + the deltas' bytes, the padding by class."""
     from pbs_plus_tpu.ops import cuckoo
     from pbs_plus_tpu.utils import trace
-    idx = DedupIndex(budget_mb=1,
+    idx = DedupIndex(budget_mb=2,
                      spill_dir=str(tmp_path) if spill else None)
     idx.mark_booted()
     pool = _digests(2 * n + 8, seed=36)
     known = set(pool[:n:2])                 # every other one of a batch
     idx.insert_many(sorted(known))
     clock = trace.ThreadClock(label="test")
-    uploads0 = cuckoo.stats["table_uploads"]
+    stats0 = dict(cuckoo.stats)
     rounds = [pool[:n], pool[:n], pool[n:2 * n], pool[1:n + 1]]
     dirty = [True, False, True, True]       # preloaded; clean; then both
     hits = 0
@@ -191,14 +194,22 @@ def test_device_probe_across_classes_answers_as_a_set(
                 assert idx.insert(pool[0]) is True
                 assert idx.insert(pool[0]) is False
                 known.add(pool[0])
-    c = clock.counts
+    c = dict.fromkeys(("index_table_uploads", "index_table_delta_uploads",
+                       "index_table_delta_buckets"), 0)
+    c.update(clock.counts)
     klass = next(k for k in (64, 256, 1024) if k >= n)
     assert c["index_probe_trips"] == len(rounds)
     assert c["index_probe_digests"] == len(rounds) * n
     assert c["index_probe_padded"] == len(rounds) * klass
-    assert c["index_table_uploads"] == sum(dirty) \
-        == cuckoo.stats["table_uploads"] - uploads0
-    assert c["index_table_upload_bytes"] == sum(dirty) * idx.table_bytes
+    spent = {k: cuckoo.stats[k] - stats0[k] for k in stats0}
+    assert c["index_table_uploads"] == spent["table_uploads"]
+    assert c["index_table_delta_uploads"] == spent["table_delta_uploads"] \
+        == sum(dirty) - c["index_table_uploads"] >= 2
+    assert c["index_table_delta_buckets"] == spent["table_delta_buckets"] \
+        >= c["index_table_delta_uploads"]
+    assert c["index_table_upload_bytes"] == spent["table_upload_bytes"] \
+        == c["index_table_uploads"] * idx.table_bytes \
+        + spent["table_delta_bytes"]
     assert c["index_upload_s"] > 0 and c["index_device_s"] > 0
     assert c["index_inserts"] == 2 and c["index_false_positives"] == 0
     assert c["index_hits"] == hits
@@ -206,28 +217,42 @@ def test_device_probe_across_classes_answers_as_a_set(
 
 def test_lookup_programs_are_built_before_a_writer_needs_them(device_host):
     """After boot on a device host a probe of each class a flush can
-    produce compiles nothing on its own thread; neither after the table
-    has grown.  On a CPU host nothing is built."""
+    produce compiles nothing on its own thread, nor does the update of
+    the device's table by a change of each class; neither after the
+    table has grown.  On a CPU host nothing is built."""
     from pbs_plus_tpu.ops import cuckoo
     from pbs_plus_tpu.ops.cuckoo import probe_classes_upto
     from pbs_plus_tpu.pxar.transfer import _HASH_BATCH_COUNT
     from pbs_plus_tpu.utils import jaxenv
     classes = probe_classes_upto(_HASH_BATCH_COUNT)
     assert classes == (64, 256, 1024)
-    idx = DedupIndex(budget_mb=1)
+    idx = DedupIndex(budget_mb=2)
     nb = idx.n_buckets
     for k in classes:                       # another test's, perhaps
-        cuckoo._programs.pop((nb, k), None)
-        cuckoo._programs.pop((2 * nb, k), None)
+        for programs in (cuckoo._programs, cuckoo._scatters):
+            programs.pop((nb, k), None)
+            programs.pop((2 * nb, k), None)
     probes = [_digests(k, seed=k) for k in (1, 64, 65, 256, 257, 512)]
     idx.probe_batch(_digests(2, seed=1))    # before boot: nothing built
     assert not any((nb, k) in cuckoo._programs for k in classes)
     idx.mark_booted()
     idx.wait_warm(60)
     assert all((nb, k) in cuckoo._programs for k in classes)
+    assert [k for k in classes if (nb, k) in cuckoo._scatters] \
+        == [k for k in classes if not cuckoo._goes_whole(nb, k)] == [64]
     before = jaxenv.thread_compiles()
     for batch in probes:
         assert idx.probe_batch(batch) == [False] * len(batch)
+    # a flush's inserts, 1 to 512 digests: each update in place where
+    # its class takes it so, else whole
+    stats0 = dict(cuckoo.stats)
+    for k in (1, 65, 512):
+        fresh = _digests(k, seed=1000 + k)
+        assert idx.insert_many(fresh) == k
+        assert idx.probe_batch(fresh) == [True] * k
+    assert cuckoo.stats["table_delta_uploads"] \
+        - stats0["table_delta_uploads"] == 1
+    assert cuckoo.stats["table_uploads"] - stats0["table_uploads"] == 2
     assert jaxenv.thread_compiles() == before
     # growth: past load 0.85 the table doubles, and the index asks for
     # the new shape's programs itself
@@ -235,10 +260,229 @@ def test_lookup_programs_are_built_before_a_writer_needs_them(device_host):
     assert idx.n_buckets == 2 * nb
     idx.wait_warm(60)
     assert all((2 * nb, k) in cuckoo._programs for k in classes)
+    assert [k for k in classes if (2 * nb, k) in cuckoo._scatters] == [64]
     before = jaxenv.thread_compiles()
     for batch in probes:
         assert idx.probe_batch(batch) == [False] * len(batch)
+    assert idx.insert(probes[0][0]) is True
+    assert idx.probe_batch(probes[0]) == [True]
     assert jaxenv.thread_compiles() == before
+
+
+# ------------------------------------- the device's table kept in step
+
+
+def _digest_in(nb: int, b1: int, b2: int, rng) -> bytes:
+    """A digest whose two candidate buckets in a table of ``nb`` are
+    ``b1`` and ``b2``: word 2 picks b1, and fp0 · mix picks b1 ^ b2 in
+    the low bits whatever the bits above them."""
+    from pbs_plus_tpu.ops.cuckoo import _MIX
+    inv = pow(int(_MIX), -1, 1 << 32)
+    fp0 = ((b1 ^ b2) * inv + int(rng.integers(0, 1 << 16)) * nb) % (1 << 32)
+    words = np.array([fp0, int(rng.integers(1, 1 << 32)),
+                      (b1 + int(rng.integers(0, 1 << 16)) * nb) % (1 << 32)],
+                     dtype=">u4")
+    return words.tobytes() + rng.bytes(20)
+
+
+def _step(idx: DedupIndex, op: str, known: set, rng) -> None:
+    cu = idx._cuckoo
+    nb = cu.n_buckets
+    if op == "insert":
+        for d in _digests(7, seed=int(rng.integers(1 << 30))):
+            assert idx.insert(d) is True
+            known.add(d)
+    elif op == "insert_many":
+        batch = _digests(300, seed=int(rng.integers(1 << 30)))
+        assert idx.insert_many(batch) == 300
+        known.update(batch)
+    elif op == "discard":
+        for d in sorted(known)[:3]:
+            assert idx.discard(d) is True
+            known.discard(d)
+    elif op == "chain":
+        # both buckets of a digest full: its insert kicks a fingerprint
+        # on to its other bucket, and more if that one is full too
+        x, y = (int(b) for b in rng.choice(nb, 2, replace=False))
+        for b in (x, y):
+            while not (cu._table[b] != 0).any(axis=1).all():
+                d = _digest_in(nb, b, int(rng.integers(nb)), rng)
+                assert idx.insert(d) is True
+                known.add(d)
+        before = cu._table.copy()
+        d = _digest_in(nb, x, y, rng)
+        assert idx.insert(d) is True
+        known.add(d)
+        assert len(np.flatnonzero((before != cu._table).any(axis=(1, 2)))) >= 2
+    else:                                   # past load 0.85: it doubles
+        batch = _digests(int(nb * SLOTS * 0.85) + 1 - len(idx),
+                         seed=int(rng.integers(1 << 30)))
+        idx.insert_many(batch)
+        known.update(batch)
+        assert idx._cuckoo.n_buckets == 2 * nb
+
+
+@pytest.mark.parametrize("ops", [
+    ("insert", "discard", "insert_many", "chain", "discard"),
+    ("chain", "grow", "insert", "chain", "insert_many")],
+    ids=["changes", "growth"])
+@pytest.mark.parametrize("spill", [False, True], ids=["ram", "spill"])
+def test_device_table_equals_the_mirror_after_every_probe(
+        device_host, tmp_path, ops, spill):
+    """Inserts, discards, eviction chains and a growth between probes:
+    after each probe the device's table is the host mirror bit for bit,
+    the index answers as a set does, and the device twin as the host
+    twin."""
+    from pbs_plus_tpu.ops import cuckoo
+    rng = np.random.default_rng(37)
+    idx = DedupIndex(budget_mb=2, spill_dir=str(tmp_path) if spill else None)
+    idx.mark_booted()
+    known = set(_digests(500, seed=370))
+    idx.insert_many(sorted(known))
+    stats0 = dict(cuckoo.stats)
+    for op in ("probe",) + ops:
+        if op != "probe":
+            _step(idx, op, known, rng)
+        asked = sorted(known)[:200] + _digests(56, seed=371)
+        assert idx.probe_batch(asked) == [d in known for d in asked]
+        cu = idx._cuckoo
+        assert np.array_equal(np.asarray(cu._device_table), cu._table), op
+        arr = np.frombuffer(b"".join(asked), np.uint8).reshape(-1, 32)
+        assert np.array_equal(cu.probe(arr), lookup_host(cu._table, arr))
+    spent = {k: cuckoo.stats[k] - stats0[k] for k in stats0}
+    # a few buckets go in place; the first probe (500 preloaded), 300 at
+    # once and a growth go whole
+    assert spent["table_delta_uploads"] >= sum(
+        op in ("insert", "discard", "chain") for op in ops)
+    assert spent["table_uploads"] >= 1 + sum(
+        op in ("insert_many", "grow") for op in ops)
+
+
+def test_probes_on_threads_beside_an_inserter_agree_with_the_host_twin():
+    """Six threads probe one ``CuckooIndex`` while a seventh inserts into
+    it, with the interpreter switching threads every 10 µs: no thread
+    meets an array the update gave away, every answer is the host twin's
+    for the digests that were there before, and at the end the device's
+    table is the mirror."""
+    import sys
+
+    from pbs_plus_tpu.ops import cuckoo
+    idx = CuckooIndex(n_buckets=1 << 16)
+    members = _digests(400, seed=380)
+    idx.insert_many(members)
+    fresh = _digests(400, seed=381)
+    arr = np.frombuffer(b"".join(members[:200] + _digests(200, seed=382)),
+                        np.uint8).reshape(-1, 32)
+    want = lookup_host(idx._table, arr)
+    assert want[:200].all() and not want[200:].any()
+    idx.probe(arr)
+    deltas0 = cuckoo.stats["table_delta_uploads"]
+    errors, wrong = [], []
+
+    def prober():
+        try:
+            for _ in range(20):
+                got = idx.probe(arr)
+                if not np.array_equal(got, want):
+                    wrong.append(got)
+        except Exception as e:          # noqa: BLE001 — reported below
+            errors.append(e)
+
+    def inserter():
+        try:
+            for d in fresh:
+                idx.insert(d)
+        except Exception as e:          # noqa: BLE001 — reported below
+            errors.append(e)
+    threads = [threading.Thread(target=prober) for _ in range(6)] \
+        + [threading.Thread(target=inserter)]
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+    finally:
+        sys.setswitchinterval(switch)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors and not wrong
+    assert cuckoo.stats["table_delta_uploads"] > deltas0
+    everyone = np.frombuffer(b"".join(members + fresh),
+                             np.uint8).reshape(-1, 32)
+    assert idx.probe(everyone).all()
+    assert np.array_equal(np.asarray(idx._device_table), idx._table)
+
+
+@pytest.mark.parametrize("k", [1, 9, 40])
+def test_a_probe_after_k_inserts_sends_their_buckets_and_no_more(
+        k, monkeypatch):
+    """After k scalar inserts (no eviction) a probe writes at most 2k
+    buckets into the device's table, and the job's bytes are what went;
+    a rebuild and a growth send the table whole."""
+    from pbs_plus_tpu.ops import cuckoo
+    from pbs_plus_tpu.utils import trace
+    sent = []
+    scatter = cuckoo._scatter
+
+    def spy(table, idx, rows):
+        sent.append(idx.nbytes + rows.nbytes)
+        return scatter(table, idx, rows)
+    monkeypatch.setattr(cuckoo, "_scatters", {})
+    monkeypatch.setattr(cuckoo, "_scatter", spy)
+    idx = CuckooIndex(n_buckets=1 << 16)
+    idx.insert_many(_digests(100, seed=390))
+    asked = np.frombuffer(b"".join(_digests(5, seed=391)),
+                          np.uint8).reshape(-1, 32)
+    idx.probe(asked)                        # the first copy, whole
+
+    def counted(change) -> dict:
+        clock = trace.ThreadClock()
+        with trace.clocked(clock):
+            change()
+            idx.probe(asked)
+        assert np.array_equal(np.asarray(idx._device_table), idx._table)
+        return clock.counts
+    c = counted(lambda: [idx.insert(d) for d in _digests(k, seed=392)])
+    assert c["index_table_delta_uploads"] == 1
+    assert "index_table_uploads" not in c
+    assert 1 <= c["index_table_delta_buckets"] <= 2 * k
+    assert c["index_table_upload_bytes"] == sum(sent) \
+        == 64 * cuckoo.DELTA_BUCKET_BYTES
+    for change in (idx._rebuild_bulk,       # the same shape, rebuilt
+                   lambda: idx.insert_many(_digests(
+                       int(idx.n_buckets * SLOTS * 0.85), seed=393))):
+        del sent[:]
+        nb = idx.n_buckets
+        c = counted(change)
+        assert c["index_table_uploads"] == 1 and not sent
+        assert c["index_table_upload_bytes"] == idx._table.nbytes
+        assert "index_table_delta_uploads" not in c
+    assert idx.n_buckets == 2 * nb
+
+
+def test_a_change_that_costs_more_than_a_copy_goes_whole():
+    """A change of more than a 1,024th of the table's buckets goes whole:
+    300 fresh buckets of a 65,536-bucket table, and from then on nothing
+    more is noted.  A preload batch of 65,536 goes in place at
+    ``index-at-size``'s 2 GiB table and whole at the 64 MiB one."""
+    from pbs_plus_tpu.ops import cuckoo
+    idx = CuckooIndex(n_buckets=1 << 16)
+    asked = np.frombuffer(b"".join(_digests(5, seed=394)),
+                          np.uint8).reshape(-1, 32)
+    idx.probe(asked)
+    before = dict(cuckoo.stats)
+    idx.insert_many(_digests(300, seed=395))
+    assert idx._whole and idx._n_marks == 0
+    idx.probe(asked)
+    assert cuckoo.stats["table_uploads"] == before["table_uploads"] + 1
+    assert cuckoo.stats["table_delta_uploads"] \
+        == before["table_delta_uploads"]
+    assert np.array_equal(np.asarray(idx._device_table), idx._table)
+    assert cuckoo._goes_whole(1 << 16, 256)
+    assert not cuckoo._goes_whole(1 << 16, 64)
+    assert not cuckoo._goes_whole(1 << 26, 1 << 16)
+    assert cuckoo._goes_whole(1 << 21, 1 << 16)
 
 
 def test_cpu_host_builds_no_lookup_program():
@@ -246,11 +490,13 @@ def test_cpu_host_builds_no_lookup_program():
     idx = DedupIndex(budget_mb=2)
     for k in (64, 256, 1024):
         cuckoo._programs.pop((idx.n_buckets, k), None)
+        cuckoo._scatters.pop((idx.n_buckets, k), None)
     idx.mark_booted()
     idx.wait_warm(60)
     idx.insert_many(_digests(100, seed=3))
     assert idx.probe_batch(_digests(100, seed=3)) == [True] * 100
     assert not any(key[0] == idx.n_buckets for key in cuckoo._programs)
+    assert not any(key[0] == idx.n_buckets for key in cuckoo._scatters)
 
 
 def test_dedupindex_discard_and_reinsert():

@@ -200,7 +200,13 @@ def test_utils_trace_imports_without_jax():
 
 
 def test_table_upload_is_counted_once_per_dirtying(spans):
-    index = _index()
+    """The first probe copies the table whole, a probe after an insert
+    sends the changed bucket padded to a class, a clean probe nothing:
+    bytes = whole uploads × the table's bytes + the deltas' bytes.  A
+    table of 65,536 buckets: the smallest that takes a change of one
+    class in place."""
+    index = cuckoo.CuckooIndex(n_buckets=1 << 16)
+    index.insert_many([bytes([i]) * 32 for i in range(10)])
     before = dict(cuckoo.stats)
     _trip("probe", index)
     _trip("probe", index)
@@ -209,13 +215,23 @@ def test_table_upload_is_counted_once_per_dirtying(spans):
         == before["table_upload_bytes"] + index._table.nbytes
     index.insert(b"\x77" * 32)
     _trip("probe", index)
-    assert cuckoo.stats["table_uploads"] == before["table_uploads"] + 2
-    assert cuckoo.stats["probes"] == before["probes"] + 60
+    delta = 64 * cuckoo.DELTA_BUCKET_BYTES
+    spent = {k: cuckoo.stats[k] - before[k] for k in before}
+    assert spent["table_uploads"] == 1
+    assert spent["table_delta_uploads"] == 1
+    assert spent["table_delta_buckets"] == 1
+    assert spent["table_delta_bytes"] == delta
+    assert spent["table_upload_bytes"] == index._table.nbytes + delta
+    assert spent["probes"] == 60
     # and the span of a probe that carried the table says so
     probes = [r["attrs"] for r in spans if r["name"] == "device.probe"]
-    assert [a.get("table_uploads", 0) for a in probes] == [1, 0, 1]
+    assert [a.get("table_uploads", 0) for a in probes] == [1, 0, 0]
+    assert [a.get("table_delta_uploads", 0) for a in probes] == [0, 0, 1]
     assert probes[0]["table_upload_bytes"] == index._table.nbytes
     assert "table_upload_bytes" not in probes[1]
+    assert probes[2]["table_upload_bytes"] \
+        == probes[2]["table_delta_bytes"] == delta
+    assert probes[2]["upload_s"] > 0
 
 
 def test_concurrent_probes_lose_no_count():
@@ -339,10 +355,13 @@ def test_metrics_render_every_new_gauge(tmp_path):
         assert (f'pbs_plus_feeder_queue_wait_seconds_count{{kind="{kind}"}}'
                 in expo)
     for name in ("device_compilations", "device_compile_seconds",
-                 "device_table_uploads", "device_table_upload_bytes",
-                 "feeder_rounds", "feeder_scan_feeds",
-                 "feeder_scan_rows_shared"):
+                 "device_table_delta_buckets", "feeder_rounds",
+                 "feeder_scan_feeds", "feeder_scan_rows_shared"):
         assert f"pbs_plus_{name}_total " in expo, name
+    for name in ("device_table_uploads", "device_table_upload_bytes",
+                 "index_table_uploads"):
+        for kind in ("whole", "delta"):
+            assert f'pbs_plus_{name}_total{{kind="{kind}"}} ' in expo, name
 
 
 # --- how many devices a scan's rows sat on (ISSUE 26) ---------------------

@@ -153,6 +153,23 @@ def test_cuckoo_lookup_compiles_at_the_default_table(shape):
     assert device_bytes(compiled) < 64 * MIB
 
 
+@pytest.mark.parametrize("k", [256, 1 << 16])
+def test_cuckoo_table_update_is_written_in_place(shape, k):
+    """The update of a changed table at ``index-at-size``'s 2 GiB table
+    (2^26 buckets), for a flush's class and a preload batch's: the table
+    it is given comes back as its output, aliased, with KiB of scratch —
+    no second table on the device (a ``scatter`` of whole rows has the
+    compiler relay the table out at 32 times its size first)."""
+    nb = 1 << 26
+    compiled = cuckoo._scatter.lower(
+        shape((nb, cuckoo.SLOTS, 2), jnp.uint32), shape((k,), jnp.int32),
+        shape((k, cuckoo.SLOTS, 2), jnp.uint32)).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == m.output_size_in_bytes \
+        == nb * cuckoo.BUCKET_BYTES
+    assert m.temp_size_in_bytes < MIB
+
+
 def test_simhash_projection_compiles(shape):
     compiled = similarity._simhash.lower(
         shape((1 << 16, 32), jnp.uint8), shape((256, 64), jnp.float32),
