@@ -34,6 +34,15 @@ round the program.  ``_lookup`` is one program a (table shape, probe
 class) and ``_scatter`` one a (table shape, change class);
 ``warm_lookups`` builds both from shapes alone, ahead of the writers
 (``DedupIndex._warm_lookups``).
+
+A table that one device cannot hold beside the program lies by bucket
+range over all of the host's devices (``table_devices``): the whole copy
+is one ``device_put`` of the mirror, sharded; a change goes to the shards
+that own its buckets, each written in place (``_scatter_sharded``); and a
+lookup asks every shard for both of a digest's buckets, each answering
+for the rows it holds, and sums the partial hits over the shards
+(``_lookup_sharded``).  Their programs are built ahead as the one
+device's, keyed by (table shape, class, shards).
 """
 
 from __future__ import annotations
@@ -45,6 +54,8 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..utils import jaxenv, trace
 from ..utils.log import L
@@ -64,21 +75,95 @@ DELTA_BUCKET_BYTES = BUCKET_BYTES + 4   # a changed bucket's row and index
 # loop against 0.1 ns a byte of the copy; PERF.md, section 6)
 _STEP_BUCKETS = 1024
 
+# Where the device's table lies.  On one device where the table and the
+# program's working set beside it fit that device's memory; else by bucket
+# range over every device of the host, where a shard and the working set
+# fit the least of them; else on none, and ``TableTooLarge`` says so when
+# the index is built (``DedupIndex``), not in a backup.  Read from the
+# devices' own ``memory_stats()["bytes_limit"]``; a backend that reports
+# none (the CPU's) holds any table on one device.  The working set: the
+# scan's programs, the probes' digests and an update's rows — 0.03 GB
+# beside the 2 GiB table, 0.17 GB with no large table (PERF.md, section
+# 4) — with room for a compile's scratch.  On TPU v5e (15.75 GiB a chip):
+# 64 MiB, 2 GiB and 8 GiB tables lie on one device, on one chip and on
+# four; 16 GiB (2^29 buckets) on four chips lies 4 GiB a chip, and on one
+# chip is refused.
+WORKING_SET_BYTES = 2 << 30
+_AXIS = "index"
+_SHARDED = P(_AXIS, None, None)
+
+
+class TableTooLarge(ValueError):
+    """No set of the host's devices holds the index's table."""
+
 
 # device probes, mirror of rolling_hash.stats: ``probes`` digests asked in
 # ``dispatches`` lookups (``bytes`` of digests, ``padded_bytes`` after
 # padding to a probe class); the table brought up to date for a probe
 # ``table_uploads`` times whole and ``table_delta_uploads`` times by its
 # changed buckets (``table_delta_buckets`` of them, ``table_delta_bytes``
-# sent, padding included), ``table_upload_bytes`` the bytes of both; and
-# the five phase clocks — the table's update is inside ``h2d_s``.  Probes
-# run on the writers' threads, several at once: a trip adds to them under
-# the lock.
+# sent, padding included), ``table_upload_bytes`` the bytes of both;
+# ``table_shards`` the devices the table went to at its last whole copy
+# (a gauge); and the five phase clocks — the table's update is inside
+# ``h2d_s``.  Probes run on the writers' threads, several at once: a trip
+# adds to them under the lock.
 stats = trace.device_stats("probe", {
     "dispatches": 0, "probes": 0, "bytes": 0, "padded_bytes": 0,
     "table_uploads": 0, "table_upload_bytes": 0, "table_delta_uploads": 0,
-    "table_delta_buckets": 0, "table_delta_bytes": 0})
+    "table_delta_buckets": 0, "table_delta_bytes": 0, "table_shards": 0})
 _stats_lock = threading.Lock()
+
+
+def shards_for(n_buckets: int, limits) -> int:
+    """How many of the host's devices a table of ``n_buckets`` lies on:
+    1, or all of them (``len(limits)``); ``limits`` are their
+    ``bytes_limit``s, None where the backend reports none."""
+    table = n_buckets * BUCKET_BYTES
+    if not limits or None in limits \
+            or table + WORKING_SET_BYTES <= limits[0]:
+        return 1
+    n = len(limits)
+    if n > 1 and n_buckets % n == 0 \
+            and table // n + WORKING_SET_BYTES <= min(limits):
+        return n
+    raise TableTooLarge(
+        f"the dedup index's table is {table:,} bytes ({n_buckets:,} "
+        f"buckets); with {WORKING_SET_BYTES:,} bytes of working set beside "
+        f"it, no set of this host's {n} device(s) holds it (bytes_limit "
+        f"{', '.join(f'{b:,}' for b in limits)})")
+
+
+_placements: dict = {}
+
+
+def table_devices(n_buckets: int) -> tuple:
+    """The devices the table of ``n_buckets`` lies on, decided once a
+    table shape (``shards_for``); raises ``TableTooLarge``."""
+    devices = _placements.get(n_buckets)
+    if devices is None:
+        every = jax.devices()
+        limits = [(d.memory_stats() or {}).get("bytes_limit")
+                  for d in every]
+        devices = _placements[n_buckets] = \
+            tuple(every[:shards_for(n_buckets, limits)])
+    return devices
+
+
+@functools.lru_cache(maxsize=None)
+def _mesh(devices: tuple) -> Mesh:
+    """A 1-D mesh over ``devices`` whose axis holds the table's buckets."""
+    return Mesh(np.array(devices), (_AXIS,))
+
+
+def _mesh_of(table: jax.Array) -> "Mesh | None":
+    """The mesh a sharded table lies on; None for one device's."""
+    sharding = table.sharding
+    return sharding.mesh if isinstance(sharding, NamedSharding) else None
+
+
+def _key(n_buckets: int, k: int, shards: int) -> tuple:
+    """A program's key: the one device's as it always was."""
+    return (n_buckets, k) if shards == 1 else (n_buckets, k, shards)
 
 
 def buckets_for_bytes(budget_bytes: int, *, minimum: int = 1 << 10) -> int:
@@ -167,8 +252,62 @@ def _scatter(table: jax.Array, idx: jax.Array, rows: jax.Array) -> jax.Array:
     return jax.lax.fori_loop(0, idx.shape[0], put, table, unroll=8)
 
 
-# ``_lookup`` and ``_scatter`` built ahead of a writer's first probe, by
-# (buckets, class): a table of another shape and every class is a program
+def _lookup_shard(table_shard: jax.Array, digests: jax.Array,
+                  n_buckets: int, axis_name: str) -> jax.Array:
+    """``_lookup`` on one shard of a table of ``n_buckets`` split by
+    bucket range over ``axis_name``: table_shard uint32[NB/n, SLOTS, 2];
+    digests uint8[N, 32], the same on every shard → bool[N], the hits in
+    the rows this shard holds (a bucket outside them reads a clipped row
+    and is masked out); the caller sums the shards'."""
+    rows_here = table_shard.shape[0]
+    base = jax.lax.axis_index(axis_name) * rows_here
+    fp0, fp1, bidx = _digest_words(digests)
+    fp0 = jnp.where((fp0 == 0) & (fp1 == 0), jnp.uint32(0x5A5A5A5A), fp0)
+    mask = jnp.uint32(n_buckets - 1)
+    b1 = bidx & mask
+    b2 = b1 ^ ((fp0 * _MIX) & mask)
+
+    def check(b):
+        local = b.astype(jnp.int32) - base
+        here = (local >= 0) & (local < rows_here)
+        rows = table_shard[jnp.clip(local, 0, rows_here - 1)]
+        hit = jnp.any((rows[..., 0] == fp0[:, None]) &
+                      (rows[..., 1] == fp1[:, None]), axis=1)
+        return hit & here
+
+    return check(b1) | check(b2)
+
+
+@functools.partial(jax.jit, static_argnames="mesh")
+def _lookup_sharded(table: jax.Array, digests: jax.Array, *,
+                    mesh: Mesh) -> jax.Array:
+    """``_lookup`` of a table split by bucket range over ``mesh``; the
+    digests lie whole on every device, the answer too."""
+    nb = table.shape[0]
+
+    def body(shard, dg):
+        part = _lookup_shard(shard, dg, nb, _AXIS)
+        return jax.lax.psum(part.astype(jnp.int32), _AXIS) > 0
+    return shard_map(body, mesh=mesh, in_specs=(_SHARDED, P()),
+                     out_specs=P())(table, digests)
+
+
+@functools.partial(jax.jit, donate_argnums=0, static_argnames="mesh")
+def _scatter_sharded(table: jax.Array, idx: jax.Array, rows: jax.Array, *,
+                     mesh: Mesh) -> jax.Array:
+    """``_scatter`` on every shard of a table split by bucket range over
+    ``mesh``, given to be reused: idx int32[n·K] and rows
+    uint32[n·K, SLOTS, 2] split as the table is, K a shard — the indices
+    local to the shard, each shard's padded by repeating its last (a
+    shard with no change rewrites its first row as the mirror has it)."""
+    return shard_map(_scatter.__wrapped__, mesh=mesh,
+                     in_specs=(_SHARDED, P(_AXIS), _SHARDED),
+                     out_specs=_SHARDED)(table, idx, rows)
+
+
+# ``_lookup`` and ``_scatter`` (their sharded twins for a table that lies
+# on several devices) built ahead of a writer's first probe, by (buckets,
+# class[, shards]): a table of another shape and every class is a program
 # of its own, and one that compiles inside a probe stops a backup for as
 # long as it takes.  A shape nobody built ahead still compiles at the
 # probe, as before (``round_trip`` warns).
@@ -185,20 +324,41 @@ def _goes_whole(n_buckets: int, k: int) -> bool:
 
 
 def _build_lookups(n_buckets: int, classes) -> None:
-    table = jax.ShapeDtypeStruct((n_buckets, SLOTS, 2), jnp.uint32)
+    try:
+        devices = table_devices(n_buckets)
+    except TableTooLarge as e:      # the probe raises it
+        L.warning("device.probe buckets=%d: %s", n_buckets, e)
+        return
+    shards = len(devices)
+    if shards == 1:
+        def arg(dims, dtype, spec=None):
+            return jax.ShapeDtypeStruct(dims, dtype)
+        lookup, scatter = _lookup.lower, _scatter.lower
+    else:
+        mesh = _mesh(devices)
+
+        def arg(dims, dtype, spec=P()):
+            return jax.ShapeDtypeStruct(dims, dtype,
+                                        sharding=NamedSharding(mesh, spec))
+        lookup = functools.partial(_lookup_sharded.lower, mesh=mesh)
+        scatter = functools.partial(_scatter_sharded.lower, mesh=mesh)
+    table = arg((n_buckets, SLOTS, 2), jnp.uint32, _SHARDED)
     for k in classes:
-        key = (n_buckets, k)
+        key = _key(n_buckets, k, shards)
         try:
             if key not in _programs:
-                _programs[key] = _lookup.lower(
-                    table, jax.ShapeDtypeStruct((k, 32), jnp.uint8)).compile()
-            if key not in _scatters and not _goes_whole(n_buckets, k):
-                _scatters[key] = _scatter.lower(
-                    table, jax.ShapeDtypeStruct((k,), jnp.int32),
-                    jax.ShapeDtypeStruct((k, SLOTS, 2), jnp.uint32)).compile()
+                _programs[key] = lookup(
+                    table, arg((k, 32), jnp.uint8)).compile()
+            # a change of k buckets a shard
+            if key not in _scatters \
+                    and not _goes_whole(n_buckets // shards, k):
+                _scatters[key] = scatter(
+                    table, arg((shards * k,), jnp.int32, P(_AXIS)),
+                    arg((shards * k, SLOTS, 2), jnp.uint32,
+                        _SHARDED)).compile()
         except Exception as e:      # the probe then compiles for itself
-            L.warning("device.probe rows=%d buckets=%d not built ahead: %s",
-                      k, n_buckets, e)
+            L.warning("device.probe rows=%d buckets=%d shards=%d not built "
+                      "ahead: %s", k, n_buckets, shards, e)
 
 
 def warm_lookups(n_buckets: int, classes) -> threading.Thread:
@@ -588,14 +748,10 @@ class CuckooIndex:
                 return None, 0, 0
             idx = np.unique(self._marks[:self._n_marks])
             self._n_marks = 0
-            k = _probe_class(idx.size)
-            if not _goes_whole(self.n_buckets, k):
-                pad = np.full(k, idx[-1], dtype=np.int32)
-                pad[:idx.size] = idx
-                rows = self._table[pad]
-                scatter = _scatters.get((self.n_buckets, k), _scatter)
-                self._device_table = scatter(self._device_table, pad, rows)
-                return "delta", pad.nbytes + rows.nbytes, idx.size
+            sent = self._write_delta(idx)
+            if sent:
+                return "delta", sent, idx.size
+        devices = table_devices(self.n_buckets)
         # cleared before the mirror is read: a row written while it is
         # copied is marked for the next probe.  The CPU backend may take
         # an aligned array's memory as its own instead of copying it, and
@@ -604,8 +760,59 @@ class CuckooIndex:
         self._device_table = None           # not two tables at once
         src = np.array(self._table) if jax.default_backend() == "cpu" \
             else self._table
-        self._device_table = jnp.asarray(src)
+        if len(devices) == 1:
+            self._device_table = jnp.asarray(src)
+        else:       # each device its own range of buckets, and no more
+            self._device_table = jax.device_put(
+                src, NamedSharding(_mesh(devices), _SHARDED))
         return "whole", self._table.nbytes, 0
+
+    def _write_delta(self, idx: np.ndarray) -> int:
+        """Write the buckets ``idx`` (sorted, distinct) of the mirror into
+        the device's table in place, each into the shard that holds it;
+        returns the bytes sent, or 0 where the change, padded to its class
+        a shard, costs more than the whole copy (``_goes_whole``) and
+        nothing was written."""
+        table = self._device_table
+        mesh = _mesh_of(table)
+        if mesh is None:
+            k = _probe_class(idx.size)
+            if _goes_whole(self.n_buckets, k):
+                return 0
+            pad = np.full(k, idx[-1], dtype=np.int32)
+            pad[:idx.size] = idx
+            rows = self._table[pad]
+            scatter = _scatters.get((self.n_buckets, k), _scatter)
+            self._device_table = scatter(table, pad, rows)
+            return pad.nbytes + rows.nbytes
+        # the host routes each shard its own buckets, as local indices
+        shards = mesh.size
+        per = self.n_buckets // shards
+        parts = np.split(idx, np.searchsorted(idx, per * np.arange(1, shards)))
+        k = _probe_class(max(p.size for p in parts))
+        if _goes_whole(per, k):
+            return 0
+        local = np.zeros((shards, k), dtype=np.int32)
+        for s, p in enumerate(parts):
+            if p.size:
+                local[s] = p[-1] - s * per
+                local[s, :p.size] = p - s * per
+        base = per * np.arange(shards, dtype=np.int32)[:, None]
+        rows = self._table[(local + base).ravel()]
+        local = local.ravel()
+        scatter = _scatters.get(_key(self.n_buckets, k, shards)) \
+            or functools.partial(_scatter_sharded, mesh=mesh)
+        self._device_table = scatter(
+            table, jax.device_put(local, NamedSharding(mesh, P(_AXIS))),
+            jax.device_put(rows, table.sharding))
+        return local.nbytes + rows.nbytes
+
+    @property
+    def table_shards(self) -> int:
+        """The devices the device's copy of the table lies on now; 0
+        where there is none (a CPU host probes the mirror)."""
+        table = self._device_table
+        return 0 if table is None else len(table.sharding.device_set)
 
     def device_table(self) -> jax.Array:
         """The device's copy of the table, brought up to date.  The next
@@ -644,8 +851,13 @@ class CuckooIndex:
                         rt.attrs["upload_s"] = upload_s
                         mine.update(index_table_upload_bytes=nbytes,
                                     index_upload_s=upload_s)
+                    mesh = _mesh_of(table)
+                    shards = 1 if mesh is None else mesh.size
+                    rt.attrs["shards"] = shards
                     if kind == "whole":
                         rt.add(table_uploads=1, table_upload_bytes=nbytes)
+                        with _stats_lock:
+                            stats["table_shards"] = shards
                         mine["index_table_uploads"] = 1
                     elif kind == "delta":
                         rt.shape = (f"delta={_probe_class(changed)} "
@@ -656,11 +868,18 @@ class CuckooIndex:
                                table_upload_bytes=nbytes)
                         mine.update(index_table_delta_uploads=1,
                                     index_table_delta_buckets=changed)
-                    dd = jnp.asarray(padded).block_until_ready()
+                    if mesh is None:
+                        dd = jnp.asarray(padded).block_until_ready()
+                    else:   # the digests whole on every shard
+                        dd = jax.device_put(padded, NamedSharding(
+                            mesh, P())).block_until_ready()
                 rt.shape = f"rows={n_pad} buckets={self.n_buckets}"
                 rt.add(dispatches=1, probes=n, bytes=arr.nbytes,
                        padded_bytes=padded.nbytes)
-                lookup = _programs.get((self.n_buckets, n_pad), _lookup)
+                lookup = _programs.get(
+                    _key(self.n_buckets, n_pad, shards),
+                    _lookup if mesh is None
+                    else functools.partial(_lookup_sharded, mesh=mesh))
                 with rt.phase("device"):
                     dhit = lookup(table, dd).block_until_ready()
             trace.tally(index_device_s=rt.attrs["device_s"], **mine)

@@ -1,8 +1,7 @@
 """Distributed chunk index: consistent-hash-sharded digest space served
 by N index processes, probed with batched scatter/gather fan-out
-(ISSUE 16, ROADMAP item 2) — plus the original device-mesh sketch
-(cuckoo table sharded over the ``index`` mesh axis, probes resolved
-with a psum of partial hits).
+(ROADMAP item 2).  (A table that one device cannot hold is split over
+the host's devices by ``ops/cuckoo.py`` itself, inside one index.)
 
 The service half (docs/dist-index.md):
 
@@ -36,7 +35,6 @@ file on disk — never a false dedup skip, never a resurrected digest.
 from __future__ import annotations
 
 import base64
-import functools
 import hashlib
 import hmac
 import http.client
@@ -48,124 +46,10 @@ import threading
 import urllib.parse
 from typing import Iterable, Iterator, Sequence
 
-import jax
-import jax.numpy as jnp
 import numpy as np
-from jax import shard_map
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..ops.cuckoo import SLOTS, _MIX, CuckooIndex, _digest_words
 from ..utils import atomicio, fswitness
 from ..utils.log import L
-
-
-def _probe_local(table_shard: jax.Array, digests: jax.Array,
-                 n_buckets: int, axis_name: str) -> jax.Array:
-    """table_shard uint32[NB/ni, SLOTS, 2]; digests uint8[N,32] (replicated
-    within the index axis) → partial-hit bool[N]; caller psums."""
-    ni = jax.lax.axis_size(axis_name)
-    shard_rows = n_buckets // ni
-    base = jax.lax.axis_index(axis_name) * shard_rows
-    fp0, fp1, bidx = _digest_words(digests)
-    fp0 = jnp.where((fp0 == 0) & (fp1 == 0), jnp.uint32(0x5A5A5A5A), fp0)
-    mask = jnp.uint32(n_buckets - 1)
-    b1 = bidx & mask
-    b2 = b1 ^ ((fp0 * _MIX) & mask)
-
-    def check(b):
-        local = b.astype(jnp.int32) - base
-        in_range = (local >= 0) & (local < shard_rows)
-        rows = table_shard[jnp.clip(local, 0, shard_rows - 1)]
-        hit = jnp.any((rows[..., 0] == fp0[:, None]) &
-                      (rows[..., 1] == fp1[:, None]), axis=1)
-        return hit & in_range
-
-    return check(b1) | check(b2)
-
-
-class ShardedCuckooIndex:
-    """Host-authoritative cuckoo index whose device table shards over the
-    ``index`` axis of a mesh.  Inserts mutate the host mirror (exactly as
-    CuckooIndex); ``device_table`` re-places the table sharded."""
-
-    def __init__(self, mesh: Mesh, *, axis_name: str = "index",
-                 n_buckets: int = 1 << 16, seed: int = 0):
-        ni = int(np.prod([mesh.shape[a] for a in (axis_name,)]))
-        if n_buckets % ni:
-            raise ValueError("n_buckets must divide by index-axis size")
-        self.mesh = mesh
-        self.axis_name = axis_name
-        self.inner = CuckooIndex(n_buckets=n_buckets, seed=seed)
-        self._device_table: jax.Array | None = None
-
-    # host-authoritative ops delegate
-    def insert(self, digest: bytes) -> bool:
-        self.inner._device_table = None  # sharded copy managed here
-        r = self.inner.insert(digest)
-        if r:
-            self._device_table = None
-        return r
-
-    def insert_many(self, digests) -> int:
-        """Bulk preload: one vectorized host-mirror build, one sharded
-        re-upload at the next ``device_table`` call — not one
-        invalidation per digest (judge r2 weak#7; feeds the PBSStore
-        ``previous`` → DeviceFeeder warm-up path)."""
-        self.inner._device_table = None  # sharded copy managed here
-        added = self.inner.insert_many(list(digests))
-        if added:
-            self._device_table = None
-        return added
-
-    def contains_exact(self, digest: bytes) -> bool:
-        return self.inner.contains_exact(digest)
-
-    def __len__(self) -> int:
-        return len(self.inner)
-
-    def device_table(self) -> jax.Array:
-        if self._device_table is None:
-            sharding = NamedSharding(self.mesh, P(self.axis_name, None, None))
-            self._device_table = jax.device_put(
-                jnp.asarray(self.inner._table), sharding)
-        return self._device_table
-
-    def probe(self, digests: np.ndarray | jax.Array, *,
-              data_axis: str | None = "data") -> jax.Array:
-        """digests uint8[N,32] → bool[N].  With ``data_axis``, N shards over
-        the data axis (each data-shard's digests replicated across index
-        shards); partial hits psum over the index axis."""
-        d = np.asarray(jnp.asarray(digests, dtype=jnp.uint8))
-        n = d.shape[0]
-        nb = self.inner.n_buckets
-        ax = self.axis_name
-
-        def body(table_shard, dg):
-            part = _probe_local(table_shard, dg, nb, ax)
-            return jax.lax.psum(part.astype(jnp.int32), ax) > 0
-
-        use_data = bool(data_axis) and data_axis in self.mesh.shape
-        if use_data:
-            nd = self.mesh.shape[data_axis]
-            pad = (-n) % nd
-            if pad:
-                d = np.concatenate([d, np.zeros((pad, 32), np.uint8)])
-        dspec = P(data_axis) if use_data else P()
-        fn = shard_map(
-            body, mesh=self.mesh,
-            in_specs=(P(ax, None, None), dspec),
-            out_specs=dspec,
-        )
-        dd = jnp.asarray(d)
-        if use_data:
-            dd = jax.device_put(dd, NamedSharding(self.mesh, P(data_axis, None)))
-        return jax.jit(fn)(self.device_table(), dd)[:n]
-
-    def probe_confirmed(self, digests: list[bytes]) -> list[bool]:
-        arr = np.frombuffer(b"".join(digests), dtype=np.uint8).reshape(-1, 32)
-        maybe = np.asarray(self.probe(arr))
-        return [bool(m) and self.contains_exact(d)
-                for m, d in zip(maybe, digests)]
 
 
 # ---------------------------------------------------------------------------

@@ -22,11 +22,10 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..chunker.spec import ChunkerParams
-from ..ops.cuckoo import CuckooIndex
+from ..ops.cuckoo import CuckooIndex, _lookup_shard
 from ..ops.rolling_hash import _candidate_mask_impl, device_tables
 from ..ops.sha256 import _sha256_scan_impl
 from ..ops.similarity import simhash_projection
-from .dist_index import _probe_local
 
 
 def _words_to_bytes(words: jax.Array) -> jax.Array:
@@ -50,7 +49,7 @@ def _step_body(streams, table, index_table, proj, mask, magic,
     words = _sha256_scan_impl(flat, starts, lens, (chunk_len + 8) // 64 + 1)
     digests = _words_to_bytes(words)
     # 3) distributed index probe: partial hits psum over the index axis
-    part = _probe_local(index_table, digests, n_buckets, index_axis)
+    part = _lookup_shard(index_table, digests, n_buckets, index_axis)
     hits = jax.lax.psum(part.astype(jnp.int32), index_axis) > 0
     # 4) simhash sketches (MXU matmul)
     bits = ((digests[:, :, None] >> jnp.arange(7, -1, -1, dtype=jnp.uint8)

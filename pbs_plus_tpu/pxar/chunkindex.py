@@ -159,13 +159,19 @@ class DedupIndex:
         count.  Without it the exact set stays fully in RAM (the PR 8
         behavior — bare indexes in tests, and the
         PBS_PLUS_DEDUP_RESIDENT_MB=0 escape hatch)."""
-        from ..ops.cuckoo import CuckooIndex, buckets_for_bytes
+        from ..ops.cuckoo import CuckooIndex, buckets_for_bytes, \
+            table_devices
         self._lock = threading.RLock()
         # the filter + exact set are ONE coherent unit under _lock: a
         # probe against a half-swapped rebuild would answer wrongly
         self._cuckoo = CuckooIndex(                 # guarded-by: self._lock
             n_buckets=buckets_for_bytes(max(1, int(budget_mb)) << 20),
             seed=seed)
+        if jaxenv.on_accelerator():
+            # where the device's table will lie; a table that no set of
+            # the host's devices holds is refused here, at the server's
+            # start, and not by the allocator inside a backup's probe
+            table_devices(self._cuckoo.n_buckets)
         self._datablob: set[bytes] = set()          # guarded-by: self._lock
         # bound once at construction, never reassigned — the log's own
         # contents are mutated only under self._lock (plus its internal
@@ -279,6 +285,12 @@ class DedupIndex:
     def table_bytes(self) -> int:
         with self._lock:
             return self._cuckoo._table.nbytes
+
+    @property
+    def table_shards(self) -> int:
+        """The devices the filter table's device copy lies on (0: none)."""
+        with self._lock:
+            return self._cuckoo.table_shards
 
     @property
     def resident_bytes(self) -> int:

@@ -111,8 +111,9 @@ MUX_TOTALS = dict.fromkeys(MUX_COUNTS, 0)
 # that changed (``table_delta_uploads``, ``table_delta_buckets``) — with
 # the bytes and the wall seconds of both kinds; and the seconds of the
 # lookups' device phase.  On the job's record as ``index_*`` (with
-# ``index_table_bytes``, the table's size at the job's end), totalled
-# here for /metrics.
+# ``index_table_bytes``, the table's size at the job's end, and
+# ``index_table_shards``, the devices its device copy then lay on),
+# totalled here for /metrics.
 INDEX_COUNTS = ("probe_trips", "probe_digests", "probe_padded", "hits",
                 "false_positives", "contains", "inserts", "table_uploads",
                 "table_upload_bytes", "upload_s", "device_s",
@@ -352,13 +353,15 @@ class RemoteTreeBackup:
         stats = getattr(conn, "stats", {})
         return {k: stats[k] for k in MUX_COUNTS if k in stats}
 
-    def _index_table_bytes(self) -> int:
-        """The size of the dedup index's filter table now; 0 where the
-        session's store has none (the tests' fakes, a remote index)."""
+    def _index_table(self) -> dict:
+        """The dedup index's filter table now: its size and the devices
+        its device copy lies on; 0 where the session's store has none
+        (the tests' fakes, a remote index)."""
         chunks = getattr(getattr(self.session, "writer", None), "store",
                          None)
-        return int(getattr(getattr(chunks, "_index", None), "table_bytes",
-                           0))
+        index = getattr(chunks, "_index", None)
+        return {"index_table_" + k: int(getattr(index, "table_" + k, 0))
+                for k in ("bytes", "shards")}
 
     async def run(self) -> BackupResult:
         with trace.span("backup.pump") as sp:
@@ -378,8 +381,7 @@ class RemoteTreeBackup:
                     "pump_life_s": time.perf_counter() - t0,
                     "loop_cpu0": loop_cpu0, "loop_cpu1": time.thread_time()}
                 mux = {k: v - mux0[k] for k, v in self._mux_counts().items()}
-                index = {**self.writer_clock.counts,
-                         "index_table_bytes": self._index_table_bytes()}
+                index = {**self.writer_clock.counts, **self._index_table()}
                 sp.set(job=self.log.scope.get("job_id", ""), **self.pump,
                        **clocks, **{"mux_" + k: v for k, v in mux.items()},
                        **index)

@@ -698,6 +698,11 @@ class MetricsRegistry:
         gauge("pbs_plus_index_upload_seconds_total",
               "Wall seconds the backup writers stood at those updates",
               [({}, float(it["index_upload_s"]))])
+        gauge("pbs_plus_index_table_shards",
+              "Devices the index's filter table went to, split by bucket "
+              "range where one device cannot hold it, at its last whole "
+              "copy (ops/cuckoo.py table_devices)",
+              [({}, float(pr["table_shards"]))] if pr else [])
         gauge("pbs_plus_device_compilations_total",
               "Programs jax built or loaded from its cache since the "
               "device ops were loaded; one that moves while backups run "

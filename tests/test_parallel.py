@@ -1,6 +1,7 @@
 """Multi-chip tests on the virtual 8-device CPU mesh: sequence-parallel
-chunker parity, distributed index probe, the full sharded step, and the
-driver entry points."""
+chunker parity, the full sharded step (its probe is the index's own
+sharded lookup: tests/test_sharded_table.py), and the graft entry
+points."""
 
 import hashlib
 
@@ -12,8 +13,8 @@ import pytest
 from pbs_plus_tpu.chunker import ChunkerParams, chunk_bounds
 from pbs_plus_tpu.ops.cuckoo import CuckooIndex
 from pbs_plus_tpu.parallel import (
-    ShardedCuckooIndex, build_step_inputs, make_mesh, make_seq_mesh,
-    multichip_dedup_step, sp_chunk_stream,
+    build_step_inputs, make_mesh, make_seq_mesh, multichip_dedup_step,
+    sp_chunk_stream,
 )
 
 P = ChunkerParams(avg_size=4 << 10)
@@ -31,19 +32,6 @@ def test_sp_chunker_matches_cpu():
     data = _data(300_000, seed=1)        # not divisible by 8 → padded
     cuts = sp_chunk_stream(mesh, data, P)
     assert cuts == [e for _, e in chunk_bounds(data, P)]
-
-
-def test_sharded_index_probe():
-    mesh = make_mesh(8)                  # 4 data × 2 index
-    idx = ShardedCuckooIndex(mesh, n_buckets=1 << 12)
-    present = [hashlib.sha256(bytes([i, 1])).digest() for i in range(128)]
-    absent = [hashlib.sha256(bytes([i, 2])).digest() for i in range(128)]
-    idx.insert_many(present)
-    arr = np.frombuffer(b"".join(present + absent), np.uint8).reshape(-1, 32)
-    got = np.asarray(idx.probe(arr))
-    assert got[:128].all()
-    assert got[128:].sum() <= 1
-    assert idx.probe_confirmed(present[:3] + absent[:3]) == [True] * 3 + [False] * 3
 
 
 def test_multichip_step():

@@ -676,7 +676,7 @@ RECORD_KEYS = ({"job"} | set(bj.PUMP_TOTALS)
                | {"pump_" + k for k in bj.PUMP_WAITS}
                | {"pump_life_s", "loop_cpu0", "loop_cpu1"}
                | {"index_" + k for k in bj.INDEX_COUNTS}
-               | {"index_table_bytes"})
+               | {"index_table_bytes", "index_table_shards"})
 
 
 class _StreamSession:

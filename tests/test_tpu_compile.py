@@ -170,6 +170,55 @@ def test_cuckoo_table_update_is_written_in_place(shape, k):
     assert m.temp_size_in_bytes < MIB
 
 
+@pytest.fixture(scope="module")
+def by_buckets(topo):
+    """``by_buckets(dims, dtype, spec)`` → a ShapeDtypeStruct on a 1-D
+    mesh of the described v5e 2x2's four chips, the mesh beside it."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    mesh = Mesh(np.array(topo.devices).reshape(4), (cuckoo._AXIS,))
+    return mesh, lambda dims, dtype, spec: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=NamedSharding(mesh, spec))
+
+
+COLLECTIVES = ("all-gather", "all-reduce", "all-to-all",
+               "collective-permute", "reduce-scatter")
+
+
+@pytest.mark.parametrize("k", [256, 1 << 14])
+def test_sharded_table_update_is_written_in_place_on_four_chips(
+        by_buckets, k):
+    """``index-100tib``'s 16 GiB table (2^29 buckets) over four chips, a
+    flush's class and a preload batch's a shard: each chip's 4 GiB shard
+    comes back as its output, aliased, with KiB of scratch, and no
+    collective — no shard is gathered or relaid."""
+    from jax.sharding import PartitionSpec as P
+    mesh, arg = by_buckets
+    nb, rows = 1 << 29, cuckoo._SHARDED
+    compiled = cuckoo._scatter_sharded.lower(
+        arg((nb, cuckoo.SLOTS, 2), jnp.uint32, rows),
+        arg((4 * k,), jnp.int32, P(cuckoo._AXIS)),
+        arg((4 * k, cuckoo.SLOTS, 2), jnp.uint32, rows), mesh=mesh).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == m.output_size_in_bytes \
+        == nb // 4 * cuckoo.BUCKET_BYTES
+    assert m.temp_size_in_bytes < MIB
+    assert not any(op in compiled.as_text() for op in COLLECTIVES)
+
+
+def test_sharded_lookup_sums_the_shards_hits_on_four_chips(by_buckets):
+    """The lookup over the same table: a 4 GiB shard a chip is all it
+    holds of it, and the partial hits meet in one all-reduce."""
+    from jax.sharding import PartitionSpec as P
+    mesh, arg = by_buckets
+    nb = 1 << 29
+    compiled = cuckoo._lookup_sharded.lower(
+        arg((nb, cuckoo.SLOTS, 2), jnp.uint32, cuckoo._SHARDED),
+        arg((1024, 32), jnp.uint8, P()), mesh=mesh).compile()
+    assert device_bytes(compiled) < nb // 4 * cuckoo.BUCKET_BYTES + MIB
+    assert "all-reduce" in compiled.as_text()
+
+
 def test_simhash_projection_compiles(shape):
     compiled = similarity._simhash.lower(
         shape((1 << 16, 32), jnp.uint8), shape((256, 64), jnp.float32),
