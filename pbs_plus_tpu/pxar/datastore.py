@@ -426,10 +426,16 @@ class ChunkStore:
         """Declared batched-ingest surface (pxar/ingestbackend.py): the
         answer tracks the LIVE index/similarity attachments, so a store
         that gains a shared similarity index after construction starts
-        presketching on the next flush."""
+        presketching on the next flush.  Inserts may run concurrently
+        where the store is ``thread_safe`` (a shard lock and a zstd
+        context a shard) and the similarity tier is off: its sketch
+        pool and delta bases follow the order chunks arrive in, so with
+        the tier on a stream's inserts stay in emission order."""
         from .ingestbackend import IngestCapabilities
-        return IngestCapabilities(probe=self.index is not None,
-                                  presketch=self._sim is not None)
+        return IngestCapabilities(
+            probe=self.index is not None,
+            presketch=self._sim is not None,
+            concurrent_insert=self.thread_safe and self._sim is None)
 
     def on_disk_many(self, digests: "list[bytes]") -> "list[bool]":
         """Batched disk-TRUE existence (``on_disk`` over a whole batch

@@ -37,13 +37,21 @@ class IngestCapabilities(NamedTuple):
     ``presketch`` — ``presketch_batch`` precomputes similarity sketches
                     (+ delta-base candidate shortlists) for a batch's
                     novel chunks (the delta tier is attached).
+    ``concurrent_insert`` — ``insert`` may be called from several
+                    threads at once for one stream, and what it stores
+                    does not depend on the order of those calls: the
+                    store stage of a hash batch fans its novel chunks
+                    out over helper threads (transfer.py
+                    ``_ChunkedStream._store_fanned``).
     """
 
     probe: bool
     presketch: bool
+    concurrent_insert: bool
 
 
-NO_CAPABILITIES = IngestCapabilities(probe=False, presketch=False)
+NO_CAPABILITIES = IngestCapabilities(probe=False, presketch=False,
+                                     concurrent_insert=False)
 
 
 @runtime_checkable

@@ -392,7 +392,8 @@ class PBSChunkSink:
     def ingest_capabilities(self):
         """Declared batched-ingest surface (pxar/ingestbackend.py):
         membership lives server-side behind ``known`` — no batched
-        probe or presketch exists on the push wire."""
+        probe or presketch exists on the push wire — and every insert
+        goes over the one HTTP connection, so none run concurrently."""
         from .ingestbackend import NO_CAPABILITIES
         return NO_CAPABILITIES
 

@@ -40,6 +40,7 @@ from ..utils.log import L
 from .datastore import ChunkStore, Datastore, DynamicIndex, SnapshotRef
 from .format import Entry, KIND_DIR, KIND_FILE, decode_entries
 from .ingestbackend import resolve_ingest_backend
+from .storepool import StoreFanOut, store_helpers
 from .pxarv2 import (
     PAYLOAD_HDR_SIZE, Pxar2Encoder, decode_pxar2, payload_header,
     payload_start_marker, sniff_is_pxar2,
@@ -299,18 +300,73 @@ class _ChunkedStream:
         known = self._probe_known(digests)
         self._presketch(digests, [c for _, c in self._pending], known)
         new0 = self.stats.new_chunks
+        helpers = self._store_helpers(digests, known)
         with trace.state("store_s"), \
                 trace.span("ingest.store", chunks=len(digests),
                            bytes=self._pending_bytes) as sp:
-            for i, ((idx, chunk), digest) in enumerate(zip(self._pending,
-                                                           digests)):
-                end, _ = self.records[idx]
-                self.records[idx] = (end, digest)
-                self._insert_probed(digest, chunk,
-                                    known[i] if known is not None else None)
+            if helpers:
+                self._store_fanned(digests, known, helpers)
+            else:
+                for i, ((idx, chunk), digest) in enumerate(
+                        zip(self._pending, digests)):
+                    end, _ = self.records[idx]
+                    self.records[idx] = (end, digest)
+                    self._insert_probed(
+                        digest, chunk,
+                        known[i] if known is not None else None)
             sp.set(new=self.stats.new_chunks - new0)
         self._pending.clear()
         self._pending_bytes = 0
+
+    def _store_helpers(self, digests: "list[bytes]",
+                       known: "list[bool] | None") -> int:
+        """Helper threads the store stage engages for this batch: none
+        where the store declares no ``concurrent_insert``, else one
+        fewer than the batch's novel chunks (those the probe did not
+        find), at most the pool's width — 0 leaves the stage sequential."""
+        if not self._ingest.capabilities.concurrent_insert:
+            return 0
+        novel = len(digests) if known is None else known.count(False)
+        return max(0, min(store_helpers(), novel - 1))
+
+    def _store_fanned(self, digests: "list[bytes]",
+                      known: "list[bool] | None", helpers: int) -> None:
+        """The store stage of a batch with two or more novel chunks, on
+        a store that declares ``concurrent_insert``: the novel chunks'
+        inserts go to ``StoreFanOut`` (this thread and ``helpers`` of
+        the store pool), the known chunks' GC-mark touches stay on this
+        thread meanwhile, and nothing returns before every insert has.
+        The records and the new/known counts are the sequential stage's
+        for any interleaving: a digest twice in the batch meets itself
+        on one shard lock, so one insert is new and the other known."""
+        pending = self._pending
+        novel: list = []
+        hits: list = []
+        for i, ((idx, chunk), digest) in enumerate(zip(pending, digests)):
+            end, _ = self.records[idx]
+            self.records[idx] = (end, digest)
+            if known is not None and known[i]:
+                hits.append(i)
+            else:
+                novel.append((digest, chunk))
+        fan = StoreFanOut(self.store.insert, novel)
+        fan.start(helpers)
+        try:
+            try:
+                for i in hits:
+                    self._insert_probed(digests[i], pending[i][1], True)
+            except BaseException:
+                fan.cancel()
+                raise
+            fan.join()
+        finally:
+            # what the helpers did, on this thread's clock: the index's
+            # asks and inserts, and the pool's own three counts
+            trace.tally(store_pool_chunks=fan.helped, store_pool_flushes=1,
+                        store_pool_s=fan.helper_s, **fan.counts)
+        n_new = fan.new.count(True)
+        self.stats.new_chunks += n_new
+        self.stats.known_chunks += len(novel) - n_new
 
     def flush_chunker(self) -> None:
         """Force a cut at the current offset and restart the chunker."""

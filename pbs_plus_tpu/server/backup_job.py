@@ -120,6 +120,15 @@ INDEX_COUNTS = ("probe_trips", "probe_digests", "probe_padded", "hits",
                 "table_delta_uploads", "table_delta_buckets")
 INDEX_TOTALS = {"index_" + k: 0 for k in INDEX_COUNTS}
 
+# The store stage's fan-out (pxar/storepool.py ``StoreFanOut``), counted
+# on the writer's thread at each flush's join: the novel chunks a helper
+# thread of the store pool stored in the writer's place, the flushes
+# that fanned out, and the helpers' summed seconds inside ``insert``
+# (docs/observability.md "The session's clocks").  On the job's record
+# as ``store_pool_*``, totalled here for /metrics.
+STORE_POOL_COUNTS = ("chunks", "flushes", "s")
+STORE_POOL_TOTALS = {"store_pool_" + k: 0 for k in STORE_POOL_COUNTS}
+
 
 def _get_abortable(q: "queue.Queue", abort: "threading.Event | None"):
     """Blocking queue get that returns _ABORTED instead of waiting
@@ -320,7 +329,7 @@ class RemoteTreeBackup:
         # the session's clocks: the writer thread's, and the pump's waits
         self.writer_clock = trace.ThreadClock(
             dict.fromkeys(WRITER_STATES, 0.0), label="writer",
-            counts=dict.fromkeys(INDEX_TOTALS, 0))
+            counts=dict.fromkeys([*INDEX_TOTALS, *STORE_POOL_TOTALS], 0))
         self.waits = dict.fromkeys(PUMP_WAITS, 0.0)
         # until the agent answers that it does not know read_many
         self._batching = True
@@ -372,8 +381,9 @@ class RemoteTreeBackup:
             finally:
                 # one record a job: the pump's counts, the writer's
                 # clock (its thread is joined by now) with what the
-                # index did on that thread, the pump's waits and the
-                # loop thread's CPU clock at both ends
+                # index and the store pool did for that thread, the
+                # pump's waits and the loop thread's CPU clock at both
+                # ends
                 clocks = {
                     **{"writer_" + k: v
                        for k, v in self.writer_clock.seconds.items()},
@@ -395,6 +405,8 @@ class RemoteTreeBackup:
                 CLOCK_TOTALS["loop_cpu_s"] = clocks["loop_cpu1"]
                 for k in INDEX_TOTALS:
                     INDEX_TOTALS[k] += index[k]
+                for k in STORE_POOL_TOTALS:
+                    STORE_POOL_TOTALS[k] += index[k]
                 self.log.info("session clocks: %s", " ".join(
                     f"{k}={v}" if isinstance(v, int) else f"{k}={v:.6f}"
                     for k, v in {**clocks, **index}.items()))

@@ -698,6 +698,21 @@ class MetricsRegistry:
         gauge("pbs_plus_index_upload_seconds_total",
               "Wall seconds the backup writers stood at those updates",
               [({}, float(it["index_upload_s"]))])
+        # the store stage's fan-out over the store pool, counted on the
+        # writers' threads (server/backup_job.py STORE_POOL_TOTALS)
+        st = dict(_backup_job.STORE_POOL_TOTALS)
+        gauge("pbs_plus_store_pool_chunks_total",
+              "Novel chunks a helper thread of the store pool stored in "
+              "a backup writer's place",
+              [({}, float(st["store_pool_chunks"]))])
+        gauge("pbs_plus_store_pool_flushes_total",
+              "Hash batches whose novel chunks the backup writers stored "
+              "with the store pool's helpers",
+              [({}, float(st["store_pool_flushes"]))])
+        gauge("pbs_plus_store_pool_seconds_total",
+              "The store pool's helpers' summed seconds inside the chunk "
+              "store's insert for backup writers",
+              [({}, float(st["store_pool_s"]))])
         gauge("pbs_plus_index_table_shards",
               "Devices the index's filter table went to, split by bucket "
               "range where one device cannot hold it, at its last whole "

@@ -323,6 +323,35 @@ def test_ingest_discipline_declared_backend_clean():
     assert v == []
 
 
+def test_ingest_discipline_flags_an_undeclared_store_fan_out():
+    v = run_lint("""
+        from .storepool import StoreFanOut
+
+        def store(self, novel, helpers):
+            if self.store.thread_safe:
+                fan = StoreFanOut(self.store.insert, novel)
+                fan.start(helpers)
+                fan.join()
+    """, path="pbs_plus_tpu/pxar/transfer.py", rules=["ingest-discipline"])
+    assert names(v) == ["ingest-discipline"]
+    assert "concurrent_insert" in v[0].message
+
+
+def test_ingest_discipline_declared_store_fan_out_clean():
+    v = run_lint("""
+        from .storepool import StoreFanOut
+
+        def width(self):
+            return int(self._ingest.capabilities.concurrent_insert)
+
+        def store(self, novel, helpers):
+            fan = StoreFanOut(self.store.insert, novel)
+            fan.start(helpers)
+            fan.join()
+    """, path="pbs_plus_tpu/pxar/transfer.py", rules=["ingest-discipline"])
+    assert v == []
+
+
 def test_ingest_discipline_scoped_to_stream_modules():
     # the sync plane legitimately calls probe_batch
     v = run_lint("""

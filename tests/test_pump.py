@@ -14,6 +14,7 @@ import pytest
 
 from agentfs_fakes import OpenReadViaCalls
 from pbs_plus_tpu.pxar.format import KIND_DIR, KIND_FILE, KIND_SYMLINK
+from pbs_plus_tpu.pxar.transfer import store_helpers
 from pbs_plus_tpu.server import backup_job as bj
 from pbs_plus_tpu.server.backup_job import RemoteTreeBackup
 from pbs_plus_tpu.utils import failpoints, trace
@@ -676,7 +677,8 @@ RECORD_KEYS = ({"job"} | set(bj.PUMP_TOTALS)
                | {"pump_" + k for k in bj.PUMP_WAITS}
                | {"pump_life_s", "loop_cpu0", "loop_cpu1"}
                | {"index_" + k for k in bj.INDEX_COUNTS}
-               | {"index_table_bytes", "index_table_shards"})
+               | {"index_table_bytes", "index_table_shards"}
+               | {"store_pool_" + k for k in bj.STORE_POOL_COUNTS})
 
 
 class _StreamSession:
@@ -693,13 +695,18 @@ class _StreamSession:
         from pbs_plus_tpu.pxar.datastore import ChunkStore
         monkeypatch.setattr(transfer, "_HASH_BATCH_COUNT", 4)
         store = ChunkStore(str(base), n_shards=2, index_budget_mb=2)
-        self.inserts = 0
+        # each insert's (start, end): the store stage may run a hash
+        # batch's inserts on several threads at once
+        self.spans: list = []
         insert = store.insert
 
         def slow_insert(digest, data, **kw):
-            self.inserts += 1
+            t0 = time.perf_counter()
             time.sleep(insert_delay)
-            return insert(digest, data, **kw)
+            try:
+                return insert(digest, data, **kw)
+            finally:
+                self.spans.append((t0, time.perf_counter()))
         store.insert = slow_insert
         self.writer = transfer.SessionWriter(
             store, payload_params=ChunkerParams(avg_size=1 << 10),
@@ -767,16 +774,25 @@ def test_a_slow_agent_is_the_writers_pump_wait_and_the_pumps_rpc_wait(
 
 def test_a_slow_store_is_the_writers_store_and_the_pumps_put_wait(
         tmp_path, monkeypatch):
-    """Every insert into the chunk store takes 20 ms: the writer's
-    thread is in its store state for that long, and the pump, one item
-    ahead of it, is suspended on the writer's queue (at the end, on its
-    join) as long; no other state of either moves by a tenth of it."""
+    """Every insert into the chunk store takes 60 ms: the writer's
+    thread is in its store state while any insert it began, or one of
+    its hash batch's inserts on the store pool's helpers, is under way,
+    and the pump, one item ahead of it, is suspended on the writer's
+    queue (at the end, on its join) as long; no other state of either
+    moves by a tenth of it.  (A batch's four inserts run at once where
+    the host has the cores: 60 ms keeps the wall time they take where
+    20 ms one after the other had it.)"""
     monkeypatch.setattr(bj, "QUEUE_DEPTH", 1)
-    sess = _StreamSession(tmp_path, monkeypatch, insert_delay=0.02)
+    sess = _StreamSession(tmp_path, monkeypatch, insert_delay=0.06)
     fs = CountingFS({f"f{i:02d}": 600 + i for i in range(60)})
     pump, attrs, writer, waits = _clocked_run(fs, sess, "row-slow-store")
-    injected = 0.02 * sess.inserts
-    assert sess.inserts >= 20
+    # the wall time in which at least one insert was under way
+    injected, reach = 0.0, 0.0
+    for t0, t1 in sorted(sess.spans):
+        injected += max(0.0, t1 - max(t0, reach))
+        reach = max(reach, t1)
+    assert len(sess.spans) >= 20
+    assert injected >= 0.06 * len(sess.spans) / (store_helpers() + 1)
     assert writer["store_s"] >= injected
     assert waits["put_wait_s"] + waits["join_wait_s"] >= injected
     assert waits["put_wait_s"] >= 0.8 * injected
@@ -842,7 +858,8 @@ def test_the_log_the_traces_endpoint_and_metrics_show_the_same_clocks(
             if line.startswith(("pbs_plus_writer_thread_seconds_total{",
                                 "pbs_plus_pump_wait_seconds_total{",
                                 "pbs_plus_loop_cpu_seconds_total",
-                                "pbs_plus_index_")):
+                                "pbs_plus_index_",
+                                "pbs_plus_store_pool_")):
                 name, value = line.rsplit(" ", 1)
                 out[name] = float(value)
         return out
@@ -858,7 +875,8 @@ def test_the_log_the_traces_endpoint_and_metrics_show_the_same_clocks(
     logged = {k: float(v) for k, v in (
         kv.split("=") for kv in lines[0].getMessage().split(": ")[1].split())}
     clocks = {k: v for k, v in attrs.items()
-              if k.startswith(("writer_", "pump_", "loop_", "index_"))}
+              if k.startswith(("writer_", "pump_", "loop_", "index_",
+                               "store_pool_"))}
     assert set(logged) == set(clocks)
     assert logged == pytest.approx(clocks, abs=1e-6)
     # the endpoint: the ring's span, and the table's record
@@ -891,6 +909,11 @@ def test_the_log_the_traces_endpoint_and_metrics_show_the_same_clocks(
         == attrs["index_table_upload_bytes"]
     assert moved["pbs_plus_index_upload_seconds_total"] \
         == pytest.approx(attrs["index_upload_s"], abs=1e-6)
+    # and by what the store pool did for it
+    for key, name in (("chunks", "chunks_total"), ("flushes", "flushes_total"),
+                      ("s", "seconds_total")):
+        assert moved["pbs_plus_store_pool_" + name] == pytest.approx(
+            attrs["store_pool_" + key], abs=1e-6)
 
 
 # ------------------------------------------------ blocks served as views

@@ -378,14 +378,17 @@ def test_resolve_backend_declared_capabilities(tmp_path):
     indexed = ChunkStore(str(tmp_path / "indexed"))
     be = resolve_ingest_backend(indexed)
     assert isinstance(be, StoreIngestBackend)
-    assert be.capabilities == IngestCapabilities(probe=True,
-                                                 presketch=False)
+    assert be.capabilities == IngestCapabilities(
+        probe=True, presketch=False, concurrent_insert=True)
     indexed.similarity = SimilarityIndex()
     assert be.capabilities.presketch is True      # live re-read
+    # the tier's sketch pool follows the order chunks arrive in
+    assert be.capabilities.concurrent_insert is False
 
     legacy = ChunkStore(str(tmp_path / "legacy"), index_budget_mb=0)
     assert resolve_ingest_backend(legacy).capabilities == \
-        IngestCapabilities(probe=False, presketch=False)
+        IngestCapabilities(probe=False, presketch=False,
+                           concurrent_insert=True)
 
 
 def test_resolve_backend_undeclared_store_is_inline():

@@ -18,6 +18,12 @@ hazards are flagged:
   ``sha256_stream_chunks`` / ``sha256_streams_chunks``) — chunk
   fingerprinting flows through the injected ``batch_hasher`` seam,
   never a per-stage kernel dispatch of the stream's own.
+- **Undeclared concurrent inserts**: a store-pool fan-out
+  (``StoreFanOut(...)``, pxar/storepool.py) in a module that never
+  reads the resolved backend's ``capabilities.concurrent_insert`` —
+  whether a stream's inserts may run at once is the store's declared
+  capability (a remote sink shares one connection, the similarity tier
+  depends on the order chunks arrive in).
 
 Receivers whose source text mentions the resolved backend
 (``self._ingest`` / a local named ``backend``) are the sanctioned seam.
@@ -48,10 +54,34 @@ class IngestDiscipline(Rule):
                  "store/kernel calls")
 
     def begin_file(self, ctx):
+        self._fan_outs: list = []
+        self._declared = False
         return ctx.path in _SCOPES
+
+    def end_file(self, ctx) -> None:
+        if self._declared:
+            return
+        for node in self._fan_outs:
+            ctx.report(self, node,
+                       "store-pool fan-out with no read of the resolved "
+                       "backend's `capabilities.concurrent_insert`: "
+                       "concurrent inserts are a DECLARED capability "
+                       "(pxar/ingestbackend.py)")
+
+    def visit_Attribute(self, ctx, node: ast.Attribute) -> None:
+        if node.attr == "concurrent_insert":
+            try:
+                recv = ast.unparse(node.value).lower()
+            except Exception:
+                recv = ""
+            if any(m in recv for m in _SEAM_MARKERS):
+                self._declared = True
 
     def visit_Call(self, ctx, node: ast.Call) -> None:
         func = node.func
+        if call_name(node) in ("StoreFanOut", "storepool.StoreFanOut"):
+            self._fan_outs.append(node)
+            return
         if call_name(node) == "getattr" and len(node.args) >= 2:
             arg = node.args[1]
             if isinstance(arg, ast.Constant) and arg.value in _DUCK_NAMES:
